@@ -93,6 +93,8 @@ def main(argv=None) -> None:
                          "ledger JSON (env REPRO_LEDGER)")
     args = ap.parse_args(argv)
     sections = _parse_sections(args.sections)
+    from repro.core.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     quick = os.environ.get("REPRO_BENCH_FULL", "0") != "1"
     from benchmarks import stencil_bench, cg_bench, policy_bench, decode_bench

@@ -228,14 +228,17 @@ def gm_bytes_deep(
     return passes * 2.0 * uncached + 2.0 * cached_bytes
 
 
-def deep_scratch_rows(sub_rows: int, radius: int, fuse_steps: int) -> int:
+def deep_scratch_rows(sub_rows: int, halo: int, fuse_steps: int) -> int:
     """VMEM working-set rows of the deep wavefront kernel beyond the
-    resident region: (2t+3) block buffers (triple-buffered level 0 for
-    DMA overlap, one ping-pong pair per inner level, a double-buffered
-    write-back) plus (t+1) radius-row edge stashes — exactly
-    ``kernels.stencil2d._deep_scratch_shapes`` in row units. Linear in t:
-    this is where deep blocking pays for its depth."""
-    return (2 * fuse_steps + 3) * sub_rows + (fuse_steps + 1) * radius
+    resident region: (2t+5) block buffers (triple-buffered level 0, one
+    ping-pong pair per inner level, a double-buffered write-back, the
+    resident sweep's output) and the stage window (one block plus a halo
+    on each side), plus (t+3) halo-row stashes — exactly
+    ``kernels.stencil2d._deep_scratch_shapes`` in row units, with ``halo``
+    the radius rounded up to the tile rows. Linear in t: this is where
+    deep blocking pays for its depth."""
+    return ((2 * fuse_steps + 5) * sub_rows + 2 * halo
+            + (fuse_steps + 3) * halo)
 
 
 def plan_fuse_steps(

@@ -1,8 +1,8 @@
 """Hardware constants used by the cache policy and the performance models.
 
-The TPU v5e numbers are the assignment-specified target; the GPU entries
-mirror Table I of the paper and are used only by the paper-fidelity
-performance-model checks.
+The TPU entries are looked up by the attached device's ``device_kind``
+(``attached_chip``); the GPU entries mirror Table I of the paper and are
+used only by the paper-fidelity performance-model checks.
 """
 from __future__ import annotations
 
@@ -33,11 +33,25 @@ class Chip:
     ici_bw_per_link: float = 0.0
     # Number of ICI links per chip participating in a collective (torus).
     ici_links: int = 1
+    # Pallas kernels run in interpret mode, not through Mosaic: the CPU
+    # test backend's stand-in for a TPU (``attached_chip``), where every
+    # kernel form runs, including those Mosaic refuses. The planner reads
+    # it through ``Problem.resident_compiles``.
+    interpret: bool = False
 
 
-# Assignment-mandated target. 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link.
-# VMEM on v5e is 128 MiB per TensorCore; VMEM bandwidth is taken as ~22x the
-# HBM bandwidth (consistent with public Mosaic/TPU guidance of O(10 TB/s)).
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s (Google Cloud, "TPU v5e");
+# ~50 GB/s/link ICI.
+# VMEM: 128 MiB, the figure Mosaic itself enforces. Compiling for a
+# described v5e topology (jax.experimental.topologies, "v5e:2x2") accepts
+# a kernel holding 128 MiB of VMEM scratch and refuses 129 MiB with
+# "would exceed memory (size=134217728)". A kernel's scoped-VMEM limit
+# (``vmem_limit_bytes``, 16 MiB by default) may be raised to that figure,
+# so every kernel requests its own footprint
+# (``kernels.common.compiler_params``) and the planner admits a plan only
+# when that footprint fits ``onchip_bytes``.
+# VMEM bandwidth is taken as ~22x the HBM bandwidth (public Mosaic/TPU
+# guidance of O(10 TB/s)); not measured.
 TPU_V5E = Chip(
     name="tpu_v5e",
     peak_flops=197e12,
@@ -105,6 +119,36 @@ V100 = Chip(
 )
 
 CHIPS = {c.name: c for c in (TPU_V5E, TPU_V4, TPU_V5P, A100, V100)}
+
+#: What the CPU test backend plans for: the v5e's figures, with every
+#: Pallas kernel run in interpret mode.
+CPU_INTERPRET = dataclasses.replace(TPU_V5E, interpret=True)
+
+#: ``jax.Device.device_kind`` of an attached TPU -> its Chip. A kind that
+#: is not listed is an error, never a default. Only the v5e is listed: its
+#: VMEM figure is the one established by compiling for it (above).
+DEVICE_KINDS = {"TPU v5 lite": TPU_V5E}
+
+
+def attached_chip() -> Chip:
+    """The Chip of the first attached device, looked up by its
+    ``device_kind``. On the CPU (the test suite, where every kernel runs
+    in interpret mode) plans are made for ``CPU_INTERPRET``; any other
+    platform is an error."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return CPU_INTERPRET
+    if dev.platform != "tpu":
+        raise ValueError(
+            f"no Chip for platform {dev.platform!r}: the kernels run on a "
+            "TPU, or interpreted on the CPU")
+    try:
+        return DEVICE_KINDS[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no Chip for device kind {dev.device_kind!r}; add its published "
+            "figures to repro.core.hardware.DEVICE_KINDS") from None
 
 
 def vmem_cache_budget(chip: Chip, working_set_bytes: float) -> float:

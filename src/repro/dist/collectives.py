@@ -20,16 +20,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 AxisName = Union[str, Sequence[str]]
 
 
-def axis_size(name: str) -> int:
-    """Static size of named axis ``name`` inside a shard_map body (version
-    portable; jax only grew ``lax.axis_size`` after 0.4.x)."""
-    try:
-        return int(jax.lax.axis_size(name))
-    except AttributeError:
-        frame = jax.core.axis_frame(name)
-        return int(getattr(frame, "size", frame))
-
-
 # -- thin reduction wrappers (so solvers/models import one module) ---------------
 
 def psum(x, axis: AxisName):
@@ -78,7 +68,7 @@ def halo_exchange(x, radius: int, axis: str, *, periodic: bool = False):
         raise ValueError(
             f"halo radius {radius} exceeds shard extent {x.shape[0]}; "
             f"lower fuse_steps or use more rows per shard")
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     fwd = [(i, (i + 1) % n) for i in range(n if periodic else n - 1)]
     bwd = [((i + 1) % n, i) for i in range(n if periodic else n - 1)]
     if n == 1:

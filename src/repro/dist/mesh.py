@@ -5,37 +5,24 @@ importing ``repro.dist`` never locks the backend device count — the
 dry-run and the subprocess-spawned multi-device tests both set
 ``XLA_FLAGS`` before the first mesh is built.
 
-``make_mesh`` papers over a JAX API gap: ``jax.make_mesh`` grew an
-``axis_types`` keyword after 0.4.x. All meshes in this repo are Auto-typed
-(shard_map supplies explicit specs everywhere), so on older JAX we simply
-drop the keyword — semantics are identical.
+All meshes in this repo are Auto-typed: shard_map supplies explicit specs
+everywhere.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Optional, Sequence
 
 import jax
 
 
-_SUPPORTS_AXIS_TYPES = "axis_types" in inspect.signature(
-    jax.make_mesh).parameters
-
-
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               *, devices=None):
-    """``jax.make_mesh`` with Auto axis types on every JAX version.
-
-    Uses the first ``prod(axis_shapes)`` local devices when ``devices`` is
-    not given (matching ``jax.make_mesh``).
-    """
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if _SUPPORTS_AXIS_TYPES:
-        kwargs["axis_types"] = (
-            jax.sharding.AxisType.Auto,) * len(tuple(axis_names))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` with Auto axis types. Uses the first
+    ``prod(axis_shapes)`` local devices when ``devices`` is not given."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)),
+        devices=devices)
 
 
 def mesh_axis_size(mesh: jax.sharding.Mesh, name: str) -> int:

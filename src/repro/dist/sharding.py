@@ -13,7 +13,7 @@ onto whatever mesh the launcher built:
     divisibility fallback (indivisible dims replicate, recorded in
     ``Rules.fallbacks`` for the dry-run report),
   * ``Rules.param_shardings`` — NamedSharding tree for a ParamSpec tree,
-  * ``smap``               — ``shard_map`` across JAX versions.
+  * ``smap``               — ``jax.shard_map`` with explicit specs.
 
 Default table (axes absent from the mesh are dropped):
 
@@ -24,7 +24,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import inspect
 import math
 from typing import Any, Optional, Sequence
 
@@ -33,22 +32,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.dist.mesh import mesh_axis_size
 
-try:  # pragma: no cover - version compat
-    from jax import shard_map as _shard_map          # jax >= 0.6
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SMAP_CHECK_ARG = (
-    "check_rep" if "check_rep" in inspect.signature(_shard_map).parameters
-    else "check_vma")
-
-
-def smap(fn, *, mesh: Mesh, in_specs, out_specs, check_rep: bool = False):
-    """``shard_map`` with explicit mesh/specs and replication checking off
-    by default (the solvers' psum/ppermute patterns are manual SPMD; the
-    rep checker predates several of them)."""
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_SMAP_CHECK_ARG: check_rep})
+def smap(fn, *, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
+    """``jax.shard_map`` with explicit mesh/specs and replication checking
+    off by default (the solvers' psum/ppermute patterns are manual SPMD;
+    the checker predates several of them)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 # -- the rule table -------------------------------------------------------------

@@ -30,13 +30,14 @@ from repro.core.cache_policy import (
     cg_arrays_for,
     stencil_shard_arrays,
 )
-from repro.dist.collectives import axis_size, halo_exchange
+from repro.dist.collectives import halo_exchange
 from repro.dist.sharding import smap
 from repro.exec.precision import PRECISIONS, dot_for
 from repro.exec.problem import HaloSpec, Problem, operand_fingerprint
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.kernels.common import StencilSpec
+from repro.kernels.stencil2d import row_align
 
 
 def operator_fingerprint(data, cols, matrix, matvec) -> str:
@@ -104,7 +105,7 @@ def make_distributed_step(spec: StencilSpec, mesh: Mesh, axis: str = "data",
 
     def local_step(x_l):
         h = x_l.shape[0]
-        n = axis_size(axis)
+        n = jax.lax.axis_size(axis)
         idx = jax.lax.axis_index(axis)
         H = h * n                      # global leading extent
         top, bot = halo_exchange(x_l, r * t, axis)
@@ -211,6 +212,12 @@ class StencilProblem(Problem):
 
     # -- tiers ----------------------------------------------------------------
 
+    def resident_compiles(self, chip) -> bool:
+        # Mosaic takes only tile-aligned leading-axis windows
+        # (kernels/stencil2d._check_tiling), the domain's extent included
+        return chip.interpret or self.x.shape[0] % row_align(
+            self.x.shape, self.x.dtype) == 0
+
     def _trace_resident(self, plan) -> None:
         """Structural chunk/dma events for a resident dispatch (DESIGN.md
         §11/§12): the kernel's streaming passes happen inside ONE Pallas
@@ -258,7 +265,8 @@ class StencilProblem(Problem):
         self._trace_resident(plan)
         if cached_rows >= self.x.shape[0]:
             return kops.stencil_resident(self.x, spec=self.spec,
-                                         steps=self.n_steps)
+                                         steps=self.n_steps,
+                                         sub_rows=plan.sub_rows)
         if plan.schedule == "deep":
             return kops.stencil_perks_deep(
                 self.x, spec=self.spec, steps=self.n_steps,
@@ -506,6 +514,11 @@ class CGProblem(Problem):
         return name != "A"
 
     # -- tiers ----------------------------------------------------------------
+
+    def resident_compiles(self, chip) -> bool:
+        # the fused kernels gather x by column index, which Mosaic does
+        # not lower for a TPU (kernels/spmv_ell.py)
+        return chip.interpret
 
     def run_resident(self, plan):
         if self.data is None:

@@ -50,6 +50,7 @@ from repro.core.cache_policy import (
 )
 from repro.dist.sharding import smap
 from repro.exec.adapters import (
+    CGProblem,
     _operand_sig,
     fused_block_rows,
     fusion_schedule,
@@ -239,6 +240,8 @@ class BiCGStabProblem(Problem):
         return name != "A"
 
     # -- tiers ----------------------------------------------------------------
+
+    resident_compiles = CGProblem.resident_compiles
 
     def run_resident(self, plan):
         if self.data is None:
@@ -435,6 +438,8 @@ class GMRESProblem(Problem):
         return name != "A"
 
     # -- tiers ----------------------------------------------------------------
+
+    resident_compiles = CGProblem.resident_compiles
 
     def run_resident(self, plan):
         if self.data is None:

@@ -395,14 +395,16 @@ class SSMScanProblem(Problem):
         ]
 
     def resident_scratch_bytes(self) -> int:
-        """VMEM the kernel needs live at once: the f32 state plus one
-        chunk's input/output tiles."""
-        t, h, p = (int(s) for s in self.x.shape)
-        n = int(self.b.shape[-1])
-        db = jnp.dtype(self.x.dtype).itemsize
-        ck = self.chunk_eff
-        tiles = ck * (2 * h * p + h + 2 * n) * db
-        return h * n * p * 4 + tiles
+        """The kernel's whole VMEM request: the f32 state, its
+        double-buffered chunk tiles, one head's temporaries."""
+        from repro.kernels.ssm_scan import ssm_vmem_bytes
+        return ssm_vmem_bytes(self.x, self.dt, self.a, self.b, self.c,
+                              self.d, chunk=self.chunk_eff)
+
+    def resident_compiles(self, chip) -> bool:
+        from repro.kernels.ssm_scan import chunk_compiles
+        return chip.interpret or chunk_compiles(int(self.x.shape[0]),
+                                                self.chunk_eff)
 
     def domain_bytes(self) -> int:
         return sum(a.bytes for a in self.cacheable_arrays()

@@ -23,15 +23,19 @@ from typing import Optional, Sequence
 
 from repro.core.cache_policy import (
     cg_arrays,
-    deep_scratch_rows,
     gm_bytes_deep,
     gm_bytes_fused,
     plan_caching,
 )
-from repro.core.hardware import CHIPS, Chip, TPU_V5E
+from repro.core.hardware import CHIPS, Chip, TPU_V5E, attached_chip
 from repro.core.perf_model import project_host_loop, sm_bytes_accessed
 from repro.exec.plan import CacheDecision, Plan
 from repro.exec.problem import Problem
+from repro.kernels.stencil2d import (
+    deep_vmem_bytes,
+    default_sub_rows,
+    perks_vmem_bytes,
+)
 from repro.kernels.stencil3d import plan_resident_planes
 
 #: Host→device dispatch cost charged per kernel launch (the overhead the
@@ -52,6 +56,8 @@ DEEP_MAX_FUSE = 32
 
 
 def _as_chip(chip) -> Chip:
+    if chip is None:
+        return attached_chip()
     if isinstance(chip, Chip):
         return chip
     return CHIPS[chip]
@@ -78,7 +84,8 @@ def _rank(cands: list[Plan]) -> list[Plan]:
 # -----------------------------------------------------------------------------
 
 def _stencil_candidates(problem, chip: Chip, mesh, *, max_fuse: int,
-                        shard_axis: str, sub_rows: int, batch: int = 1,
+                        shard_axis: str, sub_rows: Optional[int],
+                        batch: int = 1,
                         name: Optional[str] = None) -> list[Plan]:
     shape = problem.x.shape
     db = problem.x.dtype.itemsize
@@ -108,14 +115,33 @@ def _stencil_candidates(problem, chip: Chip, mesh, *, max_fuse: int,
     # depth (the wider streaming window of deeper fusion evicts planes).
     # Each instance of a batch gets 1/B of the on-chip budget — the
     # B-scaled working set (DESIGN.md §8) — so large batches naturally
-    # demote toward the loop tiers.
+    # demote toward the loop tiers. Streamed block rows are planned per
+    # schedule and depth unless the caller fixes ``sub_rows``.
     from repro.exec.batch import per_instance_chip
     chip_per_inst = per_instance_chip(chip, B)
+    dtype = problem.x.dtype
+
+    def block_rows(schedule, t):
+        if sub_rows is not None:
+            return sub_rows if schedule == "shallow" else max(sub_rows, r)
+        return default_sub_rows(shape, dtype, problem.spec,
+                                schedule=schedule, fuse_steps=t)
+
+    def fits(footprint, sub, t):
+        # the kernel's whole VMEM request with nothing resident — the
+        # scoped limit it asks Mosaic for — must fit this instance's VMEM
+        return footprint(shape, dtype, problem.spec, cached_rows=0,
+                         sub_rows=sub, fuse_steps=t) \
+            <= chip_per_inst.onchip_bytes
+
     t = 1
     while t <= max(1, min(max_fuse, n)):
+        sub = block_rows("shallow", t)
+        if not fits(perks_vmem_bytes, sub, t):
+            break
         rows = plan_resident_planes(shape, db, problem.spec,
                                     chip=chip_per_inst,
-                                    sub_rows=sub_rows, fuse_steps=t)
+                                    sub_rows=sub, fuse_steps=t)
         cached_bytes = rows * row_bytes
         gm = gm_bytes_fused(n, domain_bytes, cached_bytes,
                             row_bytes=row_bytes, radius=r, fuse_steps=t)
@@ -124,7 +150,7 @@ def _stencil_candidates(problem, chip: Chip, mesh, *, max_fuse: int,
         bound = "main_memory" if t_gm >= t_sm else "onchip_memory"
         cands.append(Plan(
             tier="resident", fuse_steps=t, cached_rows=rows,
-            sub_rows=sub_rows,
+            sub_rows=sub,
             cache=(CacheDecision("domain_rows", B * cached_bytes,
                                  B * domain_bytes),),
             predicted_s=max(t_gm, t_sm) + DISPATCH_OVERHEAD_S,
@@ -134,17 +160,16 @@ def _stencil_candidates(problem, chip: Chip, mesh, *, max_fuse: int,
     # RESIDENT × DEEP wavefront schedule (DESIGN.md §12): each streaming
     # pass reads and writes every uncached row exactly once regardless of
     # t, so depth is no longer capped by the shallow r*t recompute window.
-    # The B-scaled scratch gate runs BEFORE the candidate is emitted —
+    # The B-scaled footprint gate runs BEFORE the candidate is emitted —
     # the planner must never offer a deep plan whose wavefront buffers
     # (per-instance, so ×B across a batched dispatch) exceed the chip's
-    # VMEM budget, and since the scratch grows monotonically in t the
-    # first overflow terminates the depth sweep (batches thus demote
-    # depth before resident rows).
-    deep_sub = max(sub_rows, r)
+    # VMEM, and since the footprint grows monotonically in t the first
+    # overflow terminates the depth sweep (batches thus demote depth
+    # before resident rows).
     t = 2
     while t <= max(1, min(max(max_fuse, DEEP_MAX_FUSE), n)):
-        scratch = deep_scratch_rows(deep_sub, r, t) * row_bytes
-        if scratch > chip_per_inst.onchip_bytes * 0.9:
+        deep_sub = block_rows("deep", t)
+        if not fits(deep_vmem_bytes, deep_sub, t):
             break
         rows = plan_resident_planes(shape, db, problem.spec,
                                     chip=chip_per_inst, sub_rows=deep_sub,
@@ -340,7 +365,8 @@ def _ml_candidates(problem, chip: Chip, *, sync_every: Optional[int],
     ``cacheable_arrays`` prices the loop tiers; the resident tier elides
     the ``carry_names`` arrays' round-trips (they live on-chip for the
     whole time loop) and is gated on ``resident_scratch_bytes`` fitting
-    the per-instance VMEM budget (``per_instance_chip``, DESIGN.md §8).
+    the per-instance VMEM (``per_instance_chip``, DESIGN.md §8); for the
+    SSD scan that is the Pallas kernel's own ``vmem_limit_bytes``.
     """
     from repro.exec.batch import per_instance_chip
 
@@ -385,8 +411,8 @@ def _ml_candidates(problem, chip: Chip, *, sync_every: Optional[int],
     # (executor.honors_on_sync); EOS decode lands on device_loop+sync.
     chip_per_inst = per_instance_chip(chip, batch)
     scratch = problem.resident_scratch_bytes()
-    if (not has_sync and n > 0
-            and scratch <= chip_per_inst.onchip_bytes * 0.9):
+    if (not has_sync and n > 0 and scratch is not None
+            and scratch <= chip_per_inst.onchip_bytes):
         t_gm = n * max(0.0, total - carry) / chip.hbm_bw
         t_sm = sm_bytes_accessed(n, carry_bytes) / chip.onchip_bw
         bound = "main_memory" if t_gm >= t_sm else "onchip_memory"
@@ -403,17 +429,22 @@ def _ml_candidates(problem, chip: Chip, *, sync_every: Optional[int],
 # Public entry points
 # -----------------------------------------------------------------------------
 
-def plan_candidates(problem: Problem, *, chip=TPU_V5E, mesh=None,
+def plan_candidates(problem: Problem, *, chip=None, mesh=None,
                     budget_bytes: Optional[int] = None, max_fuse: int = 4,
-                    shard_axis: str = "data", sub_rows: int = 128,
+                    shard_axis: str = "data", sub_rows: Optional[int] = None,
                     sync_every: Optional[int] = None,
                     batch: int = 1, ledger=None) -> list[Plan]:
     """Every candidate Plan for ``problem``, ranked by projected time.
 
     ``chip`` is a :class:`~repro.core.hardware.Chip` or a name from
-    ``CHIPS``; ``budget_bytes`` overrides its on-chip capacity (e.g. the
+    ``CHIPS``, by default the attached device's (``attached_chip``; on the
+    CPU, ``CPU_INTERPRET``). No resident candidate is offered whose
+    kernel Mosaic refuses for that chip (``Problem.resident_compiles``);
+    ``budget_bytes`` overrides its on-chip capacity (e.g. the
     ``PROXY_ONCHIP_BYTES`` regime); ``mesh`` enables distributed
-    candidates over ``shard_axis``; ``max_fuse`` caps temporal blocking.
+    candidates over ``shard_axis``; ``max_fuse`` caps temporal blocking;
+    ``sub_rows`` fixes the resident stencil's streamed block rows (planned
+    per schedule and depth when omitted).
 
     ``batch`` plans for B instances served by ONE dispatch
     (``repro.exec.batch``): per-step traffic and per-instance VMEM
@@ -455,7 +486,9 @@ def plan_candidates(problem: Problem, *, chip=TPU_V5E, mesh=None,
     else:
         raise NotImplementedError(
             f"no candidate generator for problem kind {template.kind!r}")
-    cands = [c for c in cands if problem.supports(c.tier)]
+    # no resident plan whose kernel the chip's compiler would refuse
+    cands = [c for c in cands if problem.supports(c.tier) and (
+        c.tier != "resident" or template.resident_compiles(chip))]
     cands = _rank(cands)
     if ledger is None:
         ledger = obs.get_ledger()
@@ -469,9 +502,9 @@ def plan_candidates(problem: Problem, *, chip=TPU_V5E, mesh=None,
     return cands
 
 
-def plan(problem: Problem, *, chip=TPU_V5E, mesh=None,
+def plan(problem: Problem, *, chip=None, mesh=None,
          budget_bytes: Optional[int] = None, max_fuse: int = 4,
-         shard_axis: str = "data", sub_rows: int = 128,
+         shard_axis: str = "data", sub_rows: Optional[int] = None,
          sync_every: Optional[int] = None, batch: int = 1,
          ledger=None) -> Plan:
     """The planner's top candidate for ``problem``: lowest measured time

@@ -10,10 +10,11 @@ hardens only the reductions:
 
 * with fp64 enabled (``jax_enable_x64``): accumulate the dot in fp64 and
   round once back to the storage dtype;
-* without it (this container's default): Neumaier block-compensated
-  summation of the fp32 products — the accumulation error drops from
-  O(n·eps) to O(eps) + O(block·eps) per block partial, at ~3x the adds
-  and zero extra memory traffic (the terms are already on-chip).
+* without it (the TPU's default): pairwise summation of the fp32
+  products in which every addition keeps its exact rounding error
+  (TwoSum) — the accumulation error drops from O(n·eps) to
+  O(eps) + O(n·eps²), at ~4x the adds and zero extra memory traffic
+  (the terms are already on-chip).
 
 ``solve_refined`` layers iterative refinement on top: solve in working
 precision, recompute the true residual, re-solve for the correction —
@@ -30,46 +31,40 @@ import jax.numpy as jnp
 #: Plan.precision values (plan.py validates against this).
 PRECISIONS = ("uniform", "mixed")
 
-#: block width for compensated summation — one Neumaier carry per block
-#: partial keeps the scan short (n/block sequential steps) while the
-#: in-block fp32 partial stays O(block·eps) accurate.
-_BLOCK = 256
-
 
 def _x64_enabled() -> bool:
     return bool(jax.config.jax_enable_x64)
 
 
-def compensated_sum(x: jax.Array) -> jax.Array:
-    """Neumaier block-compensated sum of a 1-D array (storage dtype out).
+def _two_sum(a: jax.Array, b: jax.Array):
+    """Knuth's TwoSum: ``s = fl(a + b)`` and its exact rounding error."""
+    s = a + b
+    bb = s - a
+    return s, (a - (s - bb)) + (b - bb)
 
-    The array is padded with zeros to a multiple of ``_BLOCK``; each block
-    reduces with the backend's native sum, and the block partials are
-    folded left-to-right through a Neumaier two-sum carry, so the partial
-    that is *smaller* in magnitude contributes its rounding error to the
-    running compensation instead of losing it.
+
+def compensated_sum(x: jax.Array) -> jax.Array:
+    """Compensated pairwise sum of a 1-D array (storage dtype out).
+
+    The array is padded with zeros to a power of two and halved level by
+    level, adjacent pairs added through ``_two_sum``; each level's
+    rounding errors are summed into a running compensation, which is
+    added to the total once at the end. Only the errors (already
+    O(eps) of the partials) are summed natively, so what that loses is
+    O(eps²).
     """
     (n,) = x.shape
-    nb = -(-n // _BLOCK)
-    pad = nb * _BLOCK - n
-    blocks = jnp.sum(jnp.pad(x, (0, pad)).reshape(nb, _BLOCK), axis=1)
-
-    def two_sum(carry, v):
-        s, comp = carry
-        t = s + v
-        # Neumaier: whichever operand is larger absorbs the other exactly;
-        # the remainder of the smaller one is recoverable.
-        err = jnp.where(jnp.abs(s) >= jnp.abs(v),
-                        (s - t) + v, (v - t) + s)
-        return (t, comp + err), None
-
-    zero = jnp.zeros((), x.dtype)
-    (s, comp), _ = jax.lax.scan(two_sum, (zero, zero), blocks)
-    return s + comp
+    v = jnp.pad(x, (0, (1 << max(n - 1, 0).bit_length()) - n))
+    comp = jnp.zeros((), x.dtype)
+    while v.shape[0] > 1:
+        pairs = v.reshape(-1, 2)
+        v, err = _two_sum(pairs[:, 0], pairs[:, 1])
+        comp = comp + jnp.sum(err)
+    return v[0] + comp
 
 
 def compensated_vdot(a: jax.Array, b: jax.Array) -> jax.Array:
-    """``vdot`` with a hardened accumulation (fp64 when enabled, Neumaier
+    """``vdot`` with a hardened accumulation (fp64 when enabled, TwoSum
     otherwise). The elementwise products still round once in the storage
     dtype — full fp64 accuracy needs ``jax_enable_x64``; what this
     removes is the O(n·eps) *accumulation* error that dominates for the

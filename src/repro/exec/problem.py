@@ -231,3 +231,10 @@ class Problem(abc.ABC):
         if tier == "distributed":
             return type(self).run_distributed is not Problem.run_distributed
         return False
+
+    def resident_compiles(self, chip) -> bool:
+        """Whether this problem's resident-tier kernel can be built for
+        ``chip`` (a :class:`~repro.core.hardware.Chip`). Mosaic refuses
+        some kernel forms that interpret mode (``chip.interpret``) runs;
+        the planner offers no resident plan where this is False."""
+        return True

@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.spmv_ell import gather_interpret
+
 
 def _safe_div(a, b):
     return jnp.where(jnp.abs(b) > 0, a / jnp.where(b == 0, 1.0, b), 0.0)
@@ -118,8 +120,7 @@ def cg_fused(
     """
     n, k = data.shape
     assert cols.shape == (n, k) and b.shape == (n,)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = gather_interpret(interpret, "cg_fused")
     out_shape = (
         jax.ShapeDtypeStruct((n,), b.dtype),
         jax.ShapeDtypeStruct((1,), b.dtype),

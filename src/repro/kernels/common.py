@@ -1,4 +1,6 @@
-"""Shared kernel machinery: stencil specifications (Table III of the paper).
+"""Shared kernel machinery: stencil specifications (Table III of the
+paper) and the VMEM accounting every Pallas kernel requests its scoped
+limit by.
 
 A ``StencilSpec`` is a pure description — offsets + weights — consumed by
 the Pallas kernels (``stencil2d.py``/``stencil3d.py``), the jnp oracles
@@ -13,8 +15,31 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import math
 from typing import Sequence
 
+import jax.numpy as jnp
+
+#: VMEM a kernel requests beyond its own buffers and temporaries estimate,
+#: for Mosaic's internal scratch and register spills. Each kernel passes
+#: its estimate plus this as ``vmem_limit_bytes``: Mosaic's default scoped
+#: limit is 16 MiB on a v5e, and the limit may go up to the chip's whole
+#: VMEM (``core.hardware.Chip.onchip_bytes``), which the planner's budget
+#: matches.
+VMEM_HEADROOM_BYTES = 4 << 20
+
+
+def vmem_bytes(shape, dtype) -> int:
+    """VMEM bytes of one buffer: its two minor axes padded to the memory
+    tile (8 sublanes of 32-bit words, 128 lanes); a 1-D buffer to whole
+    1024-element vregs."""
+    itemsize = jnp.dtype(dtype).itemsize
+    if len(shape) < 2:
+        return -(-math.prod(shape) // 1024) * 1024 * itemsize
+    sub = 8 * max(1, 4 // itemsize)
+    *lead, s, lanes = shape
+    return (math.prod(lead) * -(-s // sub) * sub * -(-lanes // 128) * 128
+            * itemsize)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,16 +85,19 @@ class StencilSpec:
                 idx.append(slice(r + d, n - r + d))
             term = w * x[tuple(idx)]
             acc = term if acc is None else acc + term
-        out = x[lo:hi]
-        interior = tuple([slice(None)] + [slice(r, x.shape[1 + ax] - r)
-                                          for ax in range(self.ndim - 1)])
-        return out.at[interior].set(acc.astype(x.dtype))
-
-    def apply(self, x):
-        """One full time step: interior updated, global border frozen."""
-        r = self.radius
-        upd = self.apply_rows(x, r, x.shape[0] - r)
-        return x.at[r:x.shape[0] - r].set(upd)
+        # Re-attach the frozen border strips by concatenation, innermost
+        # axis first (Mosaic lowers no scatter, so no ``.at[].set``).
+        out = acc.astype(x.dtype)
+        for d in range(self.ndim - 1, 0, -1):
+            def strip(s, d=d):
+                idx = ([slice(lo, hi)]
+                       + [slice(r, x.shape[k] - r) for k in range(1, d)]
+                       + [s] + [slice(None)] * (self.ndim - 1 - d))
+                return x[tuple(idx)]
+            n = x.shape[d]
+            out = jnp.concatenate(
+                [strip(slice(0, r)), out, strip(slice(n - r, n))], axis=d)
+        return out
 
 
 def _star(ndim: int, radius: int) -> list[tuple[int, ...]]:

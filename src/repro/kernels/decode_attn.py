@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import VMEM_HEADROOM_BYTES, vmem_bytes
+
 
 def _decode_kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *, blocks: int):
     sb = pl.program_id(1)
@@ -77,6 +79,14 @@ def decode_attention(
         interpret = jax.default_backend() != "tpu"
     blocks = s // sb
     g = hq // hkv
+    f32 = jnp.float32
+    # double-buffered q/k/v/out blocks, the f32 carry, the step's f32
+    # copies of k/v and its logits/probabilities
+    need = (2 * (2 * vmem_bytes((1, hq, d), q.dtype)
+                 + 2 * vmem_bytes((1, sb, hkv, d), k.dtype))
+            + 2 * vmem_bytes((hkv, g), f32) + vmem_bytes((hkv, g, d), f32)
+            + 2 * vmem_bytes((sb, hkv, d), f32)
+            + 2 * vmem_bytes((hkv, g, sb), f32))
     return pl.pallas_call(
         functools.partial(_decode_kernel, blocks=blocks),
         grid=(bsz, blocks),
@@ -93,5 +103,8 @@ def decode_attention(
             pltpu.VMEM((hkv, g), jnp.float32),
             pltpu.VMEM((hkv, g, d), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=need + VMEM_HEADROOM_BYTES),
+        name="decode_attention",
         interpret=interpret,
     )(q, k, v)

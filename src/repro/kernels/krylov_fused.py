@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.spmv_ell import gather_interpret
+
 
 def _safe_div(a, b):
     return jnp.where(jnp.abs(b) > 0, a / jnp.where(b == 0, 1.0, b), 0.0)
@@ -141,8 +143,7 @@ def bicgstab_fused(
     Oracle: ``repro.kernels.ref.bicgstab_run``."""
     n, k = data.shape
     assert cols.shape == (n, k) and b.shape == (n,)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = gather_interpret(interpret, "bicgstab_fused")
     out_shape = (
         jax.ShapeDtypeStruct((n,), b.dtype),
         jax.ShapeDtypeStruct((1,), b.dtype),
@@ -247,8 +248,7 @@ def gmres_cycle_fused(
     ``exec.krylov.GMRESProblem.run_resident``)."""
     n, k = data.shape
     assert cols.shape == (n, k) and b.shape == (n,) and x.shape == (n,)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = gather_interpret(interpret, "gmres_cycle_fused")
     out_shape = (
         jax.ShapeDtypeStruct((m + 1, n), b.dtype),
         jax.ShapeDtypeStruct((m + 1, m), b.dtype),

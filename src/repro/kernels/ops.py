@@ -1,9 +1,12 @@
 """Jit'd public wrappers for every Pallas kernel in this package.
 
-On TPU these dispatch the compiled Mosaic kernels; on any other backend
-(this CPU container) they run the same kernel bodies in interpret mode —
-the tests validate them there against the ``ref.py`` oracles. Model code
-and solvers call through these wrappers only.
+On a TPU these dispatch the compiled Mosaic kernels, and nothing falls
+back to interpret mode or a jnp reference there: a kernel that Mosaic
+cannot compile raises (the gather kernels ``spmv``, ``spmv_sell``, ``cg``,
+``bicgstab``, ``gmres_cycle`` — see ``spmv_ell.py``). On any other backend
+(the test suite's CPU) they run the same kernel bodies in interpret mode,
+which the tests validate against the ``ref.py`` oracles. Model code and
+solvers call through these wrappers only.
 """
 from __future__ import annotations
 
@@ -21,10 +24,10 @@ from repro.kernels import ssm_scan as _ssm
 from repro.kernels import decode_attn as _da
 
 
-@functools.partial(jax.jit, static_argnames=("spec", "steps"))
-def stencil_resident(x, *, spec: StencilSpec, steps: int):
+@functools.partial(jax.jit, static_argnames=("spec", "steps", "sub_rows"))
+def stencil_resident(x, *, spec: StencilSpec, steps: int, sub_rows: int = 128):
     """Small-domain PERKS stencil (whole domain VMEM-resident)."""
-    return _s2d.stencil_resident(x, spec, steps=steps)
+    return _s2d.stencil_resident(x, spec, steps=steps, sub_rows=sub_rows)
 
 
 @functools.partial(

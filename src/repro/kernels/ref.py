@@ -15,14 +15,31 @@ from repro.kernels.common import StencilSpec
 # -- stencils ---------------------------------------------------------------
 
 def stencil_step(x: jax.Array, spec: StencilSpec) -> jax.Array:
-    """One time step: interior updated, outermost ``radius`` cells frozen."""
-    return spec.apply(x)
+    """One time step: interior updated, outermost ``radius`` cells frozen.
+
+    Written without the kernels' ``StencilSpec.apply_rows``, so the tests
+    compare two implementations: the whole interior is one shifted-slice
+    sum, wrapped in the frozen border one axis at a time."""
+    r = spec.radius
+    acc = None
+    for off, w in zip(spec.offsets, spec.weights):
+        term = w * x[tuple(slice(r + d, n - r + d)
+                           for d, n in zip(off, x.shape))]
+        acc = term if acc is None else acc + term
+    out = acc.astype(x.dtype)
+    for ax in reversed(range(x.ndim)):
+        n = x.shape[ax]
+        inner = tuple(slice(r, m - r) for m in x.shape[:ax])
+        lo = x[inner + (slice(0, r),)]
+        hi = x[inner + (slice(n - r, n),)]
+        out = jnp.concatenate([lo, out, hi], axis=ax)
+    return out
 
 
 def stencil_run(x: jax.Array, spec: StencilSpec, steps: int) -> jax.Array:
     """``steps`` time steps via lax.scan (oracle for the PERKS kernels)."""
     def body(s, _):
-        return spec.apply(s), None
+        return stencil_step(s, spec), None
     y, _ = jax.lax.scan(body, x, None, length=steps)
     return y
 

@@ -14,8 +14,16 @@ systolic datapath (DESIGN.md §2). The TPU-idiomatic equivalent:
     this is the PERKS caching decision (vector > matrix, paper §III-B2):
     x is read K times per row (gather) while A is read once.
 
-The gather ``x[cols]`` lowers to a VMEM dynamic-gather on TPU (supported by
-Mosaic for 32-bit types); the oracle in ``ref.py`` is identical math.
+The gather ``x[cols]`` does NOT lower on a TPU: Mosaic compiles only
+gathers that stay inside one vreg ("Only 2D gather is supported" for
+``x[cols]``; ``take_along_axis`` over a vector wider than 128 lanes is
+refused too). So this kernel and every kernel that gathers ``x`` by
+column index (``spmv_sell``, ``cg_fused``, ``krylov_fused``) run only in
+interpret mode, off the TPU: ``gather_interpret`` refuses a Mosaic
+compile, and the planner offers no resident Krylov plan for a chip that
+compiles its kernels (``Chip.interpret`` unset). The loop tiers' SpMV is
+the XLA gather of ``ref.spmv_ell``, which the TPU runs. The oracle in
+``ref.py`` is identical math.
 """
 from __future__ import annotations
 
@@ -26,6 +34,21 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+def gather_interpret(interpret: Optional[bool], kernel: str) -> bool:
+    """Resolve a gather kernel's ``interpret`` flag (by default: off the
+    TPU), refusing a Mosaic compile (``interpret=False``) with the
+    reason."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    if not interpret:
+        raise NotImplementedError(
+            f"{kernel} gathers x by column index, which Mosaic cannot "
+            "compile for a TPU (only gathers within one vreg lower); on a "
+            "TPU plan a loop or distributed tier, whose SpMV is XLA's "
+            "gather")
+    return interpret
 
 
 def _spmv_kernel(data_ref, cols_ref, x_ref, y_ref):
@@ -51,8 +74,7 @@ def spmv_ell(
     """
     n_rows, k = data.shape
     assert cols.shape == (n_rows, k)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = gather_interpret(interpret, "spmv_ell")
     bm = min(block_rows, n_rows)
     # auto-pad the row dimension to a block multiple (zero rows: data 0,
     # col 0 -> y 0) and slice the result back, so arbitrary sizes work
@@ -107,21 +129,21 @@ def dense_to_ell(a: np.ndarray, k: Optional[int] = None):
 
 def poisson2d_ell(side: int, dtype=np.float32):
     """ELL form of the 2D 5-point Poisson matrix on a side x side grid —
-    the canonical SPD test operator (the paper's CG datasets are SPD)."""
+    the canonical SPD test operator (the paper's CG datasets are SPD).
+    Slot 0 holds the diagonal; the present neighbours follow in the order
+    up, down, left, right; absent ones leave zero slots at the end."""
     n = side * side
-    k = 5
-    data = np.zeros((n, k), dtype)
-    cols = np.zeros((n, k), np.int32)
-    for r in range(side):
-        for c in range(side):
-            i = r * side + c
-            slot = 0
-            data[i, slot] = 4.0
-            cols[i, slot] = i
-            slot += 1
-            for rr, cc in ((r - 1, c), (r + 1, c), (r, c - 1), (r, c + 1)):
-                if 0 <= rr < side and 0 <= cc < side:
-                    data[i, slot] = -1.0
-                    cols[i, slot] = rr * side + cc
-                    slot += 1
+    rows = np.arange(n)
+    r, c = np.divmod(rows, side)
+    data = np.zeros((n, 5), dtype)
+    cols = np.zeros((n, 5), np.int32)
+    data[:, 0] = 4.0
+    cols[:, 0] = rows
+    slot = np.ones(n, np.int64)
+    for dr, dc in ((-1, 0), (1, 0), (0, -1), (0, 1)):
+        rr, cc = r + dr, c + dc
+        ok = (rr >= 0) & (rr < side) & (cc >= 0) & (cc < side)
+        data[rows[ok], slot[ok]] = -1.0
+        cols[rows[ok], slot[ok]] = (rr * side + cc)[ok]
+        slot += ok
     return data, cols

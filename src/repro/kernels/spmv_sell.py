@@ -39,6 +39,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.spmv_ell import gather_interpret
+
 
 def _sell_kernel(off_ref, k_ref, data_ref, cols_ref, x_ref, y_ref,
                  dbuf, cbuf, sem, *, c: int, k_max: int):
@@ -82,8 +84,7 @@ def spmv_sell(
     """
     n_slices = slice_offsets.shape[0]
     assert slice_k.shape == (n_slices,)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = gather_interpret(interpret, "spmv_sell")
     # one extra window of zeros keeps the last slice's fixed-size read
     # in bounds (its tail is masked anyway)
     data = jnp.concatenate([data, jnp.zeros(c * k_max, data.dtype)])

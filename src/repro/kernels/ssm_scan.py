@@ -26,47 +26,86 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import VMEM_HEADROOM_BYTES, vmem_bytes
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, h_s):
+
+def _ssd_kernel(x_ref, dt_ref, a_ref, bt_ref, c_ref, d_ref, y_ref, h_s):
+    """One chunk, head by head. Head-major layout so that every product is
+    a 2-D matmul and every per-head access a leading-axis or sublane
+    index: x/y (H, C, P), dt (H, C), a (H, 1), d (H, P), b transposed
+    (N, C), c (C, N), state (H, N, P). No value is broadcast along both
+    tile axes at once, which Mosaic does not lower."""
     c_idx = pl.program_id(0)
 
     @pl.when(c_idx == 0)
     def _init():
         h_s[...] = jnp.zeros_like(h_s)
 
-    x = x_ref[...].astype(jnp.float32)      # (C, H, P)
-    dt = dt_ref[...].astype(jnp.float32)    # (C, H)
-    a = a_ref[...].astype(jnp.float32)      # (H,)
-    b = b_ref[...].astype(jnp.float32)      # (C, N)
-    c = c_ref[...].astype(jnp.float32)      # (C, N)
-    d = d_ref[...].astype(jnp.float32)      # (H,)
+    f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST
+    n_heads, ck, p = x_ref.shape
+    bt = bt_ref[...].astype(f32)            # (N, C)
+    c = c_ref[...].astype(f32)              # (C, N)
+    scores = jnp.dot(c, bt, precision=hi)   # (C, C)  c_i . b_j
+    row = jax.lax.broadcasted_iota(jnp.int32, (ck, ck), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (ck, ck), 1)
+    causal = row >= col                     # j <= i
+    lower = causal.astype(f32)
+    upper = (row <= col).astype(f32)
+    # cum[i] broadcast along a row: (lower * g) @ ones. Prefix sums are
+    # products with triangular masks (Mosaic lowers no cumsum) and every
+    # operand stays 2-D.
+    ones = jnp.ones((ck, max(ck, p)), f32)
 
-    g = dt * a[None, :]                     # (C, H) log-decay per step
-    cum = jnp.cumsum(g, axis=0)             # (C, H) inclusive
+    def head(h, carry):
+        dt = dt_ref[pl.ds(h, 1), :].astype(f32)            # (1, C)
+        g = dt * a_ref[pl.ds(h, 1), :].astype(f32)         # (1, C) log-decay
+        cum = jnp.dot(g, upper, precision=hi)              # (1, C) inclusive
+        cum_i = jnp.dot(lower * g, ones, precision=hi)     # (C, W) cum[i]
+        # intra-chunk (quadratic in C, on the MXU). Mask BEFORE exp: the
+        # upper triangle has cum[i]-cum[j] > 0, which overflows exp for
+        # long chunks; masking after would give inf * 0 = NaN.
+        li = jnp.where(causal, cum_i[:, :ck] - cum, -jnp.inf)
+        m = jnp.exp(li) * scores * dt                      # (i, j)
+        x = x_ref[h].astype(f32)                           # (C, P)
+        y = jnp.dot(m, x, precision=hi)
+        # cross-chunk from the resident state, then the skip connection
+        h_prev = h_s[h]                                    # (N, P)
+        y += jnp.exp(cum_i[:, :p]) * jnp.dot(c, h_prev, precision=hi)
+        y += d_ref[pl.ds(h, 1), :].astype(f32) * x
+        # state update (stays in VMEM)
+        w = jnp.exp(cum[:, ck - 1:] - cum) * dt            # (1, C)
+        upd = jnp.dot(bt * w, x, precision=hi)             # (N, P)
+        decay = jnp.exp(cum_i[ck - 1:, :p])                # (1, P) e^{cum[C-1]}
+        h_s[h] = decay * h_prev + upd
+        y_ref[h] = y.astype(y_ref.dtype)
+        return carry
 
-    # intra-chunk (quadratic in C, runs on the MXU). Mask BEFORE exp:
-    # the upper triangle has cum[i]-cum[j] > 0 which overflows exp for
-    # long chunks; masking after would give inf * 0 = NaN.
-    scores = c @ b.T                        # (C, C)  c_i . b_j
-    li = cum[:, None, :] - cum[None, :, :]  # (C, C, H) cum[i]-cum[j]
-    causal = jnp.tril(jnp.ones((x.shape[0], x.shape[0]), bool))
-    li = jnp.where(causal[:, :, None], li, -jnp.inf)
-    m = jnp.exp(li) * scores[:, :, None] * dt[None, :, :]  # (i,j,H)
-    y = jnp.einsum("ijh,jhp->ihp", m, x)
+    jax.lax.fori_loop(0, n_heads, head, 0)
 
-    # cross-chunk from the resident state
-    h_prev = h_s[...]                       # (H, N, P)
-    y += jnp.exp(cum)[:, :, None] * jnp.einsum("in,hnp->ihp", c, h_prev)
 
-    # skip connection
-    y += d[None, :, None] * x
+def chunk_compiles(t: int, chunk: int) -> bool:
+    """Whether Mosaic takes this chunk: the (H, chunk) and (N, chunk)
+    blocks put it on the lane axis, so it must fill whole 128-lane tiles
+    or be the whole sequence."""
+    return chunk == t or chunk % 128 == 0
 
-    # state update (stays in VMEM)
-    tail = jnp.exp(cum[-1][None, :] - cum)  # (C, H) e^{cum[C-1]-cum[j]}
-    upd = jnp.einsum("jh,jn,jhp->hnp", tail * dt, b, x)
-    h_s[...] = jnp.exp(cum[-1])[:, None, None] * h_prev + upd
 
-    y_ref[...] = y.astype(y_ref.dtype)
+def ssm_vmem_bytes(x, dt, a, b, c, d, *, chunk: int) -> int:
+    """The kernel's VMEM request (``vmem_limit_bytes``): double-buffered
+    blocks, the f32 state, one head's temporaries, headroom. Takes arrays
+    or ShapeDtypeStructs; the planner gates the resident tier on it."""
+    t, h, p = x.shape
+    n = b.shape[-1]
+    ck = min(chunk, t)
+    f32 = jnp.float32
+    blocks = [((h, ck, p), x.dtype), ((h, ck), dt.dtype), ((h, 1), a.dtype),
+              ((n, ck), b.dtype), ((ck, n), c.dtype), ((h, p), d.dtype),
+              ((h, ck, p), x.dtype)]
+    need = (2 * sum(vmem_bytes(s, dt_) for s, dt_ in blocks)
+            + vmem_bytes((h, n, p), f32)
+            + 12 * vmem_bytes((ck, max(ck, p)), f32))
+    return need + VMEM_HEADROOM_BYTES
 
 
 def ssm_scan(
@@ -90,21 +129,29 @@ def ssm_scan(
     assert t % ck == 0, "pad T to a multiple of chunk"
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if not interpret and not chunk_compiles(t, ck):
+        raise ValueError(f"on a TPU the SSD chunk must be a multiple of 128 "
+                         f"or the whole sequence (T={t}); got {ck}")
     grid = (t // ck,)
-    return pl.pallas_call(
+    vmem = ssm_vmem_bytes(x, dt, a, b, c, d, chunk=ck)
+    y = pl.pallas_call(
         _ssd_kernel,
         grid=grid,
-        out_shape=jax.ShapeDtypeStruct((t, h, p), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((h, t, p), x.dtype),
         in_specs=[
-            pl.BlockSpec((ck, h, p), lambda i: (i, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((ck, h), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((h,), lambda i: (0,), memory_space=pltpu.VMEM),
+            pl.BlockSpec((h, ck, p), lambda i: (0, i, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((h, ck), lambda i: (0, i), memory_space=pltpu.VMEM),
+            pl.BlockSpec((h, 1), lambda i: (0, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((n, ck), lambda i: (0, i), memory_space=pltpu.VMEM),
             pl.BlockSpec((ck, n), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((ck, n), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((h,), lambda i: (0,), memory_space=pltpu.VMEM),
+            pl.BlockSpec((h, p), lambda i: (0, 0), memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((ck, h, p), lambda i: (i, 0, 0),
+        out_specs=pl.BlockSpec((h, ck, p), lambda i: (0, i, 0),
                                memory_space=pltpu.VMEM),
         scratch_shapes=[pltpu.VMEM((h, n, p), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
+        name="ssm_scan",
         interpret=interpret,
-    )(x, dt, a, b, c, d)
+    )(x.transpose(1, 0, 2), dt.T, a.reshape(h, 1), b.T, c,
+      jnp.broadcast_to(d.reshape(h, 1), (h, p)))
+    return y.transpose(1, 0, 2)

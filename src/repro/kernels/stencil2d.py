@@ -46,6 +46,7 @@ axis, ``StencilSpec.apply_rows`` handles the rest):
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -53,18 +54,245 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import StencilSpec
+from repro.core.cache_policy import deep_scratch_rows
+from repro.kernels.common import VMEM_HEADROOM_BYTES, StencilSpec
+
+
+#: Elements per in-VMEM compute chunk. Mosaic unrolls every vector op
+#: over the vregs of its operand, so the chunk bounds both the program
+#: size and the kernel's VMEM temporaries (about one MiB of f32).
+COMPUTE_CHUNK_ELEMS = 1 << 18
+
+#: Live chunk-sized values per fused step (window, terms, sum, border
+#: strips, mask select), in units of one compute window.
+_TEMPS_PER_STEP = 6
+
+
+def row_align(shape, dtype) -> int:
+    """Leading-axis rows per memory tile: every VMEM slice and DMA window
+    along the leading axis must start and end on this granularity on a
+    TPU. A 2D domain's rows are the sublane axis (8 rows of 32-bit, 16 of
+    16-bit); the leading axis of a 3D domain is untiled."""
+    if len(shape) > 2:
+        return 1
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def halo_rows(spec: StencilSpec, fuse_steps: int, align: int) -> int:
+    """Halo rows one fused pass reads per side: ``radius*fuse_steps``
+    widened to the tile granularity."""
+    return -(-spec.radius * fuse_steps // align) * align
+
+
+def compute_rows(shape, sub_rows: int, align: int) -> int:
+    """Rows per in-VMEM compute chunk (``COMPUTE_CHUNK_ELEMS`` worth)."""
+    row_elems = math.prod(shape[1:])
+    rows = max(align, COMPUTE_CHUNK_ELEMS // row_elems // align * align)
+    return min(rows, sub_rows)
+
+
+#: Bytes per streamed block the planner aims for: large enough to keep
+#: each DMA long, small enough to leave VMEM to resident rows (the deep
+#: schedule keeps 2t+5 blocks alive, the shallow one three).
+BLOCK_BYTES = {"shallow": 4 << 20, "deep": 1 << 20}
+
+
+def default_sub_rows(shape, dtype, spec: StencilSpec, *, schedule: str,
+                     fuse_steps: int = 1) -> int:
+    """Streamed block rows for a planned kernel: ``BLOCK_BYTES`` worth,
+    tile-aligned, and no fewer than the halo the schedule needs."""
+    A = row_align(shape, dtype)
+    row_bytes = math.prod(shape[1:]) * jnp.dtype(dtype).itemsize
+    rows = max(A, BLOCK_BYTES[schedule] // row_bytes // A * A)
+    need = spec.radius * (fuse_steps if schedule == "shallow" else 1)
+    return max(rows, -(-need // A) * A)
+
+
+def _block_geometry(shape, dtype, spec, sub_rows, fuse_steps):
+    H = shape[0]
+    A = row_align(shape, dtype)
+    S = min(sub_rows, H)
+    h = halo_rows(spec, fuse_steps, A)
+    return A, S, h, compute_rows(shape, S, A)
+
+
+def _scratch_shapes(shape, dtype, spec, cached_rows, sub_rows, fuse_steps):
+    _, S, h, _ = _block_geometry(shape, dtype, spec, sub_rows, fuse_steps)
+    rest = tuple(shape[1:])
+    one = lambda n: (max(n, 1),) + rest  # zero-size scratch is not allowed
+    return [
+        pltpu.VMEM(one(cached_rows), dtype),   # dom: resident rows
+        pltpu.VMEM(one(h), dtype),             # edge: step-k rows [R, R+h)
+        pltpu.VMEM(one(h), dtype),             # carry: rows above a block
+        pltpu.VMEM(one(S + 2 * h), dtype),     # sub: block + both halos
+        pltpu.VMEM(one(S), dtype),             # wbuf: advanced block
+        pltpu.SemaphoreType.DMA,
+    ]
+
+
+def perks_vmem_bytes(shape, dtype, spec, *, cached_rows: int, sub_rows: int,
+                     fuse_steps: int) -> int:
+    """Whole VMEM footprint of ``stencil_perks`` for this geometry: its
+    scratch plus the compute chunk's live temporaries plus headroom. The
+    kernel requests exactly this as its scoped-VMEM limit, and the planner
+    admits a plan only when it fits the chip's limit."""
+    _, S, h, C = _block_geometry(shape, dtype, spec, sub_rows, fuse_steps)
+    row_bytes = math.prod(shape[1:]) * jnp.dtype(dtype).itemsize
+    scratch_rows = max(cached_rows, 1) + 2 * h + (S + 2 * h) + S
+    temp_rows = _TEMPS_PER_STEP * fuse_steps * (C + 2 * h)
+    return (scratch_rows + temp_rows) * row_bytes + VMEM_HEADROOM_BYTES
+
+
+def _aligned(i, A):
+    return i if isinstance(i, int) or A == 1 else pl.multiple_of(i, A)
+
+
+def _copy(src, dst, sem):
+    cp = pltpu.make_async_copy(src, dst, sem)
+    cp.start()
+    cp.wait()
+
+
+def _advance(spec, src, dst, n, g_base, *, H, ct, h, C, A, masked,
+             barrier=False):
+    """``dst`` rows [0, n) = ``ct`` steps of ``spec`` applied to ``src``
+    rows [0, n + 2h), whose row ``h`` is global row ``g_base``. Runs chunk
+    by chunk (``C`` output rows each). With ``masked``, rows inside the
+    global Dirichlet border, or outside the domain, keep their old values,
+    so rows that ``src`` holds no data for are never read by an updated
+    row; without it the caller guarantees the window lies inside the
+    domain. All slices of ``src``/``dst`` are tile-aligned when ``h``,
+    ``C`` and the offsets are.
+
+    The window keeps its length across the fused steps (its ``radius``
+    edge rows go stale, and the valid rows shrink inward). ``barrier``
+    (interpret mode only) ends each step at an optimization barrier:
+    XLA:CPU contracts multiply-adds into FMAs differently inside one
+    fusion than across fusions, and the barrier keeps every step rounding
+    exactly as the per-step loop does. Mosaic does not lower
+    ``optimization_barrier``, so the compiled kernel has none: the
+    bit-exact agreement the CPU tests check is not established on a TPU,
+    where results agree with the loop tiers to roundoff."""
+    r = spec.radius
+    ids = (1,) * (len(src.shape) - 1)
+
+    def chunk(off, rows):
+        w = src[pl.ds(_aligned(off, A), rows + 2 * h)]
+        L = w.shape[0]
+        g = g_base + off - h + r + jax.lax.broadcasted_iota(
+            jnp.int32, (L - 2 * r,) + ids, 0)   # global rows of w[r:L-r]
+        for s in range(ct):
+            upd = spec.apply_rows(w, r, L - r)
+            if masked:
+                upd = jnp.where((g < r) | (g >= H - r), w[r:L - r], upd)
+            if s + 1 < ct:
+                w = jnp.concatenate([w[:r], upd, w[L - r:]], axis=0)
+                if barrier:
+                    w = jax.lax.optimization_barrier(w)
+        dst[pl.ds(_aligned(off, A), rows)] = upd[h - r:h - r + rows]
+
+    full, tail = divmod(n, C)
+    if full == 1:
+        chunk(0, C)
+    elif full > 1:
+        def body(c, carry):
+            chunk(c * C, C)
+            return carry
+        jax.lax.fori_loop(0, full, body, 0)
+    if tail:
+        chunk(full * C, tail)
+
+
+class _Sweep:
+    """In-place blocked advance of a row range, shared by both kernels.
+
+    Each block of ``S`` rows is copied into ``sub`` with an ``h``-row halo
+    on each side, advanced into ``wbuf`` and copied back in place. The h
+    rows above a block were already overwritten at their source, so they
+    come from ``carry`` (saved from the previous block's window), from
+    ``dom`` (the first streamed block, read before the resident region
+    advances) or from nowhere (the domain's top, masked). Rows below the
+    resident region come from the ``edge`` stash of their step-k values.
+    Every window offset and length is a multiple of the tile rows, so
+    every slice and DMA is tile-aligned on a TPU. Blocks that need none of
+    the special cases run under one ``fori_loop``; the rest are unrolled.
+    """
+
+    def __init__(self, spec, H, R, S, h, C, A, e, *, dom, edge, carry, sub,
+                 wbuf, sem, barrier):
+        self.spec, self.H, self.R, self.S, self.h = spec, H, R, S, h
+        self.C, self.A, self.e, self.barrier = C, A, e, barrier
+        self.dom, self.edge, self.carry = dom, edge, carry
+        self.sub, self.wbuf, self.sem = sub, wbuf, sem
+
+    def block(self, ref, a, bn, top, n_body, n_edge, save_carry, ct,
+              masked):
+        """Advance rows [a, a+bn) of ``ref`` in place. ``n_body`` rows from
+        row ``a`` come from ``ref``, the next ``n_edge`` from ``edge``."""
+        h, A, R, sub = self.h, self.A, self.R, self.sub
+        if top == "carry":
+            sub[pl.ds(0, h)] = self.carry[...]
+        elif top == "dom":
+            n = min(h, R)
+            sub[pl.ds(h - n, n)] = self.dom[pl.ds(R - n, n)]
+        _copy(ref.at[pl.ds(_aligned(a, A), n_body)],
+              sub.at[pl.ds(h, n_body)], self.sem)
+        if n_edge:
+            sub[pl.ds(h + n_body, n_edge)] = self.edge[pl.ds(0, n_edge)]
+        if save_carry:
+            self.carry[...] = sub[pl.ds(_aligned(bn, A), h)]
+        _advance(self.spec, sub, self.wbuf, bn, a, H=self.H, ct=ct, h=h,
+                 C=self.C, A=A, masked=masked, barrier=self.barrier)
+        _copy(self.wbuf.at[pl.ds(0, bn)], ref.at[pl.ds(_aligned(a, A), bn)],
+              self.sem)
+
+    def region(self, ref, lo, hi, first_top, ct):
+        """Advance rows [lo, hi) of ``ref`` (the HBM domain, or ``dom``
+        with ``hi == R``) by ``ct`` steps."""
+        H, R, S, h = self.H, self.R, self.S, self.h
+        in_dom = ref is self.dom
+        lim = R + self.e if in_dom and hi < H else H
+        blocks = []
+        for a in range(lo, hi, S):
+            bn = min(S, hi - a)
+            win_hi = min(a + bn + h, lim)
+            n_body = min(win_hi, hi if in_dom else H) - a
+            blocks.append(dict(
+                a=a, bn=bn, top=first_top if a == lo else "carry",
+                n_body=n_body, n_edge=win_hi - a - n_body,
+                save_carry=a + bn < hi,
+                masked=not (a - h >= 0 and a + bn + h <= H)))
+        plain = [b["top"] == "carry" and b["bn"] == S and b["n_edge"] == 0
+                 and b["n_body"] == S + h and b["save_carry"]
+                 and not b["masked"] for b in blocks]
+        i = 0
+        while i < len(blocks):
+            j = i
+            while j < len(blocks) and plain[j]:
+                j += 1
+            if j - i >= 2:             # a run of uniform blocks: rolled
+                a0 = blocks[i]["a"]
+
+                def body(k, carry, a0=a0):
+                    self.block(ref, a0 + k * S, S, "carry", S + h, 0, True,
+                               ct, False)
+                    return carry
+                jax.lax.fori_loop(0, j - i, body, 0)
+                i = j
+            else:
+                self.block(ref, ct=ct, **blocks[i])
+                i += 1
 
 
 def _perks_kernel(
     x_ref,         # input ref (aliased to io_ref; unused — all I/O via io_ref)
     io_ref,        # full domain, HBM (ANY), aliased input/output
     dom,           # VMEM scratch: resident rows [0, R)
-    edge,          # VMEM scratch: step-k values of rows [R, R+r*t)
-    carry,         # VMEM scratch: step-k values of the r*t rows above the
-                   # current subtile (already overwritten in HBM)
-    sub,           # VMEM scratch: streaming read buffer
-    wbuf,          # VMEM scratch: streaming write buffer
+    edge,          # VMEM scratch: step-k values of rows [R, R+h)
+    carry,         # VMEM scratch: step-k values of the h rows above the
+                   # current block (already overwritten at their source)
+    sub,           # VMEM scratch: block window, h halo rows on each side
+    wbuf,          # VMEM scratch: the block advanced by one pass
     sem,           # DMA semaphore
     *,
     spec: StencilSpec,
@@ -72,132 +300,103 @@ def _perks_kernel(
     cached_rows: int,
     sub_rows: int,
     fuse_steps: int,
+    interpret: bool,
 ):
+    """Each pass advances ``ct`` fused steps over the streamed region, then
+    the resident one, block by block (``_Sweep``); block windows carry an
+    ``h``-row halo (``radius*fuse_steps`` rounded up to the tile) whose
+    inner steps are recomputed redundantly."""
     H = io_ref.shape[0]
-    r = spec.radius
     R = cached_rows
-    t = fuse_steps
-    starts = list(range(R, H, sub_rows))
-
-    def _copy(src, dst):
-        cp = pltpu.make_async_copy(src, dst, sem)
-        cp.start()
-        cp.wait()
-
-    def advance(w, lo, hi, ct):
-        """Advance window ``w`` (step-k values of domain rows [lo, hi)) by
-        ``ct`` time steps. Each application consumes ``r`` rows per side,
-        except sides clamped at the domain border, where the global frozen
-        rows ride along as Dirichlet boundary. Returns the final window and
-        its [lo', hi') row range (a superset of the rows the caller wants).
-        All bounds are static Python ints."""
-        for _ in range(ct):
-            new_lo = lo if lo == 0 else lo + r
-            new_hi = hi if hi == H else hi - r
-            a, b = max(new_lo, r), min(new_hi, H - r)
-            parts = []
-            if new_lo < a:                      # frozen global top rows
-                parts.append(w[new_lo - lo:a - lo])
-            if b > a:
-                parts.append(spec.apply_rows(w, a - lo, b - lo))
-            if b < new_hi:                      # frozen global bottom rows
-                parts.append(w[max(b, a) - lo:new_hi - lo])
-            w = parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
-            lo, hi = new_lo, new_hi
-        return w, lo, hi
+    A, S, h, C = _block_geometry(io_ref.shape, io_ref.dtype, spec, sub_rows,
+                                 fuse_steps)
+    e = min(h, H - R) if 0 < R < H else 0
+    sweep = _Sweep(spec, H, R, S, h, C, A, e, dom=dom, edge=edge,
+                   carry=carry, sub=sub, wbuf=wbuf, sem=sem,
+                   barrier=interpret)
 
     # Prologue: load the resident region into VMEM once.
     if R > 0:
-        _copy(io_ref.at[pl.ds(0, R)], dom)
+        _copy(io_ref.at[pl.ds(0, R)], dom, sem)
 
     def make_pass(ct):
-        """One HBM streaming pass advancing ``ct`` fused time steps (the
-        temporal block, DESIGN.md §4): every uncached row is read+written
-        once per pass instead of once per step; subtile windows widen to a
-        ``r*ct`` halo whose inner steps are redundantly recomputed."""
-        rt = r * ct
-        e = min(rt, H - R) if 0 < R < H else 0
-
-        def one_pass(_):
-            # (1) Preserve the resident region's bottom halo (rows
-            #     [R, R+rt)) at step-k values before streaming overwrites.
+        """One pass advancing ``ct`` fused time steps (the temporal block,
+        DESIGN.md §4): every uncached row is read and written once per pass
+        instead of once per step."""
+        def one_pass(*_):
+            # (1) Keep the resident region's bottom halo (rows [R, R+e)) at
+            #     step-k values before streaming overwrites it.
             if e > 0:
-                _copy(io_ref.at[pl.ds(R, e)], edge.at[pl.ds(0, e)])
-
-            # (2) Streamed subtiles, top to bottom, updated in place in HBM.
-            for j, start in enumerate(starts):
-                end = min(start + sub_rows, H)
-                u0 = max(start, r)          # first updated row
-                u1 = min(end, H - r)        # one past last updated row
-                if u1 <= u0:
-                    continue
-                read_lo = max(u0 - rt, 0)
-                read_hi = min(u1 + rt, H)
-                n_read = read_hi - read_lo
-
-                # Rows already overwritten in HBM come from VMEM:
-                #   subtile 0 borders the resident region -> from `dom`;
-                #   later subtiles border the previous subtile -> `carry`.
-                hbm_lo = max(read_lo, start)
-                n_top = hbm_lo - read_lo
-                if n_top > 0:
-                    if j == 0:
-                        sub[pl.ds(0, n_top)] = dom[pl.ds(R - n_top, n_top)]
-                    else:
-                        sub[pl.ds(0, n_top)] = carry[pl.ds(rt - n_top, n_top)]
-                _copy(io_ref.at[pl.ds(hbm_lo, read_hi - hbm_lo)],
-                      sub.at[pl.ds(n_top, read_hi - hbm_lo)])
-
-                x = sub[pl.ds(0, n_read)]
-                # Save step-k values of this subtile's bottom rt rows for
-                # the next subtile's top halo, before write-back clobbers
-                # them (sub_rows >= rt keeps them within this window).
-                if j + 1 < len(starts):
-                    carry[pl.ds(0, rt)] = x[end - rt - read_lo:end - read_lo]
-
-                w, wlo, _ = advance(x, read_lo, read_hi, ct)
-                wbuf[pl.ds(0, u1 - u0)] = w[u0 - wlo:u1 - wlo]
-                _copy(wbuf.at[pl.ds(0, u1 - u0)],
-                      io_ref.at[pl.ds(u0, u1 - u0)])
-
-            # (3) Resident region update — entirely VMEM, no HBM traffic
-            #     beyond the step-k edge stash; its bottom rt rows are
-            #     recomputed redundantly from the stash.
+                _copy(io_ref.at[pl.ds(R, e)], edge.at[pl.ds(0, e)], sem)
+            # (2) Streamed blocks, top to bottom, updated in place in HBM.
+            if R < H:
+                sweep.region(io_ref, R, H, "dom" if R > 0 else "none", ct)
+            # (3) Resident region, in place in VMEM: no HBM traffic beyond
+            #     the edge stash.
             if R > 0:
-                xc = dom[...] if e == 0 else jnp.concatenate(
-                    [dom[...], edge[pl.ds(0, e)]], axis=0)
-                w, wlo, _ = advance(xc, 0, R + e, ct)
-                if R >= H:
-                    dom[...] = w
-                else:
-                    dom[pl.ds(0, R)] = w[0:R]
+                sweep.region(dom, 0, R, "none", ct)
             return ()
 
         return one_pass
 
-    full, rem = divmod(steps, t)
+    full, rem = divmod(steps, fuse_steps)
     if full:
-        jax.lax.fori_loop(0, full, lambda i, c: make_pass(t)(c), ())
+        jax.lax.fori_loop(0, full, lambda i, c: make_pass(fuse_steps)(c), ())
     if rem:
         make_pass(rem)(())
 
     # Epilogue: the resident region's final state goes back to HBM once.
     if R > 0:
-        _copy(dom, io_ref.at[pl.ds(0, R)])
+        _copy(dom, io_ref.at[pl.ds(0, R)], sem)
 
 
-def _scratch_shapes(shape, dtype, spec, cached_rows, sub_rows, fuse_steps):
-    rt = spec.radius * fuse_steps
-    rest = shape[1:]
-    one = lambda n: (max(n, 1),) + rest  # zero-size scratch is not allowed
-    return [
-        pltpu.VMEM(one(cached_rows), dtype),
-        pltpu.VMEM(one(rt), dtype),
-        pltpu.VMEM(one(rt), dtype),
-        pltpu.VMEM(one(min(sub_rows, shape[0]) + 2 * rt), dtype),
-        pltpu.VMEM(one(min(sub_rows, shape[0])), dtype),
-        pltpu.SemaphoreType.DMA,
-    ]
+def _check_tiling(shape, dtype, cached_rows, sub_rows):
+    """Refuse, before Mosaic does, a geometry whose windows cannot be
+    tile-aligned on a TPU."""
+    A = row_align(shape, dtype)
+    bad = {k: v for k, v in (("rows", shape[0]), ("cached_rows", cached_rows),
+                             ("sub_rows", min(sub_rows, shape[0])))
+           if v % A}
+    if bad:
+        raise ValueError(
+            f"on a TPU every leading-axis window must be a multiple of "
+            f"{A} rows for {jnp.dtype(dtype).name}; got {bad}")
+
+
+def _interpret(interpret: Optional[bool]) -> bool:
+    return jax.default_backend() != "tpu" if interpret is None else interpret
+
+
+def _pallas(kernel, x, scratch, vmem, name, interpret: bool):
+    """The one ``pallas_call`` shape both stencil kernels share: the domain
+    stays in HBM, aliased in place, and all traffic is explicit DMA."""
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=scratch,
+        input_output_aliases={0: 0},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
+        name=name,
+        interpret=interpret,
+    )(x)
+
+
+def _perks_call(x, spec, steps, cached_rows, sub_rows, t, interpret):
+    interpret = _interpret(interpret)
+    if not interpret:
+        _check_tiling(x.shape, x.dtype, cached_rows, sub_rows)
+    kernel = functools.partial(
+        _perks_kernel, spec=spec, steps=steps, cached_rows=cached_rows,
+        sub_rows=sub_rows, fuse_steps=t, interpret=interpret,
+    )
+    return _pallas(
+        kernel, x,
+        _scratch_shapes(x.shape, x.dtype, spec, cached_rows, sub_rows, t),
+        perks_vmem_bytes(x.shape, x.dtype, spec, cached_rows=cached_rows,
+                         sub_rows=sub_rows, fuse_steps=t),
+        "stencil_perks", interpret)
 
 
 def stencil_perks(
@@ -219,9 +418,10 @@ def stencil_perks(
 
     ``fuse_steps=t`` is temporal blocking (DESIGN.md §4): each HBM
     streaming pass advances t time steps, so the uncached region round-
-    trips HBM ceil(steps/t) times instead of ``steps`` times. Subtile
-    windows widen to a ``radius*t`` halo of step-k values whose inner
-    steps are recomputed redundantly; ``sub_rows`` must cover that halo.
+    trips HBM ceil(steps/t) times instead of ``steps`` times. Block
+    windows widen to a ``radius*t`` halo (rounded up to the tile rows) of
+    step-k values whose inner steps are recomputed redundantly;
+    ``sub_rows`` must cover ``radius*t``.
     """
     H = x.shape[0]
     r = spec.radius
@@ -233,29 +433,36 @@ def stencil_perks(
     assert sub_rows >= r * min(t, steps), (
         "subtile must cover the next subtile's fused halo "
         f"(sub_rows >= radius*fuse_steps = {r * min(t, steps)})")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    return _perks_call(x, spec, steps, cached_rows, sub_rows, t, interpret)
 
-    kernel = functools.partial(
-        _perks_kernel, spec=spec, steps=steps,
-        cached_rows=cached_rows, sub_rows=sub_rows, fuse_steps=t,
-    )
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=_scratch_shapes(x.shape, x.dtype, spec, cached_rows,
-                                       sub_rows, t),
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(x)
+
+def _deep_geometry(shape, dtype, spec, cached_rows, sub_rows):
+    H = shape[0]
+    A = row_align(shape, dtype)
+    S = min(sub_rows, max(H - cached_rows, 1))
+    h = halo_rows(spec, 1, A)
+    if S < h:                   # off-TPU geometries only (_check_tiling)
+        h = spec.radius
+    return A, S, h, compute_rows(shape, S, A)
 
 
 def _deep_kernel(
     x_ref,         # input ref (aliased to io_ref; unused — all I/O via io_ref)
     io_ref,        # full domain, HBM (ANY), aliased input/output
-    *scratch,      # packed VMEM buffers + DMA semaphores (_deep_scratch_shapes)
+    dom,           # VMEM: resident rows [0, R)
+    b0,            # VMEM (3, S, ...): level-0 blocks, triple-buffered
+    lv,            # VMEM (2(t-1), S, ...): levels 1..t-1, ping-pong pairs
+    st,            # VMEM (t, h, ...): per-level edge stashes
+    dst,           # VMEM (h, ...): resident region's bottom rows
+    wb,            # VMEM (2, S, ...): write-back double buffer
+    win,           # VMEM (S+2h, ...): the window a stage advances
+    wbuf,          # VMEM (S, ...): resident sweep output
+    carry,         # VMEM (h, ...): resident sweep carry
+    edge,          # VMEM (h, ...): block 0's top rows, below the resident rows
+    si,            # DMA semaphores (3,): inbound, per level-0 slot
+    so,            # DMA semaphores (2,): outbound, per write-back slot
+    sem,           # DMA semaphore: local copies
+    *,
     spec: StencilSpec,
     steps: int,
     cached_rows: int,
@@ -264,174 +471,157 @@ def _deep_kernel(
 ):
     """Wavefront deep-temporal-blocking schedule (DESIGN.md §12).
 
-    The streamed region [R, H) is split into blocks of ``sub_rows`` rows.
-    One pass runs outer iterations j = 0..m+t-1; at iteration j, stage k
-    (k = 1..t, in increasing order) advances block ``i = j-k`` from time
-    level k-1 to level k, so block i finishes all t levels at iteration
-    i+t and is written back exactly once. Level-(k-1) inputs for stage k:
+    The streamed region [R, H) is split into m blocks of ``S`` rows. One
+    pass runs iterations j = 0..m+t-1; at iteration j, stage k (k = 1..t,
+    in increasing order) advances block ``i = j-k`` from time level k-1 to
+    level k, so block i finishes all t levels at iteration i+t and is
+    written back exactly once. Stage k's window is block i at level k-1
+    with an ``h``-row halo on each side (``h`` = radius rounded up to the
+    tile rows):
 
       * block i itself — the level-(k-1) ping-pong slot written last
         iteration by stage k-1 (parity (j-1)%2);
-      * the top ``r`` rows of block i+1 — written THIS iteration by stage
-        k-1 (parity j%2), which is why stages run in increasing k;
-      * the bottom ``r`` rows of block i-1 — stashed by stage k-1 this
-        iteration right before it overwrote that slot (st[k-1]), or read
-        from the still-intact slot when stage k-1 was inactive (drain).
+      * the top rows of block i+1 — written THIS iteration by stage k-1
+        (parity j%2), which is why stages run in increasing k;
+      * the bottom rows of block i-1 — stashed by stage k-1 this iteration
+        right before it overwrote that slot (st[k-1]), or read from the
+        still-intact slot when stage k-1 was inactive (drain).
 
-    Level 0 is TRIPLE buffered: at iteration j the DMA of block j+1 runs
-    while stage 1 computes on block j-1 and reads block j's top rows —
-    the compute-on-tile-i-while-DMA-ing-tile-i+1 overlap. The resident
-    region [0, R) advances one level per iteration (j -> j+1 at the end
-    of iteration j < t), coupling to block 0 through two r-row stashes,
-    so it needs no per-pass HBM traffic at all — unlike the shallow
-    kernel's step-k edge re-read. Per pass the uncached region moves
-    2*D_uncached bytes regardless of t (``gm_bytes_deep``).
+    Level 0 is TRIPLE buffered: at iteration j the DMA of block j+1 is
+    started before stage 1 computes on block j-1. The resident region
+    [0, R) advances one level per iteration (j -> j+1 at the end of
+    iteration j < t), coupled to block 0 through ``dst`` and ``edge``, so
+    it needs no per-pass HBM traffic at all. Per pass the uncached region
+    moves 2*D_uncached bytes regardless of t (``gm_bytes_deep``).
 
-    All indices are static Python ints: the wavefront is fully unrolled
-    inside one pass; passes repeat under ``lax.fori_loop``.
+    The iteration loop is one ``fori_loop``: buffer slots are indexed by
+    the iteration's parity and the wavefront's ramp-up and drain are
+    ``pl.when`` branches. Rows outside the domain (the last block's tail,
+    the halo above row 0) hold stale data, and the frozen-row mask keeps
+    every row they could reach at its old value.
     """
     H = io_ref.shape[0]
-    r = spec.radius
     R = cached_rows
     t = fuse_steps
-    S = min(sub_rows, max(H - R, 1))
-    starts = list(range(R, H, S))
-    ends = [min(s + S, H) for s in starts]
-    m = len(starts)
+    A, S, h, C = _deep_geometry(io_ref.shape, io_ref.dtype, spec, R,
+                                sub_rows)
+    m = -(-(H - R) // S)
+    last = (H - R) - (m - 1) * S
+    e = min(h, H - R)
+    nd = min(h, R)
+    sweep = _Sweep(spec, H, R, S, h, C, A, e, dom=dom, edge=edge,
+                   carry=carry, sub=win, wbuf=wbuf, sem=sem, barrier=False)
 
-    # unpack the packed scratch list (layout: _deep_scratch_shapes)
-    dom = scratch[0]
-    b0 = scratch[1:4]                        # level-0 triple buffer
-    lv_flat = scratch[4:4 + 2 * (t - 1)]     # levels 1..t-1, ping-pong pairs
-    st = scratch[2 * t + 2:3 * t + 2]        # per-level r-row edge stashes
-    dst = scratch[3 * t + 2]                 # resident region's bottom-r stash
-    wb = scratch[3 * t + 3:3 * t + 5]        # write-back double buffer
-    si = scratch[3 * t + 5:3 * t + 8]        # inbound DMA semaphores (per slot)
-    so = scratch[3 * t + 8:3 * t + 10]       # outbound DMA semaphores
+    def sized(i, fn):
+        """``fn(n)`` with block i's row count: ``last`` for the final block."""
+        if last == S:
+            fn(S)
+            return
+        if isinstance(i, int):
+            fn(last if i == m - 1 else S)
+            return
+        pl.when(i != m - 1)(lambda: fn(S))
+        pl.when(i == m - 1)(lambda: fn(last))
 
-    def lvbuf(k, p):
-        return lv_flat[2 * (k - 1) + p]
+    def dma_in(i, n):
+        return pltpu.make_async_copy(
+            io_ref.at[pl.ds(_aligned(R + i * S, A), n)],
+            b0.at[i % 3].at[pl.ds(0, n)], si.at[i % 3])
 
-    def _copy(src, dst_ref, sem):
-        cp = pltpu.make_async_copy(src, dst_ref, sem)
-        cp.start()
-        cp.wait()
+    def dma_out(i, p, n):
+        return pltpu.make_async_copy(
+            wb.at[p].at[pl.ds(0, n)],
+            io_ref.at[pl.ds(_aligned(R + i * S, A), n)], so.at[p])
 
-    def _advance_block(w, a0, s, e):
-        """One time step applied to window ``w`` (covering rows [a0, ...)),
-        returning the new values of rows [s, e); rows inside the global
-        Dirichlet border are copied through unchanged. Static bounds."""
-        u0, u1 = max(s, r), min(e, H - r)
-        if u1 <= u0:
-            return w[s - a0:e - a0]
-        parts = []
-        if u0 > s:
-            parts.append(w[s - a0:u0 - a0])
-        parts.append(spec.apply_rows(w, u0 - a0, u1 - a0))
-        if u1 < e:
-            parts.append(w[u1 - a0:e - a0])
-        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
+    def level(k, p):
+        return lv.at[2 * (k - 1) + p]
+
+    def stage(j, k, ct):
+        i = j - k
+        if k == 1:
+            own, below, prev = b0.at[(j - 1) % 3], b0.at[j % 3], \
+                b0.at[(j + 1) % 3]
+            prev_active = j + 1 < m       # block i-1's slot took a DMA
+        else:
+            own, below = level(k - 1, (j - 1) % 2), level(k - 1, j % 2)
+            prev = below
+            prev_active = i + 1 < m       # stage k-1 ran this iteration
+
+        @pl.when((i >= 0) & (i < m))
+        def _():
+            if R > 0:
+                @pl.when(i == 0)
+                def _():
+                    win[pl.ds(h - nd, nd)] = dst[pl.ds(h - nd, nd)]
+
+            @pl.when((i > 0) & prev_active)
+            def _():
+                win[pl.ds(0, h)] = st[k - 1]
+
+            @pl.when((i > 0) & jnp.logical_not(prev_active))
+            def _():
+                win[pl.ds(0, h)] = prev[pl.ds(S - h, h)]
+
+            _copy(own.at[pl.ds(0, S)], win.at[pl.ds(h, S)], sem)
+            win[pl.ds(h + S, h)] = below[pl.ds(0, h)]
+            if k == ct:
+                out = wb.at[j % 2]
+
+                @pl.when(i >= 2)
+                def _():
+                    dma_out(i - 2, j % 2, S).wait()
+            else:
+                out = level(k, j % 2)
+
+                # stage k+1 reads block i-2's bottom rows from this slot
+                # this iteration: stash them before they are overwritten
+                @pl.when(i >= 2)
+                def _():
+                    st[k] = out[pl.ds(S - h, h)]
+            _advance(spec, win, out, S, R + i * S, H=H, ct=1, h=h, C=C,
+                     A=A, masked=True)
+            if k == ct:
+                sized(i, lambda n: dma_out(i, j % 2, n).start())
+
+    def resident_level(j):
+        """Advance the resident region from level j to j+1, its bottom
+        halo being block 0's top rows at level j."""
+        dst[pl.ds(h - nd, nd)] = dom[pl.ds(R - nd, nd)]
+        pl.when(j == 0)(lambda: edge.__setitem__(
+            pl.ds(0, e), b0.at[0][pl.ds(0, e)]))
+        pl.when(j > 0)(lambda: edge.__setitem__(
+            pl.ds(0, e), lv.at[2 * (j - 1) + j % 2][pl.ds(0, e)]))
+        sweep.region(dom, 0, R, "none", 1)
 
     # Prologue: load the resident region into VMEM once.
     if R > 0:
-        _copy(io_ref.at[pl.ds(0, R)], dom, si[0])
+        _copy(io_ref.at[pl.ds(0, R)], dom, sem)
 
     def make_wave(ct):
         """One wavefront pass advancing ``ct`` time steps."""
 
-        def wave(_):
-            if m == 0:  # fully resident: pure VMEM sweep, no streaming
-                for _k in range(ct):
-                    dom[...] = _advance_block(dom[...], 0, 0, H)
-                return ()
+        def iteration(j, c):
+            # The DMA below overwrites slot (j+1)%3, which still holds
+            # block j-2 — stash its bottom rows if stage 1 reads them this
+            # iteration (its block-(j-1) above-halo).
+            @pl.when((j + 1 < m) & (j >= 2))
+            def _():
+                st[0] = b0.at[(j + 1) % 3][pl.ds(S - h, h)]
 
-            in_pending = {}
-            out_pending = {}
+            pl.when(j + 1 < m)(lambda: sized(
+                j + 1, lambda n: dma_in(j + 1, n).start()))
+            pl.when(j < m)(lambda: sized(j, lambda n: dma_in(j, n).wait()))
+            for k in range(1, ct + 1):
+                stage(j, k, ct)
+            if R > 0:
+                pl.when(j < ct)(lambda: resident_level(j))
+            return c
 
-            def start_in(i):
-                bn = ends[i] - starts[i]
-                cp = pltpu.make_async_copy(
-                    io_ref.at[pl.ds(starts[i], bn)],
-                    b0[i % 3].at[pl.ds(0, bn)], si[i % 3])
-                cp.start()
-                in_pending[i % 3] = cp
-
-            start_in(0)  # warm-up: block 0 in flight before iteration 0
-            for j in range(m + ct):
-                dma_next = j + 1 < m
-                # The DMA below overwrites slot (j+1)%3, which still holds
-                # block j-2 — stash its bottom r rows if stage 1 reads
-                # them this iteration (its block-(j-1) above-halo).
-                if dma_next and 1 <= j - 1 < m:
-                    st[0][...] = b0[(j + 1) % 3][pl.ds(S - r, r)]
-                if dma_next:
-                    start_in(j + 1)
-                if j < m:
-                    in_pending.pop(j % 3).wait()
-
-                for k in range(1, ct + 1):
-                    i = j - k
-                    if not (0 <= i < m):
-                        continue
-                    s, e = starts[i], ends[i]
-                    bn = e - s
-                    a0 = max(s - r, 0)
-                    n_below = min(e + r, H) - e
-                    if k == 1:
-                        own = b0[(j - 1) % 3]
-                        below = b0[j % 3]
-                        prev_active = dma_next
-                        prev_buf = b0[(j + 1) % 3]
-                    else:
-                        own = lvbuf(k - 1, (j - 1) % 2)
-                        below = lvbuf(k - 1, j % 2)
-                        prev_active = 0 <= j - (k - 1) < m
-                        prev_buf = lvbuf(k - 1, j % 2)
-                    parts = []
-                    if s > a0:
-                        if i == 0:
-                            parts.append(dst[...])       # dom at level k-1
-                        elif prev_active:
-                            parts.append(st[k - 1][...])  # stashed this iter
-                        else:            # drain: slot never overwritten
-                            parts.append(prev_buf[pl.ds(S - r, r)])
-                    parts.append(own[pl.ds(0, bn)])
-                    if n_below:
-                        parts.append(below[pl.ds(0, n_below)])
-                    w = (parts[0] if len(parts) == 1
-                         else jnp.concatenate(parts, 0))
-                    out = _advance_block(w, a0, s, e)
-                    if k == ct:
-                        # final level: double-buffered write-back
-                        old = out_pending.pop(j % 2, None)
-                        if old is not None:
-                            old.wait()
-                        wb[j % 2][pl.ds(0, bn)] = out
-                        cp = pltpu.make_async_copy(
-                            wb[j % 2].at[pl.ds(0, bn)],
-                            io_ref.at[pl.ds(s, bn)], so[j % 2])
-                        cp.start()
-                        out_pending[j % 2] = cp
-                    else:
-                        # stash the slot's old bottom rows (block i-2 at
-                        # level k) if stage k+1 reads them this iteration
-                        if 1 <= j - k - 1 < m:
-                            st[k][...] = lvbuf(k, j % 2)[pl.ds(S - r, r)]
-                        lvbuf(k, j % 2)[pl.ds(0, bn)] = out
-
-                # Resident region: advance level j -> j+1 at the end of
-                # iteration j, fed by block 0's top rows at level j
-                # (computed this iteration); stash its own bottom rows
-                # first — stage j+1 consumes them next iteration.
-                if R > 0 and j < ct:
-                    dst[...] = dom[pl.ds(R - r, r)]
-                    nb = min(r, H - R)
-                    top = b0[0] if j == 0 else lvbuf(j, j % 2)
-                    w = jnp.concatenate([dom[...], top[pl.ds(0, nb)]], 0)
-                    dom[...] = _advance_block(w, 0, 0, R)
-
-            for cp in out_pending.values():
-                cp.wait()
+        def wave(*_):
+            sized(0, lambda n: dma_in(0, n).start())
+            jax.lax.fori_loop(0, m + ct, iteration, 0)
+            for i in range(max(m - 2, 0), m):   # the last two write-backs
+                sized(i, lambda n: dma_out(i, (i + ct) % 2, n).wait())
             return ()
 
         return wave
@@ -444,25 +634,45 @@ def _deep_kernel(
 
     # Epilogue: the resident region's final state goes back to HBM once.
     if R > 0:
-        _copy(dom, io_ref.at[pl.ds(0, R)], si[0])
+        _copy(dom, io_ref.at[pl.ds(0, R)], sem)
 
 
 def _deep_scratch_shapes(shape, dtype, spec, cached_rows, sub_rows,
                          fuse_steps):
-    r = spec.radius
+    _, S, h, _ = _deep_geometry(shape, dtype, spec, cached_rows, sub_rows)
     t = fuse_steps
     rest = tuple(shape[1:])
-    S = min(sub_rows, max(shape[0] - cached_rows, 1))
     one = lambda n: (max(n, 1),) + rest  # zero-size scratch is not allowed
-    return (
-        [pltpu.VMEM(one(cached_rows), dtype)]            # dom
-        + [pltpu.VMEM(one(S), dtype)] * 3                # level-0 triple buf
-        + [pltpu.VMEM(one(S), dtype)] * (2 * (t - 1))    # level ping-pongs
-        + [pltpu.VMEM(one(r), dtype)] * t                # per-level stashes
-        + [pltpu.VMEM(one(r), dtype)]                    # dom stash
-        + [pltpu.VMEM(one(S), dtype)] * 2                # write-back bufs
-        + [pltpu.SemaphoreType.DMA] * 5                  # 3 in + 2 out
-    )
+    return [
+        pltpu.VMEM(one(cached_rows), dtype),              # dom
+        pltpu.VMEM((3,) + one(S), dtype),                 # b0
+        pltpu.VMEM((max(2 * (t - 1), 1),) + one(S), dtype),  # lv
+        pltpu.VMEM((t,) + one(h), dtype),                 # st
+        pltpu.VMEM(one(h), dtype),                        # dst
+        pltpu.VMEM((2,) + one(S), dtype),                 # wb
+        pltpu.VMEM(one(S + 2 * h), dtype),                # win
+        pltpu.VMEM(one(S), dtype),                        # wbuf
+        pltpu.VMEM(one(h), dtype),                        # carry
+        pltpu.VMEM(one(h), dtype),                        # edge
+        pltpu.SemaphoreType.DMA((3,)),
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.SemaphoreType.DMA,
+    ]
+
+
+def deep_vmem_bytes(shape, dtype, spec, *, cached_rows: int, sub_rows: int,
+                    fuse_steps: int) -> int:
+    """Whole VMEM footprint of ``stencil_perks_deep`` (scratch, one
+    stage's live temporaries, headroom): its scoped-VMEM request, and the
+    figure the planner admits a deep plan by."""
+    if cached_rows >= shape[0]:
+        return perks_vmem_bytes(shape, dtype, spec, cached_rows=cached_rows,
+                                sub_rows=sub_rows, fuse_steps=fuse_steps)
+    _, S, h, C = _deep_geometry(shape, dtype, spec, cached_rows, sub_rows)
+    row_bytes = math.prod(shape[1:]) * jnp.dtype(dtype).itemsize
+    rows = (max(cached_rows, 1) + deep_scratch_rows(S, h, fuse_steps)
+            + _TEMPS_PER_STEP * (C + 2 * h))
+    return rows * row_bytes + VMEM_HEADROOM_BYTES
 
 
 def stencil_perks_deep(
@@ -478,12 +688,11 @@ def stencil_perks_deep(
     """Deep temporal blocking: ``fuse_steps=t`` time steps per HBM pass
     with NO redundant recompute (arXiv:2306.03336; DESIGN.md §12).
 
-    Where ``stencil_perks`` widens every subtile read window to an
-    ``r*t`` halo of step-k values and redundantly recomputes the inner
-    steps (cost linear in t, useful depth ~2–4), the wavefront schedule
-    keeps ``t`` time levels of block edges alive in VMEM ping-pong
-    buffers so each streamed row is read once and written once per pass
-    at ANY depth:
+    Where ``stencil_perks`` widens every block window to an ``r*t`` halo
+    of step-k values and redundantly recomputes the inner steps (cost
+    linear in t, useful depth ~2–4), the wavefront schedule keeps ``t``
+    time levels of block edges alive in VMEM ping-pong buffers so each
+    streamed row is read once and written once per pass at ANY depth:
 
         A_gm = ceil(N/t) * 2*D_uncached + 2*D_cached
 
@@ -491,6 +700,8 @@ def stencil_perks_deep(
     in t, vs. the shallow kernel's per-pass ``2*r*t`` overlap re-read.
     The price is scratch: ``deep_scratch_rows`` grows linearly in t, so
     depth trades against resident rows under the planner's VMEM budget.
+    A fully resident domain has nothing to stream and runs the shallow
+    kernel's in-VMEM sweep.
 
     Validity needs only ``sub_rows >= radius`` (one level's halo), NOT
     the shallow ``radius*fuse_steps`` bound — that is what unlocks
@@ -507,34 +718,22 @@ def stencil_perks_deep(
     assert sub_rows >= r, (
         "deep schedule needs one level's halo per block "
         f"(sub_rows >= radius = {r}, got {sub_rows})")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-
+    if cached_rows >= H:
+        return _perks_call(x, spec, steps, H, sub_rows, t, interpret)
+    interpret = _interpret(interpret)
+    if not interpret:
+        _check_tiling(x.shape, x.dtype, cached_rows, sub_rows)
     kernel = functools.partial(
         _deep_kernel, spec=spec, steps=steps,
         cached_rows=cached_rows, sub_rows=sub_rows, fuse_steps=t,
     )
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=_deep_scratch_shapes(x.shape, x.dtype, spec,
-                                            cached_rows, sub_rows, t),
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(x)
-
-
-def _resident_kernel(x_ref, out_ref, dom, *, spec, steps):
-    dom[...] = x_ref[...]
-
-    def body(t, _):
-        dom[...] = spec.apply(dom[...])
-        return ()
-
-    jax.lax.fori_loop(0, steps, body, ())
-    out_ref[...] = dom[...]
+    return _pallas(
+        kernel, x,
+        _deep_scratch_shapes(x.shape, x.dtype, spec, cached_rows, sub_rows,
+                             t),
+        deep_vmem_bytes(x.shape, x.dtype, spec, cached_rows=cached_rows,
+                        sub_rows=sub_rows, fuse_steps=t),
+        "stencil_perks_deep", interpret)
 
 
 def stencil_resident(
@@ -542,25 +741,17 @@ def stencil_resident(
     spec: StencilSpec,
     *,
     steps: int,
+    sub_rows: int = 128,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Small-domain PERKS: the whole domain lives in VMEM for all steps.
 
     HBM traffic is exactly one domain load + one domain store total,
-    independent of ``steps`` (Eq. 5 with D_uncached = 0).
+    independent of ``steps`` (Eq. 5 with D_uncached = 0). This is
+    ``stencil_perks`` with every row cached.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return pl.pallas_call(
-        functools.partial(_resident_kernel, spec=spec, steps=steps),
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        in_specs=[pl.BlockSpec(x.shape, lambda *_: (0,) * x.ndim,
-                               memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(x.shape, lambda *_: (0,) * x.ndim,
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[pltpu.VMEM(x.shape, x.dtype)],
-        interpret=interpret,
-    )(x)
+    return stencil_perks(x, spec, steps=steps, cached_rows=x.shape[0],
+                         sub_rows=sub_rows, interpret=interpret)
 
 
 def stencil_baseline_step(

@@ -162,10 +162,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
         t_compile = time.time() - t0
 
         ma = compiled.memory_analysis()
-        ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):   # jax<=0.4.x wraps the dict in a list
-            ca = ca[0] if ca else {}
-        ca = dict(ca)
+        ca = dict(compiled.cost_analysis() or {})
         hlo = compiled.as_text()
         # cost_analysis() counts while bodies once; use the trip-count-
         # corrected HLO accounting instead (see core/hlo_costs.py):

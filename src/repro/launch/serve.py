@@ -12,6 +12,8 @@ import jax
 import numpy as np
 
 from repro.configs.registry import get_config, get_smoke_config
+from repro.core.compile_cache import enable_compile_cache
+from repro.core.hardware import attached_chip
 from repro.models.lm import Model
 from repro.runtime.server import Engine, Request, ServeConfig
 
@@ -26,7 +28,9 @@ def main():
     ap.add_argument("--host-loop", action="store_true",
                     help="baseline per-token dispatch instead of PERKS")
     args = ap.parse_args()
+    enable_compile_cache()
 
+    chip = attached_chip()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = Model(cfg)
     params = model.init(jax.random.key(0))
@@ -40,7 +44,7 @@ def main():
                                 dtype=np.int32),
             max_new_tokens=args.new_tokens))
     toks, stats = eng.run_batch()
-    print("generated:", toks.shape)
+    print("chip:", chip.name, "generated:", toks.shape)
     for k, v in stats.items():
         print(f"  {k}: {v}")
 

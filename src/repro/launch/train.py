@@ -12,6 +12,7 @@ import argparse
 
 
 from repro.configs.registry import get_config, get_smoke_config
+from repro.core.compile_cache import enable_compile_cache
 from repro.data.pipeline import DataConfig
 from repro.dist import sharding as shd
 from repro.launch.mesh import make_production_mesh
@@ -35,6 +36,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--production-mesh", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = Model(cfg)

@@ -138,6 +138,7 @@ class Engine:
         logits, cache = self._prefill(
             self.params, {"tokens": jnp.asarray(prompts)}, total)
         first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        first.block_until_ready()
         t_prefill = time.time() - t0
 
         t0 = time.time()

@@ -79,7 +79,7 @@ class ServiceConfig:
 
     max_batch: int = 8
     pad_to_max: bool = True
-    chip: Any = "tpu_v5e"
+    chip: Any = None             # None: the attached device's chip
     autotune_top_k: int = 0
 
 
@@ -376,7 +376,7 @@ class AsyncConfig:
     max_queue: int = 1024
     overload: str = "reject"            # "reject" | "shed"
     sla_queued_s: Optional[float] = None
-    chip: Any = "tpu_v5e"
+    chip: Any = None             # None: the attached device's chip
 
     def __post_init__(self):
         if self.max_batch < 1:

@@ -48,6 +48,8 @@ def run_multi_device(code: str, n_dev: int = 8, timeout: int = 360) -> dict:
     distributed tests so the main process keeps its single real device.
     """
     env = dict(os.environ)
+    # the child wants fake CPU devices, never a chip the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
     env["PYTHONPATH"] = str(REPO / "src")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],

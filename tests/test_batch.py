@@ -274,7 +274,9 @@ def test_plan_batch_field_round_trip_and_validation():
 
 def test_planner_per_instance_cache_shrinks_with_batch():
     """VMEM/B per instance: larger batches never cache MORE rows per
-    instance, and eventually demote the resident tier's residency."""
+    instance, and eventually demote the resident tier's residency. Once
+    not even the kernel's streaming buffers fit an instance's share, the
+    resident candidate is gone (counted as zero rows)."""
     spec = get_spec("2d9pt")
     problem = StencilProblem(
         jax.ShapeDtypeStruct((4096, 2048), jnp.float32), spec, 100)
@@ -282,11 +284,12 @@ def test_planner_per_instance_cache_shrinks_with_batch():
     for b in (1, 4, 16, 64, 256):
         cands = plan_candidates(problem, batch=b)
         assert all(c.batch == b for c in cands)
-        res = next(c for c in cands
-                   if c.tier == "resident" and c.fuse_steps == 1)
+        res = next((c for c in cands
+                    if c.tier == "resident" and c.fuse_steps == 1), None)
+        rows = 0 if res is None else res.cached_rows
         if prev is not None:
-            assert res.cached_rows <= prev, (b, res)
-        prev = res.cached_rows
+            assert rows <= prev, (b, res)
+        prev = rows
     assert prev == 0    # the sweep must reach full demotion
 
 

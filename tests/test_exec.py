@@ -413,3 +413,25 @@ def test_legacy_shim_warns_exactly_once(module, entry):
                  and entry in str(x.message)]
         assert again == [], [str(x.message) for x in w]
     reset_warnings()
+
+
+@pytest.mark.parametrize("platform,kind,want", [
+    ("tpu", "TPU v5 lite", "tpu_v5e"),
+    ("tpu", "TPU v99", None),          # unknown kind: an error
+    ("gpu", "NVIDIA A100", None),      # no kernels for a GPU: an error
+    ("cpu", "cpu", "tpu_v5e"),         # tests plan for the v5e, interpreted
+])
+def test_attached_chip_resolves_device_kind(platform, kind, want,
+                                            monkeypatch):
+    from types import SimpleNamespace
+    from repro.core.hardware import attached_chip
+    dev = SimpleNamespace(platform=platform, device_kind=kind)
+    monkeypatch.setattr(jax, "devices", lambda *a: [dev])
+    if want is None:
+        with pytest.raises(ValueError, match=kind if platform == "tpu"
+                           else repr(platform)):
+            attached_chip()
+    else:
+        chip = attached_chip()
+        assert chip.name == want
+        assert chip.interpret == (platform == "cpu")

@@ -7,7 +7,7 @@ compute bit-identical results (DESIGN.md §2). This asserts it for every
   * host loop == device loop == chunked loop: exactly equal (same step
     function, only the dispatch structure differs);
   * RESIDENT (fully VMEM-resident kernel): exactly equal — the kernel body
-    applies the identical ``spec.apply`` graph;
+    applies the same shifted-slice sum as the oracle;
   * RESIDENT with partial caching (the streamed PERKS kernel): equal to
     <= 1 ulp. XLA is free to contract mul+add into FMA differently for the
     subtiled slices, so bit-equality is not guaranteed there by any

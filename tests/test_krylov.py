@@ -409,7 +409,7 @@ def _check_sstep_matches_cg(iters, s, n=128, seed=0, rel=1e-9):
     the monomial-basis conditioning taken out of the picture the two
     must agree to roundoff — dividing cadences, non-dividing tails and
     s=1 (which degenerates to per-iteration CG) alike."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         A = _random_spd_dense(n, seed=seed)
         data = jnp.asarray(A, jnp.float64)
         cols = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), (n, n))
@@ -543,6 +543,9 @@ def test_compensated_vdot_tracks_f64():
     scale = abs(exact) + 1e-12
     assert abs(comp - exact) / scale <= abs(naive - exact) / scale + 1e-9
     assert abs(comp - exact) / scale < 1e-6
+    with jax.enable_x64(True):
+        comp64 = float(compensated_vdot(jnp.asarray(a), jnp.asarray(c)))
+    assert abs(comp64 - exact) / scale < 1e-6
 
 
 @pytest.mark.parametrize("kind", ["cg", "bicgstab", "gmres"])
