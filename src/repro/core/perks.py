@@ -33,6 +33,12 @@ On TPU/JAX this maps to three execution tiers (see DESIGN.md §2):
 All tiers compute bit-identical results for the same step function (the
 barrier semantics of the host loop are preserved: step k+1 only ever sees
 completed step-k output), which the test-suite asserts.
+
+The loops carry the program's spans into the profiler trace (DESIGN.md
+§11): every dispatch of a runner runs under ``repro.compile`` (its first)
+or ``repro.chunk`` (the rest), and every host sync under
+``repro.barrier``. Given a metrics registry, they count the steps, host
+syncs and barriers that ran.
 """
 from __future__ import annotations
 
@@ -41,6 +47,8 @@ import enum
 from typing import Any, Callable, Optional
 
 import jax
+
+from repro import obs
 
 
 class Execution(enum.Enum):
@@ -98,12 +106,65 @@ def _own(state):
         lambda a: a.copy() if isinstance(a, jax.Array) else a, state)
 
 
+class _Observed:
+    """One loop's spans, and its counters where a registry is given.
+
+    A dispatch runs under ``repro.compile`` if it is its runner's first
+    (trace, lower, compile or cache load, then enqueue) and under
+    ``repro.chunk`` otherwise, with stat ``steps``; a host sync (the
+    read-back and decision of ``on_sync`` / ``on_barrier``) runs under
+    ``repro.barrier``, with stat ``steps_done``. The counters are labelled
+    with the loop's tier. A host-loop barrier is a dispatch; a device-loop
+    barrier is a step, since the loop-carried dependency is the
+    device-wide barrier.
+    """
+
+    def __init__(self, name: str, execution: Execution, metrics=None):
+        self.name = name
+        self.track = f"tier:{execution.value}"
+        self.barrier_per_step = execution is not Execution.HOST_LOOP
+        self.counted = metrics is not None
+        if self.counted:
+            tier = execution.value
+            self.steps = metrics.counter("executor_steps_total", tier=tier)
+            self.syncs = metrics.counter("executor_host_syncs_total",
+                                         tier=tier)
+            self.barriers = metrics.counter("executor_barriers_total",
+                                            tier=tier)
+
+    def runner(self, jitted, steps: int):
+        """``jitted`` (``steps`` fused steps a call), dispatched under its
+        span and counted."""
+        first = True
+
+        def dispatch(state):
+            nonlocal first
+            cat, first = ("compile" if first else "chunk"), False
+            with obs.get_tracer().span(self.name, cat=cat, track=self.track,
+                                       steps=steps):
+                state = jitted(state)
+            if self.counted:
+                self.steps.inc(steps)
+                self.barriers.inc(steps if self.barrier_per_step else 1)
+            return state
+
+        return dispatch
+
+    def host_sync(self, done: int):
+        """The span of one host sync after ``done`` steps; counts it."""
+        if self.counted:
+            self.syncs.inc()
+        return obs.get_tracer().span(self.name, cat="barrier",
+                                     track=self.track, steps_done=done)
+
+
 def host_loop(
     step_fn: StepFn,
     n_steps: int,
     *,
     donate: bool = True,
     on_sync: Optional[Callable[[Any, int], bool]] = None,
+    metrics=None,
 ) -> Callable[[Any], Any]:
     """Baseline execution: one device dispatch per time step.
 
@@ -111,17 +172,22 @@ def host_loop(
     and the domain is re-read from main memory at every step. Every step IS
     a host sync, so ``on_sync(state, k)`` — if given — is evaluated after
     each one; returning True stops early (the baseline tier honors a
-    convergence contract at the finest possible cadence).
+    convergence contract at the finest possible cadence). ``metrics`` (a
+    ``repro.obs.MetricsRegistry``) counts what ran (see ``_Observed``).
     """
-    jitted = _jit_step(step_fn, donate)
+    seen = _Observed("host_loop", Execution.HOST_LOOP, metrics)
+    dispatch = seen.runner(_jit_step(step_fn, donate), 1)
 
     def run(state):
         if donate:
             state = _own(state)
         for k in range(n_steps):
-            state = jitted(state)
-            if on_sync is not None and on_sync(state, k + 1):
-                break
+            state = dispatch(state)
+            if on_sync is not None:
+                with seen.host_sync(k + 1):
+                    stop = on_sync(state, k + 1)
+                if stop:
+                    break
         return state
 
     return run
@@ -137,7 +203,8 @@ def _fused_runner(step_fn: StepFn, n_steps: int, donate: bool):
     return jax.jit(run_all, donate_argnums=(0,) if donate else ())
 
 
-def device_loop(step_fn: StepFn, n_steps: int, *, donate: bool = True) -> Callable[[Any], Any]:
+def device_loop(step_fn: StepFn, n_steps: int, *, donate: bool = True,
+                metrics=None) -> Callable[[Any], Any]:
     """PERKS control-flow transform: the whole time loop in one dispatch.
 
     ``grid.sync()`` of the paper corresponds to the loop-carried data
@@ -146,8 +213,9 @@ def device_loop(step_fn: StepFn, n_steps: int, *, donate: bool = True) -> Callab
     whatever collective the step function performs (halo exchange, psum),
     which is exactly the device-wide barrier semantics PERKS relies on.
     """
-    jitted = _fused_runner(step_fn, n_steps, donate)
-    return (lambda state: jitted(_own(state))) if donate else jitted
+    seen = _Observed("device_loop", Execution.DEVICE_LOOP, metrics)
+    dispatch = seen.runner(_fused_runner(step_fn, n_steps, donate), n_steps)
+    return (lambda state: dispatch(_own(state))) if donate else dispatch
 
 
 def chunked_loop(
@@ -158,6 +226,8 @@ def chunked_loop(
     donate: bool = True,
     on_sync: Optional[Callable[[Any, int], bool]] = None,
     on_barrier: Optional[Callable[[Any, int], tuple[Any, bool]]] = None,
+    execution: Execution = Execution.DEVICE_LOOP,
+    metrics=None,
 ) -> Callable[[Any], Any]:
     """PERKS with periodic host synchronisation.
 
@@ -177,11 +247,17 @@ def chunked_loop(
     fused chunk per barrier until ``on_barrier`` says stop (required in that
     mode); the compiled chunk runner persists across every barrier, so
     membership can churn while the dispatch stays hot.
+
+    ``execution`` is HOST_LOOP where the chunks stand for fused host-loop
+    steps (``persistent`` with ``fuse_steps`` > 1): each dispatch is then
+    one barrier. ``metrics`` counts what ran (see ``_Observed``).
     """
     # The loop below already owns `state` (one defensive copy at entry), so
     # the inner runners donate WITHOUT re-copying per dispatch — each chunk
     # updates the same buffers in place, as the persistent scheme intends.
-    inner = _fused_runner(step_fn, sync_every, donate)
+    seen = _Observed("chunked_loop", execution, metrics)
+    inner = seen.runner(_fused_runner(step_fn, sync_every, donate),
+                        sync_every)
 
     if n_steps is None:
         if on_barrier is None:
@@ -196,14 +272,16 @@ def chunked_loop(
             while True:
                 state = inner(state)
                 done += sync_every
-                state, stop = on_barrier(state, done)
+                with seen.host_sync(done):
+                    state, stop = on_barrier(state, done)
                 if stop:
                     return state
 
         return run_open
 
     rem = n_steps % sync_every
-    inner_rem = _fused_runner(step_fn, rem, donate) if rem else None
+    inner_rem = (seen.runner(_fused_runner(step_fn, rem, donate), rem)
+                 if rem else None)
 
     def run(state):
         if donate:
@@ -213,11 +291,15 @@ def chunked_loop(
             chunk = min(sync_every, n_steps - done)
             state = (inner if chunk == sync_every else inner_rem)(state)
             done += chunk
-            if on_barrier is not None:
-                state, stop = on_barrier(state, done)
-                if stop:
-                    break
-            if on_sync is not None and on_sync(state, done):
+            if on_barrier is None and on_sync is None:
+                continue
+            with seen.host_sync(done):
+                stop = False
+                if on_barrier is not None:
+                    state, stop = on_barrier(state, done)
+                if not stop and on_sync is not None:
+                    stop = on_sync(state, done)
+            if stop:
                 break
         return state
 
@@ -230,6 +312,7 @@ def persistent(
     config: PerksConfig = PerksConfig(),
     *,
     on_sync: Optional[Callable[[Any, int], bool]] = None,
+    metrics=None,
 ) -> Callable[[Any], Any]:
     """Build a runner for ``n_steps`` applications of ``step_fn`` under the
     requested execution tier. The RESIDENT tier is kernel-specific and is
@@ -243,21 +326,26 @@ def persistent(
     knob is a no-op at this level — the distributed/RESIDENT consumers
     (``solvers/stencil.py``, ``kernels/stencil2d.py``) implement it as
     wide-halo exchange / multi-step HBM passes instead.
+
+    ``metrics`` (a ``repro.obs.MetricsRegistry``) counts the steps, host
+    syncs and barriers that ran, under the tier's name.
     """
     if config.execution == Execution.HOST_LOOP:
         if config.fuse_steps > 1:
             return chunked_loop(
                 step_fn, n_steps, sync_every=config.fuse_steps,
                 donate=config.donate, on_sync=on_sync,
+                execution=Execution.HOST_LOOP, metrics=metrics,
             )
         return host_loop(step_fn, n_steps, donate=config.donate,
-                         on_sync=on_sync)
+                         on_sync=on_sync, metrics=metrics)
     if config.sync_every is not None and config.sync_every < n_steps:
         return chunked_loop(
             step_fn, n_steps, sync_every=config.sync_every,
-            donate=config.donate, on_sync=on_sync,
+            donate=config.donate, on_sync=on_sync, metrics=metrics,
         )
-    return device_loop(step_fn, n_steps, donate=config.donate)
+    return device_loop(step_fn, n_steps, donate=config.donate,
+                       metrics=metrics)
 
 
 def scan_loop(

@@ -218,24 +218,22 @@ class StencilProblem(Problem):
         return chip.interpret or self.x.shape[0] % row_align(
             self.x.shape, self.x.dtype) == 0
 
-    def _trace_resident(self, plan) -> None:
-        """Structural chunk/dma events for a resident dispatch (DESIGN.md
-        §11/§12): the kernel's streaming passes happen inside ONE Pallas
-        dispatch where the host-sync tracer cannot see them, so the
-        adapter emits the *projected* structure — per-pass block and DMA
-        counts and bytes — from the same plan the kernel executes. CI
-        cross-checks these aggregates against ``gm_bytes_fused``/
-        ``gm_bytes_deep``: summed streamed bytes + 2*cached bytes must
-        reproduce the model."""
-        from repro import obs
-        tr = obs.get_tracer()
-        if not tr.enabled:
-            return
+    def projected_passes(self, plan) -> list[dict]:
+        """The resident kernel's HBM streaming passes as the traffic model
+        projects them from ``plan`` (DESIGN.md §12): one record per group
+        of passes that fuse the same number of steps, with its ``passes``,
+        ``fuse_steps``, streamed ``blocks``, ``stream_rows`` and
+        ``cached_rows``, ``dmas_per_pass``, ``bytes_read_per_pass``,
+        ``bytes_written_per_pass`` and ``cached_bytes``. The passes run
+        inside one Pallas dispatch, where no host span sees them; the
+        summed streamed bytes plus twice the cached bytes reproduce
+        ``gm_bytes_fused`` / ``gm_bytes_deep`` (tests/test_deep_blocking.py)."""
         H = self.x.shape[0]
         row_bytes = int(math.prod(self.x.shape[1:])) * self.x.dtype.itemsize
         cached = min(plan.cached_rows or 0, H)
         stream_rows = H - cached
         r = self.spec.radius
+        out = []
         for n_passes, chunk_t in fusion_schedule(self.n_steps,
                                                  plan.fuse_steps):
             if stream_rows == 0:
@@ -246,15 +244,13 @@ class StencilProblem(Problem):
                 wr = stream_rows * row_bytes
                 rd = wr if plan.schedule == "deep" \
                     else wr + 2 * r * chunk_t * row_bytes
-            tr.event(f"chunk:resident:{plan.schedule}", cat="chunk",
-                     track="resident", passes=n_passes, fuse_steps=chunk_t,
-                     blocks=blocks, stream_rows=stream_rows,
-                     cached_rows=cached)
-            tr.event(f"dma:resident:{plan.schedule}", cat="dma",
-                     track="resident", passes=n_passes,
-                     dmas_per_pass=2 * blocks, bytes_read_per_pass=rd,
-                     bytes_written_per_pass=wr,
-                     cached_bytes=cached * row_bytes)
+            out.append(dict(passes=n_passes, fuse_steps=chunk_t,
+                            blocks=blocks, stream_rows=stream_rows,
+                            cached_rows=cached, dmas_per_pass=2 * blocks,
+                            bytes_read_per_pass=rd,
+                            bytes_written_per_pass=wr,
+                            cached_bytes=cached * row_bytes))
+        return out
 
     def run_resident(self, plan):
         plan.validate(radius=self.spec.radius, domain_rows=self.x.shape[0])
@@ -262,7 +258,6 @@ class StencilProblem(Problem):
         if cached_rows is None:
             raise ValueError("resident stencil plan must set cached_rows "
                              "(use repro.exec.plan to build plans)")
-        self._trace_resident(plan)
         if cached_rows >= self.x.shape[0]:
             return kops.stencil_resident(self.x, spec=self.spec,
                                          steps=self.n_steps,

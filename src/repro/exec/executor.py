@@ -27,12 +27,16 @@ from repro.exec import planner as _planner
 
 def _record_plan_metrics(plan: Plan) -> None:
     """Executor-level counters the service layer can't see (DESIGN.md
-    §11): barriers, fused steps per HBM pass, bytes resident vs streamed
-    per CacheDecision, collective rounds. Derived from the Plan — the
-    executed program's structure IS the plan's structure."""
+    §11): fused steps per HBM pass, bytes resident vs streamed per
+    CacheDecision, collective rounds, and the barriers of the resident and
+    distributed tiers, which never stop early and so pay the plan's count.
+    The loop tiers count the steps, host syncs and barriers that ran
+    themselves (``core.perks``)."""
     mx = obs.get_metrics()
     mx.counter("executor_executions_total", tier=plan.tier).inc()
-    mx.counter("executor_barriers_total", tier=plan.tier).inc(plan.barriers)
+    if plan.tier in ("resident", "distributed"):
+        mx.counter("executor_barriers_total", tier=plan.tier).inc(
+            plan.barriers)
     mx.gauge("executor_fused_steps_per_pass", tier=plan.tier).set(
         plan.fuse_steps)
     if plan.cache:
@@ -44,24 +48,6 @@ def _record_plan_metrics(plan: Plan) -> None:
         mx.counter("executor_collective_rounds_total").inc(plan.barriers)
 
 
-def _traced_on_sync(tracer, on_sync, track: str, problem_name: str):
-    """Wrap (or stand in for) a problem's ``on_sync`` so every host-sync
-    barrier of a loop-tier run lands in the trace as a chunk + barrier
-    event pair. Pure host-side bookkeeping: the wrapped callback's verdict
-    is returned unchanged (and False when there was no callback), so
-    traced execution is bit-identical to untraced."""
-
-    def synced(state, k):
-        tracer.event("chunk", cat="chunk", track=track,
-                     problem=problem_name, steps_done=k)
-        stop = False if on_sync is None else bool(on_sync(state, k))
-        tracer.event("barrier", cat="barrier", track=track,
-                     problem=problem_name, steps_done=k, stop=stop)
-        return stop
-
-    return synced
-
-
 def execute(problem: Problem, plan: Plan, *, mesh=None):
     """Run ``problem`` under ``plan``; returns the problem's final result.
 
@@ -70,10 +56,12 @@ def execute(problem: Problem, plan: Plan, *, mesh=None):
     so results are bit-identical (<= 2 ulp where ``fuse_steps > 1``
     changes window shapes, DESIGN.md §4 — the same bound the legacy
     paths carry). The ambient observability context (``repro.obs``) sees
-    every call: executor counters always, span/chunk/barrier/cache trace
-    events when a real tracer is installed, and a predicted-vs-measured
-    row in the drift ledger when one is active (the ledger blocks on the
-    result to time it — values are unchanged, only laziness).
+    every call: executor counters and the ``repro.dispatch`` span around
+    the run (with the loop tiers' compile/chunk/barrier spans inside it)
+    always, cache events and in-memory records when a real tracer is
+    installed, and a predicted-vs-measured row in the drift ledger when
+    one is active (the ledger blocks on the result to time it — values
+    are unchanged, only laziness).
     """
     if plan.n_steps and plan.n_steps != problem.n_steps:
         raise ValueError(
@@ -114,54 +102,40 @@ def execute(problem: Problem, plan: Plan, *, mesh=None):
             tr.event(f"cache:{d.name}", cat="cache", track=track,
                      problem=problem.name, cached_bytes=d.cached_bytes,
                      total_bytes=d.total_bytes, fraction=d.fraction)
-    span = (tr.span(f"execute:{problem.name}", cat="dispatch", track=track,
-                    tier=plan.tier, fuse_steps=plan.fuse_steps,
-                    batch=plan.batch, n_steps=problem.n_steps,
-                    barriers=plan.barriers) if tr.enabled
-            else _noop_span)
     t0 = time.perf_counter() if ledger is not None else 0.0
-    with span:
-        result = _dispatch(problem, plan, mesh, on_sync, tr, track)
-        if ledger is not None:
-            result = jax.block_until_ready(result)
+    result = _dispatch(problem, plan, mesh, on_sync, tr, track)
     if ledger is not None:
+        result = jax.block_until_ready(result)
         ledger.record(problem, plan, time.perf_counter() - t0)
     return result
 
 
-class _NoopSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_noop_span = _NoopSpan()
-
-
 def _dispatch(problem: Problem, plan: Plan, mesh, on_sync, tracer, track):
-    """The tier dispatch proper (validation and observability live in
-    ``execute``)."""
-    if plan.tier == "distributed":
-        if mesh is None:
-            raise ValueError("distributed plan needs mesh=")
-        return problem.run_distributed(plan, mesh)
-    if plan.tier == "resident":
-        return problem.run_resident(plan)
-    execution = (perks.Execution.HOST_LOOP if plan.tier == "host_loop"
-                 else perks.Execution.DEVICE_LOOP)
-    cfg = perks.PerksConfig(execution=execution, sync_every=plan.sync_every,
-                            fuse_steps=plan.fuse_steps)
-    if tracer.enabled and honors_on_sync(plan, problem.n_steps):
-        on_sync = _traced_on_sync(tracer, on_sync, track, problem.name)
-    runner = perks.persistent(problem.step_fn(), problem.n_steps, cfg,
-                              on_sync=on_sync)
-    obs.get_metrics().counter("executor_retraces_total",
-                              tier=plan.tier).inc()
-    return problem.finalize(runner(problem.initial_state()))
+    """The tier dispatch proper, under the ``repro.dispatch`` span
+    (validation, counters and the ledger live in ``execute``)."""
+    # a Krylov problem's name holds a content fingerprint, read from the
+    # device: only a recording tracer pays for it
+    label = problem.name if tracer.enabled else problem.kind
+    with tracer.span(f"execute:{label}", cat="dispatch", track=track,
+                     tier=plan.tier, fuse_steps=plan.fuse_steps,
+                     batch=plan.batch, n_steps=problem.n_steps,
+                     barriers=plan.barriers):
+        if plan.tier == "distributed":
+            if mesh is None:
+                raise ValueError("distributed plan needs mesh=")
+            return problem.run_distributed(plan, mesh)
+        if plan.tier == "resident":
+            return problem.run_resident(plan)
+        execution = (perks.Execution.HOST_LOOP if plan.tier == "host_loop"
+                     else perks.Execution.DEVICE_LOOP)
+        cfg = perks.PerksConfig(execution=execution,
+                                sync_every=plan.sync_every,
+                                fuse_steps=plan.fuse_steps)
+        metrics = obs.get_metrics()
+        runner = perks.persistent(problem.step_fn(), problem.n_steps, cfg,
+                                  on_sync=on_sync, metrics=metrics)
+        metrics.counter("executor_retraces_total", tier=plan.tier).inc()
+        return problem.finalize(runner(problem.initial_state()))
 
 
 def honors_on_sync(plan: Plan, n_steps: int) -> bool:

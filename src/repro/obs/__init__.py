@@ -5,8 +5,9 @@ Three planes, one ambient context:
 * :class:`Tracer` (``trace.py``) — typed span/event records over the
   execution taxonomy (plan/compile/dispatch/chunk/barrier/collective/
   lane/cache/measure), injectable clock, JSON-lines + Chrome trace-event
-  exporters (Perfetto-loadable, one track per tier/lane group). Disabled
-  by default via :class:`NullTracer`.
+  exporters (Perfetto-loadable, one track per tier/lane group). Every
+  span, the default :class:`NullTracer`'s too, is also the profiler span
+  ``repro.<cat>``; recording in memory is off by default.
 * :class:`MetricsRegistry` (``metrics.py``) — counters/gauges/histograms
   behind the services' ``stats()`` views and the executor-level counters
   (barriers, fused steps per pass, bytes cached vs streamed, collective
@@ -20,10 +21,10 @@ Three planes, one ambient context:
 
 The *ambient context* (``get_tracer``/``use_tracer`` and friends) is how
 instrumentation reaches the executor without threading arguments through
-every call: the default tracer is a null object and the default ledger is
-None, so an uninstrumented process pays one attribute check per site.
-Installing a real tracer/registry/ledger (directly or with the ``use_*``
-context managers) lights the whole stack up.
+every call: the default tracer is a null object that records nothing (its
+spans still reach a running profiler session) and the default ledger is
+None. Installing a real tracer/registry/ledger (directly or with the
+``use_*`` context managers) lights the whole stack up.
 """
 from __future__ import annotations
 

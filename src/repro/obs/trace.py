@@ -7,11 +7,10 @@ is a low-overhead :class:`Tracer` emitting typed span/event records for
 the taxonomy the executor and services agree on (``CATEGORIES``):
 
     plan        candidate enumeration / ranking
-    compile     runner construction (a trace/compile boundary)
+    compile     a runner's first dispatch (trace, lower, compile, enqueue)
     dispatch    one execute()/runner invocation
-    chunk       one fused step chunk between host syncs
-    dma         a projected DMA transfer group (resident-tier streaming)
-    barrier     a host-sync barrier (scheduler runs here)
+    chunk       a later dispatch of a runner (one fused step chunk)
+    barrier     a host sync: the read-back and the decision (scheduler)
     collective  a collective round projected/executed per barrier
     lane        lane admission / retirement / harvest (continuous batching)
     cache       one CacheDecision (bytes resident vs streamed)
@@ -23,10 +22,17 @@ Design points:
   returning *seconds*; with a deterministic fake clock two identical runs
   produce byte-identical JSON-lines exports (asserted in
   ``tests/test_obs.py``), which is what makes traces diffable artifacts.
-* **Disabled by default** — the ambient tracer is a :class:`NullTracer`
-  whose ``event``/``span`` are no-ops; instrumented call sites guard arg
-  construction behind ``tracer.enabled`` so the untraced hot path pays a
-  single attribute check (overhead asserted near-zero in the tests).
+* **Spans reach the profiler** — every ``span`` (the
+  :class:`NullTracer`'s included) opens a
+  ``jax.profiler.TraceAnnotation`` named ``repro.<cat>`` around its body,
+  with the span's name and args as stats. Under a profiler session the
+  spans land on the host plane, on the same clock as the device's
+  operations, so an idle gap on the device can be put down to the
+  program step that was running; with no session a span costs about a
+  microsecond. Instant events are not forwarded.
+* **Recording is opt-in** — the ambient tracer is a :class:`NullTracer`
+  that records nothing in memory; call sites guard only args that cost
+  something to build behind ``tracer.enabled``.
 * **Two exporters** — JSON-lines (one event per line, sorted keys) for
   grepping/diffing, and Chrome trace-event JSON for Perfetto
   (``ui.perfetto.dev`` → *Open trace file*), with one named track per
@@ -39,9 +45,12 @@ import json
 import time
 from typing import Any, Callable
 
+from jax.profiler import TraceAnnotation
+
 #: The event taxonomy (DESIGN.md §11). Free-form categories are allowed
-#: but everything the repo emits uses these.
-CATEGORIES = ("plan", "compile", "dispatch", "chunk", "dma", "barrier",
+#: but everything the repo emits uses these. A span of category ``cat``
+#: is the profiler span ``repro.<cat>``.
+CATEGORIES = ("plan", "compile", "dispatch", "chunk", "barrier",
               "collective", "lane", "cache", "measure")
 
 
@@ -84,10 +93,18 @@ def _freeze_args(kw: dict) -> tuple:
     return tuple(out)
 
 
-class _Span:
-    """Context manager recording one complete ("X") event on exit."""
+def _annotation(name: str, cat: str, args: dict) -> TraceAnnotation:
+    """The profiler span of a tracer span: ``repro.<cat>``, readers match
+    on that; the span's own name and args are its stats."""
+    return TraceAnnotation(f"repro.{cat}", name=name, **args)
 
-    __slots__ = ("_tracer", "_name", "_cat", "_track", "_args", "_t0")
+
+class _Span:
+    """Context manager recording one complete ("X") event on exit, inside
+    the span's profiler annotation."""
+
+    __slots__ = ("_tracer", "_name", "_cat", "_track", "_args", "_t0",
+                 "_annotation")
 
     def __init__(self, tracer, name, cat, track, args):
         self._tracer = tracer
@@ -97,6 +114,9 @@ class _Span:
         self._args = args
 
     def __enter__(self):
+        self._annotation = _annotation(self._name, self._cat,
+                                       dict(self._args))
+        self._annotation.__enter__()
         self._t0 = self._tracer._clock()
         return self
 
@@ -106,6 +126,7 @@ class _Span:
             name=self._name, cat=self._cat, ph="X",
             ts_us=self._t0 * 1e6, dur_us=(t1 - self._t0) * 1e6,
             track=self._track, args=self._args))
+        self._annotation.__exit__(*exc)
         return False
 
 
@@ -138,7 +159,8 @@ class Tracer:
                                 args=_freeze_args(args)))
 
     def span(self, name: str, *, cat: str, track: str = "main", **args):
-        """Context manager: a complete event spanning the ``with`` body."""
+        """Context manager: a complete event spanning the ``with`` body,
+        and the profiler span ``repro.<cat>`` around it."""
         return _Span(self, name, cat, track, _freeze_args(args))
 
     def clear(self) -> None:
@@ -199,27 +221,14 @@ class Tracer:
             f.write("\n")
 
 
-class _NullSpan:
-    """Reusable no-op context manager (no per-call allocation)."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class NullTracer(Tracer):
-    """The disabled tracer: records nothing, allocates nothing per call.
+    """The disabled tracer: records nothing in memory.
 
-    This is the ambient default — instrumentation is free unless a real
-    tracer is installed (``repro.obs.use_tracer``). Call sites that build
-    expensive args should guard on ``tracer.enabled``.
+    This is the ambient default — recording is off unless a real tracer
+    is installed (``repro.obs.use_tracer``); its spans still open their
+    profiler annotation, which costs about a microsecond when no profiler
+    session runs. Call sites that build expensive args should guard on
+    ``tracer.enabled``.
     """
 
     enabled = False
@@ -232,7 +241,7 @@ class NullTracer(Tracer):
         pass
 
     def span(self, name: str, *, cat: str, track: str = "main", **args):
-        return _NULL_SPAN
+        return _annotation(name, cat, args)
 
     def _record(self, ev: TraceEvent) -> None:
         pass

@@ -255,22 +255,16 @@ class SolverService:
         bp = BatchedProblem.from_instances([p.problem for p in taken],
                                            pad_to=pad_to)
         chosen, runner, plan_s = self._plan_for(bp)
-        tr = self._tr()
-        span = (tr.span(f"serve_batch:{bp.name}", cat="dispatch",
-                        track="service", tier=chosen.tier,
-                        batch_size=len(taken), padded_to=bp.batch)
-                if tr.enabled else None)
-        if span is not None:
-            span.__enter__()
-        t0 = self._clock()
-        if runner is not None:
-            result = jax.block_until_ready(runner(bp))
-        else:
-            result = jax.block_until_ready(execute(bp, chosen,
-                                                   mesh=self.mesh))
-        t1 = self._clock()
-        if span is not None:
-            span.__exit__(None, None, None)
+        with self._tr().span(f"serve_batch:{bp.name}", cat="dispatch",
+                             track="service", tier=chosen.tier,
+                             batch_size=len(taken), padded_to=bp.batch):
+            t0 = self._clock()
+            if runner is not None:
+                result = jax.block_until_ready(runner(bp))
+            else:
+                result = jax.block_until_ready(execute(bp, chosen,
+                                                       mesh=self.mesh))
+            t1 = self._clock()
         per_request = bp.split(result)
 
         mx = self.metrics
@@ -683,17 +677,15 @@ class AsyncSolverService:
         g = self._group
         self._quantum = quantum
         tr = self._tr()
-        span = (tr.span(f"drive:{g.prog.template.name}", cat="dispatch",
-                        track=f"lanes:{g.prog.template.name}",
-                        width=g.prog.runner.width, chunk=g.prog.chunk)
-                if tr.enabled else None)
-        if span is not None:
-            span.__enter__()
-        t0 = self._clock()
-        carry = g.prog.drive((g.lanes.state, g.lanes.steps_done))
-        self.metrics.counter("async_busy_s_total").inc(self._clock() - t0)
-        if span is not None:
-            span.__exit__(None, None, None)
+        # a Krylov problem's name holds a content fingerprint, read from
+        # the device: only a recording tracer pays for it
+        label = g.prog.template.name if tr.enabled else g.prog.template.kind
+        with tr.span(f"drive:{label}", cat="dispatch", track=f"lanes:{label}",
+                     width=g.prog.runner.width, chunk=g.prog.chunk):
+            t0 = self._clock()
+            carry = g.prog.drive((g.lanes.state, g.lanes.steps_done))
+            self.metrics.counter("async_busy_s_total").inc(
+                self._clock() - t0)
         if self._group is g:                 # paused, not drained
             g.lanes = dataclasses.replace(g.lanes, state=carry[0],
                                           steps_done=carry[1])

@@ -19,8 +19,9 @@ redundant recompute. This module pins, per ISSUE/DESIGN.md §12:
     what used to be a bare kernel assert);
   * deep plans run under ``BatchedProblem`` at B in {1, 8} bit-matching
     the per-instance runs;
-  * the adapter's structural chunk/dma trace events reproduce the
-    traffic model exactly (summed streamed bytes + 2*cached == model).
+  * the adapter's projected per-pass records
+    (``StencilProblem.projected_passes``) reproduce the traffic model
+    exactly (summed streamed bytes + 2*cached == model).
 """
 import math
 
@@ -31,7 +32,6 @@ import pytest
 
 from tests._hyp import given, settings, st
 
-from repro import obs
 from repro.core.cache_policy import (
     gm_bytes_deep,
     gm_bytes_fused,
@@ -43,7 +43,6 @@ from repro.kernels import ops as kops
 from repro.kernels import ref
 from repro.kernels.common import BENCHMARKS, get_spec
 from repro.kernels.stencil2d import deep_vmem_bytes
-from repro.obs.trace import Tracer
 
 
 def _domain(spec, seed=0):
@@ -277,23 +276,19 @@ def test_plan_schedule_field_roundtrip_and_check():
     assert Plan.from_dict(d).schedule == "shallow"
 
 
-# -- traced structure vs model -------------------------------------------------
+# -- projected structure vs model ----------------------------------------------
 
-def _traced_streamed(spec, steps, t, schedule):
+def _projected_streamed(spec, steps, t, schedule):
     x = _domain(spec)
     plan = Plan(tier="resident", schedule=schedule, fuse_steps=t,
                 cached_rows=16, sub_rows=8, n_steps=steps)
-    tr = Tracer(clock=lambda: 0.0)
-    with obs.use_tracer(tr):
-        execute(StencilProblem(x, spec, steps), plan)
-    dma = [dict(e.args) for e in tr.events if e.cat == "dma"]
-    chunk = [dict(e.args) for e in tr.events if e.cat == "chunk"]
-    assert dma and chunk
-    assert sum(c["passes"] for c in chunk) == math.ceil(steps / t)
-    streamed = sum(d["passes"] * (d["bytes_read_per_pass"]
-                                  + d["bytes_written_per_pass"])
-                   for d in dma)
-    return streamed + 2 * dma[0]["cached_bytes"]
+    passes = StencilProblem(x, spec, steps).projected_passes(plan)
+    assert passes
+    assert sum(p["passes"] for p in passes) == math.ceil(steps / t)
+    streamed = sum(p["passes"] * (p["bytes_read_per_pass"]
+                                  + p["bytes_written_per_pass"])
+                   for p in passes)
+    return streamed + 2 * passes[0]["cached_bytes"]
 
 
 def _model(spec, steps, t, schedule):
@@ -307,23 +302,23 @@ def _model(spec, steps, t, schedule):
 
 @pytest.mark.parametrize("schedule", ["shallow", "deep"])
 def test_traced_dma_bytes_reproduce_model(schedule):
-    """The adapter's per-pass chunk/dma events aggregate to the traffic
+    """The adapter's projected per-pass records aggregate to the traffic
     model exactly when t divides n_steps: sum(passes * (read + written))
     + 2*cached == gm."""
     spec = get_spec("2d5pt")
-    assert _traced_streamed(spec, 12, 4, schedule) \
+    assert _projected_streamed(spec, 12, 4, schedule) \
         == _model(spec, 12, 4, schedule)
 
 
 @pytest.mark.parametrize("schedule", ["shallow", "deep"])
 def test_traced_dma_bytes_bounded_by_model_on_tails(schedule):
-    """On a non-dividing tail the trace is pass-exact (the remainder
+    """On a non-dividing tail the projection is pass-exact (the remainder
     chunk's shallow overlap is narrower than r*t), so the model is an
     upper bound — deep has no overlap term and stays exact."""
     spec = get_spec("2d5pt")
-    traced, model = _traced_streamed(spec, 11, 4, schedule), \
+    projected, model = _projected_streamed(spec, 11, 4, schedule), \
         _model(spec, 11, 4, schedule)
     if schedule == "deep":
-        assert traced == model
+        assert projected == model
     else:
-        assert traced <= model
+        assert projected <= model

@@ -6,6 +6,10 @@ The observability contract has three legs, all asserted here:
   byte-identical JSON-lines traces and identical metric snapshots;
 * **free when off** — the NullTracer records nothing, and a traced
   ``execute()`` returns bit-identical results to an untraced one;
+* **in the profiler trace** — every span, the NullTracer's included, is
+  the profiler span ``repro.<cat>``, nested by time on the calling
+  thread, and the loop tiers count the steps, host syncs and barriers
+  that ran;
 * **persistent** — the drift ledger round-trips through JSON, a second
   ``autotune()`` against it skips re-measurement, and ``drift_report``
   flags exactly the plans whose measured/predicted ratio departs the
@@ -14,6 +18,7 @@ The observability contract has three legs, all asserted here:
 import itertools
 import json
 import math
+import pathlib
 import urllib.error
 import urllib.request
 
@@ -22,7 +27,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from jax.profiler import ProfileData
+
 from repro import obs
+from repro.core import perks
 from repro.exec import (CGProblem, StencilProblem, autotune, execute,
                         plan_candidates)
 from repro.kernels.common import get_spec
@@ -123,11 +131,75 @@ def test_traced_execute_bit_identical_to_untraced():
     with obs.use_tracer(tr):
         traced = execute(p, pl)
     _assert_same(traced, base)
-    # the host-loop path syncs every chunk: chunk + barrier events appear
-    assert tr.by_cat("chunk") and tr.by_cat("barrier")
-    assert tr.by_cat("dispatch")
+    # one dispatch span; the host loop's first step compiles its runner,
+    # the other seven are chunks of one step; a stencil has no
+    # convergence check, so no host sync
+    (dispatch,) = tr.by_cat("dispatch")
+    assert dispatch.name == f"execute:{p.name}"
+    assert [dict(e.args)["steps"] for e in tr.by_cat("compile")] == [1]
+    assert [dict(e.args)["steps"] for e in tr.by_cat("chunk")] == [1] * 7
+    assert tr.by_cat("barrier") == []
+    inner = tr.by_cat("compile") + tr.by_cat("chunk")
+    assert all(dispatch.ts_us < e.ts_us and e.ts_us + e.dur_us
+               < dispatch.ts_us + dispatch.dur_us for e in inner)
     # scoping restored the null tracer
     assert obs.get_tracer().enabled is False
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a profiler session; returns its result and the
+    ``repro.*`` spans of the host planes as (name, start_ns, end_ns,
+    stats), in start order."""
+    with jax.profiler.trace(str(tmp_path)):
+        out = jax.block_until_ready(fn())
+    (path,) = pathlib.Path(tmp_path).rglob("*.xplane.pb")
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+             for plane in ProfileData.from_file(str(path)).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("repro.")]
+    return out, sorted(spans, key=lambda s: s[1])
+
+
+@pytest.mark.parametrize("recording", [False, True])
+def test_spans_reach_the_profiler_for_every_tracer(tmp_path, recording):
+    tr = obs.Tracer() if recording else obs.NullTracer()
+
+    def spans():
+        with tr.span("sweep", cat="chunk", track="t", steps=3):
+            return jnp.ones(4)
+
+    _, got = _profiled(tmp_path, spans)
+    assert [(n, s) for n, _, _, s in got] == [
+        ("repro.chunk", {"name": "sweep", "steps": 3})]
+    assert len(tr.events) == int(recording)
+
+
+def test_cg_device_loop_spans_in_the_profiler_trace(tmp_path, poisson):
+    """A CG solve on a device_loop plan with host syncs: one
+    ``repro.dispatch`` holding one ``repro.compile``, k-1 ``repro.chunk``
+    and k ``repro.barrier`` spans, k the host syncs that ran."""
+    data, cols = poisson
+    p = _cg(data, cols, 0, iters=400)
+    pl = [c for c in plan_candidates(p) if c.tier == "device_loop"][0]
+    assert pl.sync_every == 25
+    reg = obs.MetricsRegistry()
+    with obs.use_metrics(reg):
+        _, spans = _profiled(tmp_path, lambda: execute(p, pl))
+    k = reg.value("executor_host_syncs_total", tier="device_loop")
+    assert 1 < k < 400 // 25
+    (dispatch,) = [s for s in spans if s[0] == "repro.dispatch"]
+    assert dispatch[3]["name"] == "execute:cg"
+    assert dispatch[3]["tier"] == "device_loop"
+    inner = [s for s in spans if s is not dispatch]
+    assert all(dispatch[1] <= a and b <= dispatch[2] for _, a, b, _ in inner)
+    names = [n for n, _, _, _ in inner]
+    assert names == ["repro.compile", "repro.barrier"] + [
+        "repro.chunk", "repro.barrier"] * (k - 1)
+    assert {s["steps"] for n, _, _, s in inner if n != "repro.barrier"} \
+        == {25}
+    assert [s["steps_done"] for n, _, _, s in inner
+            if n == "repro.barrier"] == [25 * (i + 1) for i in range(k)]
 
 
 # -- metrics -----------------------------------------------------------------
@@ -175,13 +247,55 @@ def test_executor_records_plan_metrics():
     with obs.use_metrics(reg):
         cands = plan_candidates(p)
         resident = [c for c in cands if c.tier == "resident"][0]
+        host = [c for c in cands if c.tier == "host_loop"][0]
         execute(p, resident)
+        execute(p, host)
     assert reg.value("executor_executions_total", tier="resident") == 1
+    # the resident tier never stops early: it pays the plan's barriers
     assert reg.value("executor_barriers_total",
                      tier="resident") == resident.barriers
     if resident.cache:
         assert reg.value("executor_bytes_cached_total") == \
             resident.cached_bytes
+    # the host loop counts what ran: 8 one-step dispatches, no host sync
+    assert reg.value("executor_steps_total", tier="host_loop") == 8
+    assert reg.value("executor_barriers_total", tier="host_loop") == 8
+    assert reg.value("executor_host_syncs_total", tier="host_loop") == 0
+    assert reg.value("executor_steps_total", tier="resident") == 0
+
+
+@pytest.mark.parametrize("tier", ["device_loop", "host_loop"])
+def test_loop_counters_count_steps_that_ran(poisson, tier):
+    """A solve that converges early: the steps and barriers counted are
+    those that ran (the last host sync's ``steps_done``), not the plan's
+    iteration cap."""
+    data, cols = poisson
+    p = _cg(data, cols, 1, iters=400)
+    pl = [c for c in plan_candidates(p) if c.tier == tier][0]
+    reg = obs.MetricsRegistry()
+    tr = obs.Tracer()
+    with obs.use_metrics(reg), obs.use_tracer(tr):
+        execute(p, pl)
+    syncs = tr.by_cat("barrier")
+    ran = dict(syncs[-1].args)["steps_done"]
+    assert ran < 400 == pl.barriers
+    assert reg.value("executor_steps_total", tier=tier) == ran
+    assert reg.value("executor_barriers_total", tier=tier) == ran
+    assert reg.value("executor_host_syncs_total", tier=tier) == len(syncs)
+
+
+def test_fused_host_loop_counts_a_barrier_a_dispatch():
+    """A host loop fusing 3 steps a dispatch: 10 steps are 4 dispatches,
+    so 4 barriers, and 4 host syncs where a check runs at each."""
+    reg = obs.MetricsRegistry()
+    cfg = perks.PerksConfig(execution=perks.Execution.HOST_LOOP,
+                            fuse_steps=3, donate=False)
+    run = perks.persistent(lambda s: s + 1, 10, cfg,
+                           on_sync=lambda s, k: False, metrics=reg)
+    assert float(run(jnp.float32(0))) == 10
+    assert reg.value("executor_steps_total", tier="host_loop") == 10
+    assert reg.value("executor_barriers_total", tier="host_loop") == 4
+    assert reg.value("executor_host_syncs_total", tier="host_loop") == 4
 
 
 def test_metrics_endpoint_serves_prometheus_over_http():
