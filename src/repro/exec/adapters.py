@@ -13,9 +13,11 @@ multigrid) is one more adapter here: ~50 lines, no new solver file.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import math
+import threading
 from typing import Any, Callable, Optional, Sequence
 
 import jax
@@ -23,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro import obs
 from repro.core import perks
 from repro.core.cache_policy import (
     CacheableArray,
@@ -37,6 +40,7 @@ from repro.exec.problem import HaloSpec, Problem, operand_fingerprint
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.kernels.common import StencilSpec
+from repro.kernels.spmv_dia import ell_to_dia, spmv_dia
 from repro.kernels.stencil2d import row_align
 
 
@@ -66,6 +70,62 @@ def _operand_sig(a):
     shape = getattr(a, "shape", None)
     return (id(a), None if shape is None else tuple(shape),
             str(getattr(a, "dtype", None)))
+
+
+#: ELL operators inspected for diagonals, most recent last:
+#: ``(_operand_sig(data), _operand_sig(cols)) -> (data, cols, dia)``.
+#: Each entry pins its operands, so no id is recycled under its key.
+_DIA_CACHE: "collections.OrderedDict[tuple, tuple]" = collections.OrderedDict()
+_DIA_CACHE_SIZE = 8
+_DIA_LOCK = threading.Lock()
+
+
+def _concrete(a) -> bool:
+    return isinstance(a, (np.ndarray, jax.Array)) and not isinstance(
+        a, jax.core.Tracer)
+
+
+def dia_operator(data, cols):
+    """The DIA form ``(offsets, device planes)`` of the ELL operator
+    ``(data, cols)``, or None where the gather stays: abstract operands
+    (tracers, ``ShapeDtypeStruct`` planner probes), operands on several
+    devices, and operators whose nonzeros do not pay in DIA
+    (``kernels.spmv_dia.ell_to_dia``).
+
+    A solver builds a fresh problem per right-hand side over one operator,
+    so the conversion (a host read of both planes) is kept per device
+    operand pair, by identity; host arrays, which can change in place, are
+    converted each time. Each conversion counts in
+    ``spmv_dia_conversions_total``."""
+    if not (_concrete(data) and _concrete(cols)):
+        return None
+    on_device = isinstance(data, jax.Array)
+    if on_device and len(data.sharding.device_set) > 1:
+        return None
+    if not (on_device and isinstance(cols, jax.Array)):
+        return _convert(data, cols)
+    key = (_operand_sig(data), _operand_sig(cols))
+    with _DIA_LOCK:
+        hit = _DIA_CACHE.get(key)
+        if hit is not None:
+            _DIA_CACHE.move_to_end(key)
+            return hit[2]
+        dia = _convert(data, cols)
+        _DIA_CACHE[key] = (data, cols, dia)
+        if len(_DIA_CACHE) > _DIA_CACHE_SIZE:
+            _DIA_CACHE.popitem(last=False)
+        return dia
+
+
+def _convert(data, cols):
+    obs.get_metrics().counter("spmv_dia_conversions_total").inc()
+    dia = ell_to_dia(data, cols)
+    if dia is None:
+        return None
+    offsets, planes = dia
+    if isinstance(data, jax.Array):
+        return offsets, jax.device_put(planes, data.sharding)
+    return offsets, jnp.asarray(planes)
 
 
 # =============================================================================
@@ -437,10 +497,22 @@ class CGProblem(Problem):
         return (jnp.zeros_like(self.b), self.b, self.b,
                 jnp.vdot(self.b, self.b))
 
+    @property
+    def spmv_format(self) -> Optional[str]:
+        """The SpMV the loop tiers run: "dia" where the ELL planes'
+        nonzeros lie on few enough diagonals (``dia_operator``), "ell"
+        (the gather) otherwise, None for a ``matvec`` problem."""
+        if self.matvec is not None:
+            return None
+        return "ell" if dia_operator(self.data, self.cols) is None else "dia"
+
     def step_fn(self):
         dot = dot_for(self.precision)
         if self.matvec is not None:
             mv = self.matvec
+        elif (dia := dia_operator(self.data, self.cols)) is not None:
+            offsets, planes = dia
+            mv = functools.partial(spmv_dia, planes, offsets)
         else:
             mv = functools.partial(kref.spmv_ell, self.data, self.cols)
         return lambda s: kref.cg_iteration_matvec(s, mv, dot=dot)

@@ -191,6 +191,10 @@ class BatchedProblem(Problem):
     def supports(self, tier: str) -> bool:
         return self.template.supports(tier)
 
+    @property
+    def spmv_format(self) -> Optional[str]:
+        return self.template.spmv_format
+
     # -- batching surface -----------------------------------------------------
 
     def payload(self):

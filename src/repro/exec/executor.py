@@ -116,10 +116,13 @@ def _dispatch(problem: Problem, plan: Plan, mesh, on_sync, tracer, track):
     # a Krylov problem's name holds a content fingerprint, read from the
     # device: only a recording tracer pays for it
     label = problem.name if tracer.enabled else problem.kind
+    spmv = (problem.spmv_format if plan.tier in ("host_loop", "device_loop")
+            else None)
     with tracer.span(f"execute:{label}", cat="dispatch", track=track,
                      tier=plan.tier, fuse_steps=plan.fuse_steps,
                      batch=plan.batch, n_steps=problem.n_steps,
-                     barriers=plan.barriers):
+                     barriers=plan.barriers,
+                     **({"spmv": spmv} if spmv else {})):
         if plan.tier == "distributed":
             if mesh is None:
                 raise ValueError("distributed plan needs mesh=")
@@ -135,6 +138,8 @@ def _dispatch(problem: Problem, plan: Plan, mesh, on_sync, tracer, track):
         runner = perks.persistent(problem.step_fn(), problem.n_steps, cfg,
                                   on_sync=on_sync, metrics=metrics)
         metrics.counter("executor_retraces_total", tier=plan.tier).inc()
+        if spmv:
+            metrics.counter("executor_spmv_total", format=spmv).inc()
         return problem.finalize(runner(problem.initial_state()))
 
 

@@ -103,6 +103,9 @@ class Problem(abc.ABC):
     #: how many independent instances this problem carries (1 = a single
     #: instance; ``repro.exec.batch.BatchedProblem`` overrides)
     batch: int = 1
+    #: the sparse matvec the loop tiers' step runs ("dia", "ell"), which
+    #: the executor counts (``executor_spmv_total``); None = no SpMV
+    spmv_format: Optional[str] = None
 
     # -- required surface -----------------------------------------------------
 

@@ -22,8 +22,9 @@ column index (``spmv_sell``, ``cg_fused``, ``krylov_fused``) run only in
 interpret mode, off the TPU: ``gather_interpret`` refuses a Mosaic
 compile, and the planner offers no resident Krylov plan for a chip that
 compiles its kernels (``Chip.interpret`` unset). The loop tiers' SpMV is
-the XLA gather of ``ref.spmv_ell``, which the TPU runs. The oracle in
-``ref.py`` is identical math.
+the XLA gather of ``ref.spmv_ell``, which the TPU runs, or for CG on an
+operator whose nonzeros lie on few diagonals the gather-free DIA matvec
+(``kernels/spmv_dia.py``). The oracle in ``ref.py`` is identical math.
 """
 from __future__ import annotations
 

@@ -12,8 +12,10 @@ CG/BiCGStab/GMRES) do not compile for a TPU, nor does an SSD scan whose
 chunk does not fill the 128-lane tile, nor a stencil kernel over a domain
 whose rows are not a whole number of tiles; the tests below pin that the
 kernels refuse them there and that the planner, given the v5e, never
-offers them.
+offers them. CG's loop-tier chunk on a structured operator compiles with
+no gather at all (the DIA SpMV).
 """
+import functools
 import os
 
 import jax
@@ -22,10 +24,12 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import perks
 from repro.exec import BiCGStabProblem, CGProblem, Plan, execute, plan
 from repro.exec import plan_candidates
 from repro.exec import StencilProblem
 from repro.kernels import cg_fused, decode_attn, krylov_fused, spmv_ell
+from repro.kernels import ref as kref
 from repro.kernels import spmv_sell, ssm_scan
 from repro.kernels import stencil2d as s2d
 from repro.kernels.common import get_spec
@@ -124,6 +128,24 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     args = [jax.ShapeDtypeStruct(s, dtype, sharding=one_chip) for s in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_cg_chunk_compiles_without_a_gather(one_chip):
+    """The chunk of 25 CG iterations that ``device_loop`` runs on the
+    256^2 Poisson operator: its SpMV is the DIA matvec, which compiles for
+    the v5e with no gather; the same chunk on the ELL gather holds one."""
+    data, cols = spmv_ell.poisson2d_ell(256)
+    n = data.shape[0]
+    b = np.ones(n, np.float32)
+    vec = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    state = (vec, vec, vec, jax.ShapeDtypeStruct((), jnp.float32,
+                                                 sharding=one_chip))
+    gather = functools.partial(kref.spmv_ell, data, cols)
+    for prob, gathers in ((CGProblem.from_ell(data, cols, b, 500), False),
+                          (CGProblem.from_matvec(gather, b, 500), True)):
+        chunk = perks._fused_runner(prob.step_fn(), 25, True)
+        text = chunk.lower(state).compile().as_text()
+        assert (" gather(" in text) == gathers
 
 
 # -- gather kernels: refused on a TPU ------------------------------------------
