@@ -132,9 +132,11 @@ DEVICE_KINDS = {"TPU v5 lite": TPU_V5E}
 
 def attached_chip() -> Chip:
     """The Chip of the first attached device, looked up by its
-    ``device_kind``. On the CPU (the test suite, where every kernel runs
-    in interpret mode) plans are made for ``CPU_INTERPRET``; any other
-    platform is an error."""
+    ``device_kind``, with the HBM the device reports as usable
+    (``memory_stats()["bytes_limit"]``, below the published figure) as
+    its ``hbm_bytes``. On the CPU (the test suite, where every kernel
+    runs in interpret mode) plans are made for ``CPU_INTERPRET``; any
+    other platform is an error."""
     import jax
     dev = jax.devices()[0]
     if dev.platform == "cpu":
@@ -144,11 +146,14 @@ def attached_chip() -> Chip:
             f"no Chip for platform {dev.platform!r}: the kernels run on a "
             "TPU, or interpreted on the CPU")
     try:
-        return DEVICE_KINDS[dev.device_kind]
+        chip = DEVICE_KINDS[dev.device_kind]
     except KeyError:
         raise ValueError(
             f"no Chip for device kind {dev.device_kind!r}; add its published "
             "figures to repro.core.hardware.DEVICE_KINDS") from None
+    limit = (dev.memory_stats() or {}).get("bytes_limit")
+    return dataclasses.replace(chip, hbm_bytes=float(limit)) if limit \
+        else chip
 
 
 def vmem_cache_budget(chip: Chip, working_set_bytes: float) -> float:

@@ -146,6 +146,46 @@ def fusion_schedule(steps: int, fuse_steps: int) -> list[tuple[int, int]]:
     return sched
 
 
+def _shift(x, axis: int, d: int):
+    """``x`` moved by ``d`` along ``axis``: ``out[..., c, ...] = x[..., c +
+    d, ...]`` where that exists, zero where it does not. One ``lax.pad``
+    with a negative edge, which XLA fuses into its consumer."""
+    cfg = [(0, 0, 0)] * x.ndim
+    cfg[axis] = (-d, d, 0)
+    return jax.lax.pad(x, jnp.zeros((), x.dtype), cfg)
+
+
+def _window_step(spec: StencilSpec, w, lo, H: int):
+    """One time step of the window ``w``, whose row 0 is global row ``lo``
+    of an ``H``-row domain.
+
+    The stencil sum (``StencilSpec.apply_rows``'s terms, in its order) and
+    the frozen cells, the global domain's first and last ``r`` rows and
+    the outer ``r`` cells along the other axes, are one elementwise
+    select over shifted views of ``w``, so XLA computes the step as one
+    loop fusion from ``w`` to its successor. The window keeps its extent:
+    its own outer rows read zeros past its edge, so each step spoils ``r``
+    more rows at each edge, for the caller to cut away once."""
+    r = spec.radius
+    acc = None
+    for off, wt in zip(spec.offsets, spec.weights):
+        term = w
+        for ax, d in enumerate(off):
+            if d:
+                term = _shift(term, ax, d)
+        term = wt * term
+        acc = term if acc is None else acc + term
+    rows = lo + jnp.arange(w.shape[0])
+    frozen = ((rows < r) | (rows >= H - r)).reshape(
+        (w.shape[0],) + (1,) * (w.ndim - 1))
+    for ax in range(1, w.ndim):
+        n = w.shape[ax]
+        idx = jnp.arange(n)
+        frozen = frozen | ((idx < r) | (idx >= n - r)).reshape(
+            (1,) * ax + (n,) + (1,) * (w.ndim - 1 - ax))
+    return jnp.where(frozen, w, acc.astype(w.dtype))
+
+
 def make_distributed_step(spec: StencilSpec, mesh: Mesh, axis: str = "data",
                           *, fuse_steps: int = 1):
     """``fuse_steps`` distributed time steps per halo exchange, inside
@@ -159,6 +199,10 @@ def make_distributed_step(spec: StencilSpec, mesh: Mesh, axis: str = "data",
     Dirichlet border is re-frozen after every inner application, so the
     fused step performs exactly the arithmetic of t exchanged steps
     (agreement to <= 2 ulp on real backends; see DESIGN.md §4).
+
+    Each application is one fused XLA loop from a window to the next
+    (``_window_step``), so a step holds no HBM temporaries beyond the
+    extended window and its successor.
     """
     r = spec.radius
     t = fuse_steps
@@ -167,22 +211,17 @@ def make_distributed_step(spec: StencilSpec, mesh: Mesh, axis: str = "data",
         h = x_l.shape[0]
         n = jax.lax.axis_size(axis)
         idx = jax.lax.axis_index(axis)
-        H = h * n                      # global leading extent
         top, bot = halo_exchange(x_l, r * t, axis)
         w = jnp.concatenate([top, x_l, bot], axis=0)
         lo = idx * h - r * t           # global row index of w[0] (<0 at edges)
+        # rows outside the domain (edge shards' zero-filled halo) fall
+        # under the frozen mask and only ever feed other frozen rows
         for _ in range(t):
-            L = w.shape[0]
-            upd = spec.apply_rows(w, r, L - r)
-            # freeze the first/last `r` rows of the *global* domain; rows
-            # outside the domain (edge shards' zero-filled halo) fall under
-            # the same mask and only ever feed other frozen rows.
-            rows = lo + r + jnp.arange(L - 2 * r)
-            frozen = (rows < r) | (rows >= H - r)
-            shape = (L - 2 * r,) + (1,) * (x_l.ndim - 1)
-            w = jnp.where(frozen.reshape(shape), w[r:L - r], upd)
-            lo = lo + r
-        return w
+            w = _window_step(spec, w, lo, h * n)
+        # the shard's rows, exact after t steps, cut out once: on a TPU a
+        # window cut short by a row offset is a copy of its own, and fused
+        # into the steps the cut made XLA keep every shifted view in HBM
+        return jax.lax.optimization_barrier(w)[r * t:r * t + h]
 
     pspec = P(axis, *([None] * (spec.ndim - 1)))
     return smap(local_step, mesh=mesh, in_specs=(pspec,),
@@ -257,6 +296,21 @@ class StencilProblem(Problem):
 
     def domain_bytes(self) -> int:
         return int(math.prod(self.x.shape)) * self.x.dtype.itemsize
+
+    def halo_split(self, plan, mesh) -> dict:
+        """How a distributed call under ``plan`` splits the field over
+        ``mesh``: ``shards``, ``shard_rows``, the ``halo_rows`` one
+        exchange sends each way (``radius * fuse_steps``), and the
+        ``halo_bytes`` that cross between chips in the call: each chunk of
+        ``fusion_schedule`` sends ``radius * chunk_t`` rows each way over
+        every one of the ``shards - 1`` boundaries."""
+        shards = int(dict(mesh.shape)[plan.shard_axis or "data"])
+        r = self.spec.radius
+        rows = sum(n * 2 * (shards - 1) * r * t
+                   for n, t in fusion_schedule(self.n_steps, plan.fuse_steps))
+        return dict(shards=shards, shard_rows=self.x.shape[0] // shards,
+                    halo_rows=r * plan.fuse_steps,
+                    halo_bytes=rows * self.domain_bytes() // self.x.shape[0])
 
     # -- batching -------------------------------------------------------------
 

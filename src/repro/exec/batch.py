@@ -188,6 +188,13 @@ class BatchedProblem(Problem):
     def halo_spec(self) -> Optional[HaloSpec]:
         return self.template.halo_spec()
 
+    def halo_split(self, plan, mesh) -> Optional[dict]:
+        # every instance's halo rides the same exchange
+        split = self.template.halo_split(plan, mesh)
+        if split is not None:
+            split = dict(split, halo_bytes=split["halo_bytes"] * self.batch)
+        return split
+
     def supports(self, tier: str) -> bool:
         return self.template.supports(tier)
 

@@ -118,11 +118,18 @@ def _dispatch(problem: Problem, plan: Plan, mesh, on_sync, tracer, track):
     label = problem.name if tracer.enabled else problem.kind
     spmv = (problem.spmv_format if plan.tier in ("host_loop", "device_loop")
             else None)
+    # a distributed call's split and halo traffic, from the plan as the
+    # barriers are (``Problem.halo_split``)
+    split = (problem.halo_split(plan, mesh)
+             if plan.tier == "distributed" and mesh is not None else None)
+    if split is not None:
+        obs.get_metrics().counter("executor_halo_bytes_total",
+                                  tier=plan.tier).inc(split.pop("halo_bytes"))
     with tracer.span(f"execute:{label}", cat="dispatch", track=track,
                      tier=plan.tier, fuse_steps=plan.fuse_steps,
                      batch=plan.batch, n_steps=problem.n_steps,
                      barriers=plan.barriers,
-                     **({"spmv": spmv} if spmv else {})):
+                     **({"spmv": spmv} if spmv else {}), **(split or {})):
         if plan.tier == "distributed":
             if mesh is None:
                 raise ValueError("distributed plan needs mesh=")

@@ -54,6 +54,19 @@ COLLECTIVE_LATENCY_S = 2e-6
 #: fitting in VMEM next to the resident rows.
 DEEP_MAX_FUSE = 32
 
+#: HBM a stencil tier holds besides its input and output fields, counted in
+#: fields: the loop tiers' XLA step keeps two domains (its interior sum and
+#: the re-framed result), the resident kernels at most one, and the
+#: distributed step two extended windows of its shard (the window and its
+#: successor, ``adapters.make_distributed_step``). Read from
+#: ``memory_analysis()`` of the programs compiled for a described v5e.
+HBM_TEMP_FIELDS = {"host_loop": 2, "device_loop": 2, "resident": 1,
+                   "distributed": 2}
+
+
+def _bytes(n: float) -> str:
+    return f"{n:,.0f} bytes ({n / 2**30:.2f} GiB)"
+
 
 def _as_chip(chip) -> Chip:
     if chip is None:
@@ -101,6 +114,15 @@ def _stencil_candidates(problem, chip: Chip, mesh, *, max_fuse: int,
     common = dict(n_steps=n, problem=name or problem.name, chip=chip.name,
                   batch=B)
 
+    # A candidate is offered only where its HBM footprint fits the chip:
+    # B instances' input and output fields and the tier's temporaries, of
+    # the whole domain on one chip, of one shard on a mesh.
+    def hbm_need(tier, field_bytes, temp_bytes):
+        return B * (2 * field_bytes + HBM_TEMP_FIELDS[tier] * temp_bytes)
+
+    def fits_hbm(tier):
+        return hbm_need(tier, domain_bytes, domain_bytes) <= chip.hbm_bytes
+
     # every instance's domain is independent, so memory traffic scales by
     # B; the per-dispatch launch overhead does NOT (the whole batch rides
     # one dispatch) — which is the entire economics of the batched tier.
@@ -110,6 +132,7 @@ def _stencil_candidates(problem, chip: Chip, mesh, *, max_fuse: int,
         Plan(tier="device_loop", predicted_s=B * base.t_total
              + DISPATCH_OVERHEAD_S, predicted_bound=base.bound, **common),
     ]
+    cands = [c for c in cands if fits_hbm(c.tier)]
 
     # RESIDENT × fuse depth: VMEM occupancy decides the resident rows per
     # depth (the wider streaming window of deeper fusion evicts planes).
@@ -135,7 +158,7 @@ def _stencil_candidates(problem, chip: Chip, mesh, *, max_fuse: int,
             <= chip_per_inst.onchip_bytes
 
     t = 1
-    while t <= max(1, min(max_fuse, n)):
+    while fits_hbm("resident") and t <= max(1, min(max_fuse, n)):
         sub = block_rows("shallow", t)
         if not fits(perks_vmem_bytes, sub, t):
             break
@@ -167,7 +190,8 @@ def _stencil_candidates(problem, chip: Chip, mesh, *, max_fuse: int,
     # overflow terminates the depth sweep (batches thus demote depth
     # before resident rows).
     t = 2
-    while t <= max(1, min(max(max_fuse, DEEP_MAX_FUSE), n)):
+    while fits_hbm("resident") and t <= max(
+            1, min(max(max_fuse, DEEP_MAX_FUSE), n)):
         deep_sub = block_rows("deep", t)
         if not fits(deep_vmem_bytes, deep_sub, t):
             break
@@ -188,12 +212,19 @@ def _stencil_candidates(problem, chip: Chip, mesh, *, max_fuse: int,
             predicted_bound=bound, **common))
         t *= 2
 
+    need, where = hbm_need("resident", domain_bytes, domain_bytes), "one chip"
     if mesh is not None:
         n_chips = int(dict(mesh.shape)[shard_axis])
         shard_rows = shape[0] // n_chips
         shard_bytes = shard_rows * row_bytes
+
+        def shard_need(t):      # the window grows by the t-step halo
+            return hbm_need("distributed", shard_bytes,
+                            shard_bytes + 2 * r * min(t, n) * row_bytes)
+        need, where = shard_need(1), f"each of {n_chips} chips"
         t = 1
-        while t <= max(1, min(max_fuse, n)) and r * min(t, n) <= shard_rows:
+        while (t <= max(1, min(max_fuse, n)) and r * min(t, n) <= shard_rows
+               and shard_need(t) <= chip.hbm_bytes):
             barriers = math.ceil(n / t)
             gm = gm_bytes_fused(n, shard_bytes, 0, row_bytes=row_bytes,
                                 radius=r, fuse_steps=t)
@@ -208,6 +239,13 @@ def _stencil_candidates(problem, chip: Chip, mesh, *, max_fuse: int,
                 predicted_bound="collective" if coll > B * gm / chip.hbm_bw
                 else "main_memory", **common))
             t *= 2
+    if not cands:
+        raise ValueError(
+            f"{name or problem.name}: a {'x'.join(map(str, shape))} "
+            f"{problem.x.dtype} field (batch {B}) needs at least "
+            f"{_bytes(need)} of HBM on {where}, its fields and the tier's "
+            f"temporaries, and {chip.name} has {_bytes(chip.hbm_bytes)}"
+            + ("" if mesh is not None else "; pass mesh= to shard it"))
     return cands
 
 
@@ -444,7 +482,10 @@ def plan_candidates(problem: Problem, *, chip=None, mesh=None,
     ``PROXY_ONCHIP_BYTES`` regime); ``mesh`` enables distributed
     candidates over ``shard_axis``; ``max_fuse`` caps temporal blocking;
     ``sub_rows`` fixes the resident stencil's streamed block rows (planned
-    per schedule and depth when omitted).
+    per schedule and depth when omitted). No stencil candidate is offered
+    whose HBM footprint exceeds the chip's ``hbm_bytes`` (per shard on a
+    mesh; ``HBM_TEMP_FIELDS``); where none fits, a ``ValueError`` names
+    the footprint and the limit.
 
     ``batch`` plans for B instances served by ONE dispatch
     (``repro.exec.batch``): per-step traffic and per-instance VMEM

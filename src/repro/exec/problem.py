@@ -167,6 +167,12 @@ class Problem(abc.ABC):
         """Total bytes of the per-step working set (for planner reporting)."""
         return sum(a.bytes for a in self.cacheable_arrays())
 
+    def halo_split(self, plan, mesh) -> Optional[dict]:
+        """How a distributed call under ``plan`` splits the problem over
+        ``mesh`` and the halo bytes it exchanges (``StencilProblem``); None
+        where the problem exchanges no halo (its barrier is a reduction)."""
+        return None
+
     # -- batching surface (repro.exec.batch) ----------------------------------
 
     def payload(self) -> Any:

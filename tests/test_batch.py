@@ -276,12 +276,14 @@ def test_planner_per_instance_cache_shrinks_with_batch():
     """VMEM/B per instance: larger batches never cache MORE rows per
     instance, and eventually demote the resident tier's residency. Once
     not even the kernel's streaming buffers fit an instance's share, the
-    resident candidate is gone (counted as zero rows)."""
+    resident candidate is gone (counted as zero rows). Past what HBM holds
+    (256 instances of 32 MiB, their fields and temporaries) no plan is
+    offered at all."""
     spec = get_spec("2d9pt")
     problem = StencilProblem(
         jax.ShapeDtypeStruct((4096, 2048), jnp.float32), spec, 100)
     prev = None
-    for b in (1, 4, 16, 64, 256):
+    for b in (1, 4, 16, 64):
         cands = plan_candidates(problem, batch=b)
         assert all(c.batch == b for c in cands)
         res = next((c for c in cands
@@ -291,6 +293,8 @@ def test_planner_per_instance_cache_shrinks_with_batch():
             assert rows <= prev, (b, res)
         prev = rows
     assert prev == 0    # the sweep must reach full demotion
+    with pytest.raises(ValueError, match="of HBM on one chip"):
+        plan_candidates(problem, batch=256)
 
 
 def test_autotune_batch_sweep_returns_per_width_winners():

@@ -425,7 +425,9 @@ def test_attached_chip_resolves_device_kind(platform, kind, want,
                                             monkeypatch):
     from types import SimpleNamespace
     from repro.core.hardware import attached_chip
-    dev = SimpleNamespace(platform=platform, device_kind=kind)
+    limit = 16_911_433_728          # 15.75 GiB, a v5e's usable HBM
+    dev = SimpleNamespace(platform=platform, device_kind=kind,
+                          memory_stats=lambda: {"bytes_limit": limit})
     monkeypatch.setattr(jax, "devices", lambda *a: [dev])
     if want is None:
         with pytest.raises(ValueError, match=kind if platform == "tpu"
@@ -435,3 +437,6 @@ def test_attached_chip_resolves_device_kind(platform, kind, want,
         chip = attached_chip()
         assert chip.name == want
         assert chip.interpret == (platform == "cpu")
+        # the device's usable HBM on a chip; the published figure planned
+        # for on the CPU
+        assert chip.hbm_bytes == (limit if platform == "tpu" else 16 * 2**30)

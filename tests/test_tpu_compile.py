@@ -13,8 +13,10 @@ chunk does not fill the 128-lane tile, nor a stencil kernel over a domain
 whose rows are not a whole number of tiles; the tests below pin that the
 kernels refuse them there and that the planner, given the v5e, never
 offers them. CG's loop-tier chunk on a structured operator compiles with
-no gather at all (the DIA SpMV).
+no gather at all (the DIA SpMV). The distributed stencil tier, compiled
+for the four chips at the four-chip cell's size, fits each chip's HBM.
 """
+import dataclasses
 import functools
 import os
 
@@ -22,9 +24,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import perks
+from repro.core.hardware import TPU_V5E
 from repro.exec import BiCGStabProblem, CGProblem, Plan, execute, plan
 from repro.exec import plan_candidates
 from repro.exec import StencilProblem
@@ -229,3 +233,26 @@ def test_tpu_planner_offers_resident_stencil_only_for_tiled_rows(
             s2d.stencil_perks(jnp.zeros((rows, 8), jnp.float32), sp,
                               steps=2, cached_rows=0, sub_rows=64,
                               interpret=False)
+
+
+@pytest.mark.parametrize("fuse", [1, 2, 4])
+def test_distributed_stencil_temporaries_fit_v5e(topo, fuse):
+    """65536x32768 f32 (8 GiB) row-sharded over the four chips, 64 steps:
+    the planner, given a v5e's usable 15.75 GiB, offers only distributed
+    plans, and each compiles to at most three 2 GiB shards of HBM
+    temporaries a chip besides its field (donated, so its output)."""
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("data",))
+    sharded = NamedSharding(mesh, P("data", None))
+    x = jax.ShapeDtypeStruct((65536, 32768), jnp.float32, sharding=sharded)
+    sp = get_spec("2d5pt")
+    usable = dataclasses.replace(TPU_V5E, hbm_bytes=15.75 * 2**30)
+    cands = plan_candidates(StencilProblem(x, sp, 64), chip=usable,
+                            mesh=mesh)
+    assert {c.tier for c in cands} == {"distributed"}
+    p = next(c for c in cands if c.fuse_steps == fuse)
+    run = jax.jit(lambda a: execute(StencilProblem(a, sp, 64), p, mesh=mesh),
+                  out_shardings=sharded, donate_argnums=0)
+    mem = run.lower(x).compile().memory_analysis()
+    shard = 16384 * 32768 * 4
+    assert mem.argument_size_in_bytes == shard
+    assert mem.temp_size_in_bytes <= 3 * shard
