@@ -34,19 +34,29 @@ All tiers compute bit-identical results for the same step function (the
 barrier semantics of the host loop are preserved: step k+1 only ever sees
 completed step-k output), which the test-suite asserts.
 
-The loops carry the program's spans into the profiler trace (DESIGN.md
-§11): every dispatch of a runner runs under ``repro.compile`` (its first)
-or ``repro.chunk`` (the rest), and every host sync under
-``repro.barrier``. Given a metrics registry, they count the steps, host
-syncs and barriers that ran.
+The loops build each runner once per step *function*: a runner is cached
+by ``(step_fn, steps, donate)``, and the arrays the step reads come in as
+``operands``, jit arguments rather than constants, so a solver that runs
+one step structure over new data dispatches the executable it already
+has. The loops carry the program's spans into the profiler trace
+(DESIGN.md §11): a dispatch that traces and compiles runs under
+``repro.compile``, every other one under ``repro.chunk``, and every host
+sync under ``repro.barrier``. Given a metrics registry, they count the
+steps, host syncs and barriers that ran, and the runners built and
+reused.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import enum
+import functools
+import threading
+import weakref
 from typing import Any, Callable, Optional
 
 import jax
+import jax.numpy as jnp
 
 from repro import obs
 
@@ -92,11 +102,7 @@ class PerksConfig:
             raise ValueError(f"sync_every must be >= 1, got {self.sync_every}")
 
 
-StepFn = Callable[[Any], Any]  # state -> state
-
-
-def _jit_step(step_fn: StepFn, donate: bool):
-    return jax.jit(step_fn, donate_argnums=(0,) if donate else ())
+StepFn = Callable[..., Any]  # (*operands, state) -> state
 
 
 def _own(state):
@@ -106,17 +112,103 @@ def _own(state):
         lambda a: a.copy() if isinstance(a, jax.Array) else a, state)
 
 
+def _signature(tree):
+    """The abstract signature a jitted call is compiled for: the tree's
+    structure, and each leaf's type and, for a concrete array, its
+    placement."""
+    leaves, treedef = jax.tree.flatten(tree)
+    return treedef, tuple(
+        (jax.typeof(a), None if isinstance(a, jax.core.Tracer)
+         else getattr(a, "sharding", None)) for a in leaves)
+
+
+class _Runner:
+    """``step(*operands, state)`` applied ``n_steps`` times by ``fori_loop``
+    (once, with no loop, where ``n_steps`` is None) in one jitted program
+    of ``(operands, state)``. It donates ``state`` when asked, with NO
+    defensive copy (callers own protecting theirs), and never
+    ``operands``, which the caller's next solve reads again.
+
+    ``step_ref()`` returns the step: the cache holds it weakly, and a
+    dispatch holds it while it traces."""
+
+    def __init__(self, step_ref: Callable[[], StepFn],
+                 n_steps: Optional[int], donate: bool):
+        def run_all(operands, state):
+            step = step_ref()
+            if n_steps is None:
+                return step(*operands, state)
+            return jax.lax.fori_loop(0, n_steps,
+                                     lambda _, s: step(*operands, s), state)
+
+        self.jitted = jax.jit(run_all, donate_argnums=(1,) if donate else ())
+        self._built: set = set()
+
+    def builds(self, operands, state) -> bool:
+        """Whether dispatching ``(operands, state)`` traces and compiles:
+        the first time this runner sees its abstract signature."""
+        sig = _signature((operands, state))
+        if sig in self._built:
+            return False
+        self._built.add(sig)
+        return True
+
+
+#: Loop runners, most recently used last: ``(id(step_fn), n_steps,
+#: donate) -> (weak reference to step_fn, _Runner)``. An entry dies with
+#: its step function, so a caller that passes a fresh closure every call
+#: pins nothing it captured; none holds an operand.
+_RUNNERS: "collections.OrderedDict[tuple, tuple]" = collections.OrderedDict()
+_RUNNERS_SIZE = 32
+_RUNNERS_LOCK = threading.Lock()
+
+
+def _forget(key, ref) -> None:
+    # a weakref callback: it may run inside a collection anywhere, so it
+    # takes no lock and pops only its own entry
+    entry = _RUNNERS.get(key)
+    if entry is not None and entry[0] is ref:
+        _RUNNERS.pop(key, None)
+
+
+def _fused_runner(step_fn: StepFn, n_steps: Optional[int],
+                  donate: bool) -> _Runner:
+    """The runner of ``step_fn^n_steps``, built on the first request for
+    this step function and reused while it lives (``_RUNNERS``). A step
+    that takes no weak reference gets a runner of its own, uncached."""
+    key = (id(step_fn), n_steps, donate)
+    with _RUNNERS_LOCK:
+        hit = _RUNNERS.get(key)
+        if hit is not None and hit[0]() is step_fn:
+            _RUNNERS.move_to_end(key)
+            return hit[1]
+        try:
+            ref = weakref.ref(step_fn, functools.partial(_forget, key))
+        except TypeError:
+            return _Runner(lambda: step_fn, n_steps, donate)
+        runner = _Runner(ref, n_steps, donate)
+        _RUNNERS[key] = (ref, runner)
+        if len(_RUNNERS) > _RUNNERS_SIZE:
+            _RUNNERS.popitem(last=False)
+        return runner
+
+
 class _Observed:
     """One loop's spans, and its counters where a registry is given.
 
-    A dispatch runs under ``repro.compile`` if it is its runner's first
-    (trace, lower, compile or cache load, then enqueue) and under
-    ``repro.chunk`` otherwise, with stat ``steps``; a host sync (the
-    read-back and decision of ``on_sync`` / ``on_barrier``) runs under
-    ``repro.barrier``, with stat ``steps_done``. The counters are labelled
-    with the loop's tier. A host-loop barrier is a dispatch; a device-loop
-    barrier is a step, since the loop-carried dependency is the
-    device-wide barrier.
+    A dispatch runs under ``repro.compile`` where it builds (traces,
+    lowers, compiles or loads from the compilation cache, then enqueues):
+    the first dispatch of a runner for an abstract signature it has not
+    seen. Every other dispatch runs under ``repro.chunk``, with stat
+    ``steps``, the first of a later solve on a cached runner included. A
+    host sync (the read-back and decision of ``on_sync`` /
+    ``on_barrier``) runs under ``repro.barrier``, with stat
+    ``steps_done``. The counters are labelled with the loop's tier. A
+    host-loop barrier is a dispatch; a device-loop barrier is a step,
+    since the loop-carried dependency is the device-wide barrier.
+    ``executor_retraces_total`` counts the dispatches that build and
+    ``executor_runner_hits_total`` the runners whose first dispatch in
+    this loop reused an executable.
     """
 
     def __init__(self, name: str, execution: Execution, metrics=None):
@@ -131,21 +223,37 @@ class _Observed:
                                          tier=tier)
             self.barriers = metrics.counter("executor_barriers_total",
                                             tier=tier)
+            self.retraces = metrics.counter("executor_retraces_total",
+                                            tier=tier)
+            self.hits = metrics.counter("executor_runner_hits_total",
+                                        tier=tier)
 
-    def runner(self, jitted, steps: int):
-        """``jitted`` (``steps`` fused steps a call), dispatched under its
-        span and counted."""
+    def runner(self, step_fn: StepFn, operands: tuple,
+               n_steps: Optional[int], donate: bool):
+        """The cached runner of ``n_steps`` fused steps of ``step_fn``
+        (one step, unlooped, where None) over ``operands``, dispatched
+        under its span and counted."""
+        runner = _fused_runner(step_fn, n_steps, donate)
+        operands = jax.tree.map(jnp.asarray, tuple(operands))
+        steps = n_steps or 1
         first = True
 
-        def dispatch(state):
+        def dispatch(state, _step=step_fn):  # holds what the runner traces
             nonlocal first
-            cat, first = ("compile" if first else "chunk"), False
+            # a loop's later dispatches carry the state its first returned
+            build = first and runner.builds(operands, state)
+            cat = "compile" if build else "chunk"
             with obs.get_tracer().span(self.name, cat=cat, track=self.track,
                                        steps=steps):
-                state = jitted(state)
+                state = runner.jitted(operands, state)
             if self.counted:
                 self.steps.inc(steps)
                 self.barriers.inc(steps if self.barrier_per_step else 1)
+                if build:
+                    self.retraces.inc()
+                elif first:
+                    self.hits.inc()
+            first = False
             return state
 
         return dispatch
@@ -165,6 +273,7 @@ def host_loop(
     donate: bool = True,
     on_sync: Optional[Callable[[Any, int], bool]] = None,
     metrics=None,
+    operands: tuple = (),
 ) -> Callable[[Any], Any]:
     """Baseline execution: one device dispatch per time step.
 
@@ -174,9 +283,11 @@ def host_loop(
     each one; returning True stops early (the baseline tier honors a
     convergence contract at the finest possible cadence). ``metrics`` (a
     ``repro.obs.MetricsRegistry``) counts what ran (see ``_Observed``).
+    ``operands`` are the step's leading arguments, ``step_fn(*operands,
+    state)``: arrays passed to every dispatch, never donated.
     """
     seen = _Observed("host_loop", Execution.HOST_LOOP, metrics)
-    dispatch = seen.runner(_jit_step(step_fn, donate), 1)
+    dispatch = seen.runner(step_fn, operands, None, donate)
 
     def run(state):
         if donate:
@@ -193,18 +304,8 @@ def host_loop(
     return run
 
 
-def _fused_runner(step_fn: StepFn, n_steps: int, donate: bool):
-    """Jitted ``step_fn^n_steps`` via fori_loop; donates its input buffers
-    when asked, with NO defensive copy — callers own protecting theirs."""
-
-    def run_all(state):
-        return jax.lax.fori_loop(0, n_steps, lambda _, s: step_fn(s), state)
-
-    return jax.jit(run_all, donate_argnums=(0,) if donate else ())
-
-
 def device_loop(step_fn: StepFn, n_steps: int, *, donate: bool = True,
-                metrics=None) -> Callable[[Any], Any]:
+                metrics=None, operands: tuple = ()) -> Callable[[Any], Any]:
     """PERKS control-flow transform: the whole time loop in one dispatch.
 
     ``grid.sync()`` of the paper corresponds to the loop-carried data
@@ -214,7 +315,7 @@ def device_loop(step_fn: StepFn, n_steps: int, *, donate: bool = True,
     which is exactly the device-wide barrier semantics PERKS relies on.
     """
     seen = _Observed("device_loop", Execution.DEVICE_LOOP, metrics)
-    dispatch = seen.runner(_fused_runner(step_fn, n_steps, donate), n_steps)
+    dispatch = seen.runner(step_fn, operands, n_steps, donate)
     return (lambda state: dispatch(_own(state))) if donate else dispatch
 
 
@@ -228,6 +329,7 @@ def chunked_loop(
     on_barrier: Optional[Callable[[Any, int], tuple[Any, bool]]] = None,
     execution: Execution = Execution.DEVICE_LOOP,
     metrics=None,
+    operands: tuple = (),
 ) -> Callable[[Any], Any]:
     """PERKS with periodic host synchronisation.
 
@@ -250,14 +352,14 @@ def chunked_loop(
 
     ``execution`` is HOST_LOOP where the chunks stand for fused host-loop
     steps (``persistent`` with ``fuse_steps`` > 1): each dispatch is then
-    one barrier. ``metrics`` counts what ran (see ``_Observed``).
+    one barrier. ``metrics`` counts what ran (see ``_Observed``);
+    ``operands`` are the step's leading arguments (see ``host_loop``).
     """
     # The loop below already owns `state` (one defensive copy at entry), so
     # the inner runners donate WITHOUT re-copying per dispatch — each chunk
     # updates the same buffers in place, as the persistent scheme intends.
     seen = _Observed("chunked_loop", execution, metrics)
-    inner = seen.runner(_fused_runner(step_fn, sync_every, donate),
-                        sync_every)
+    inner = seen.runner(step_fn, operands, sync_every, donate)
 
     if n_steps is None:
         if on_barrier is None:
@@ -280,8 +382,7 @@ def chunked_loop(
         return run_open
 
     rem = n_steps % sync_every
-    inner_rem = (seen.runner(_fused_runner(step_fn, rem, donate), rem)
-                 if rem else None)
+    inner_rem = seen.runner(step_fn, operands, rem, donate) if rem else None
 
     def run(state):
         if donate:
@@ -313,6 +414,7 @@ def persistent(
     *,
     on_sync: Optional[Callable[[Any, int], bool]] = None,
     metrics=None,
+    operands: tuple = (),
 ) -> Callable[[Any], Any]:
     """Build a runner for ``n_steps`` applications of ``step_fn`` under the
     requested execution tier. The RESIDENT tier is kernel-specific and is
@@ -328,24 +430,23 @@ def persistent(
     wide-halo exchange / multi-step HBM passes instead.
 
     ``metrics`` (a ``repro.obs.MetricsRegistry``) counts the steps, host
-    syncs and barriers that ran, under the tier's name.
+    syncs and barriers that ran, and the runners built and reused, under
+    the tier's name. ``operands`` are ``step_fn``'s leading arguments,
+    ``step_fn(*operands, state)``: the arrays the step reads, passed to
+    each dispatch so that the runner, cached by ``step_fn``, serves new
+    arrays of the same shapes without a build.
     """
+    kw = dict(donate=config.donate, metrics=metrics, operands=operands)
     if config.execution == Execution.HOST_LOOP:
         if config.fuse_steps > 1:
             return chunked_loop(
                 step_fn, n_steps, sync_every=config.fuse_steps,
-                donate=config.donate, on_sync=on_sync,
-                execution=Execution.HOST_LOOP, metrics=metrics,
-            )
-        return host_loop(step_fn, n_steps, donate=config.donate,
-                         on_sync=on_sync, metrics=metrics)
+                on_sync=on_sync, execution=Execution.HOST_LOOP, **kw)
+        return host_loop(step_fn, n_steps, on_sync=on_sync, **kw)
     if config.sync_every is not None and config.sync_every < n_steps:
-        return chunked_loop(
-            step_fn, n_steps, sync_every=config.sync_every,
-            donate=config.donate, on_sync=on_sync, metrics=metrics,
-        )
-    return device_loop(step_fn, n_steps, donate=config.donate,
-                       metrics=metrics)
+        return chunked_loop(step_fn, n_steps, sync_every=config.sync_every,
+                            on_sync=on_sync, **kw)
+    return device_loop(step_fn, n_steps, **kw)
 
 
 def scan_loop(

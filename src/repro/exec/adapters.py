@@ -86,8 +86,9 @@ def _concrete(a) -> bool:
 
 
 def dia_operator(data, cols):
-    """The DIA form ``(offsets, device planes)`` of the ELL operator
-    ``(data, cols)``, or None where the gather stays: abstract operands
+    """The DIA form ``(offsets, planes)``, one device array a diagonal,
+    of the ELL operator ``(data, cols)``, or None where the gather stays:
+    abstract operands
     (tracers, ``ShapeDtypeStruct`` planner probes), operands on several
     devices, and operators whose nonzeros do not pay in DIA
     (``kernels.spmv_dia.ell_to_dia``).
@@ -117,15 +118,46 @@ def dia_operator(data, cols):
         return dia
 
 
+@functools.lru_cache(maxsize=32)
+def krylov_step(iteration, spmv: Optional[str], precision: str,
+                offsets: Optional[tuple[int, ...]] = None, matvec=None,
+                **static):
+    """The loop-tier step ``fn(operands, state)`` of a Krylov method,
+    one function per static structure, so the executor's cached runner
+    serves every solve over operators of that structure.
+
+    ``fn`` runs ``iteration(state, mv, dot=..., **static)`` with the
+    reduction of ``precision`` and the SpMV ``mv`` that ``spmv`` names:
+    "dia", over the DIA planes at ``offsets`` (``operands``); "ell", the
+    gather over ``operands = (data, cols)``; None, ``matvec`` (no
+    operands). The memo holds its arguments, at most 32 ``matvec``
+    callables among them."""
+    dot = dot_for(precision)
+
+    def step(operands, state):
+        if spmv == "dia":
+            mv = functools.partial(spmv_dia, operands, offsets)
+        elif spmv == "ell":
+            mv = functools.partial(kref.spmv_ell, *operands)
+        else:
+            mv = matvec
+        return iteration(state, mv, dot=dot, **static)
+
+    return step
+
+
 def _convert(data, cols):
     obs.get_metrics().counter("spmv_dia_conversions_total").inc()
     dia = ell_to_dia(data, cols)
     if dia is None:
         return None
     offsets, planes = dia
+    # one array a diagonal: the step takes the planes as an argument, and
+    # on a TPU a row of one (D, n) array is a relayout copy at every read
     if isinstance(data, jax.Array):
-        return offsets, jax.device_put(planes, data.sharding)
-    return offsets, jnp.asarray(planes)
+        return offsets, tuple(jax.device_put(p, data.sharding)
+                              for p in planes)
+    return offsets, tuple(jnp.asarray(p) for p in planes)
 
 
 # =============================================================================
@@ -560,16 +592,14 @@ class CGProblem(Problem):
             return None
         return "ell" if dia_operator(self.data, self.cols) is None else "dia"
 
-    def step_fn(self):
-        dot = dot_for(self.precision)
+    def step(self):
+        it, prec = kref.cg_iteration_matvec, self.precision
         if self.matvec is not None:
-            mv = self.matvec
-        elif (dia := dia_operator(self.data, self.cols)) is not None:
+            return krylov_step(it, None, prec, matvec=self.matvec), ()
+        if (dia := dia_operator(self.data, self.cols)) is not None:
             offsets, planes = dia
-            mv = functools.partial(spmv_dia, planes, offsets)
-        else:
-            mv = functools.partial(kref.spmv_ell, self.data, self.cols)
-        return lambda s: kref.cg_iteration_matvec(s, mv, dot=dot)
+            return krylov_step(it, "dia", prec, offsets), planes
+        return krylov_step(it, "ell", prec), (self.data, self.cols)
 
     def finalize(self, state):
         return state[0], state[3]

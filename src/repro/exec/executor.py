@@ -142,9 +142,11 @@ def _dispatch(problem: Problem, plan: Plan, mesh, on_sync, tracer, track):
                                 sync_every=plan.sync_every,
                                 fuse_steps=plan.fuse_steps)
         metrics = obs.get_metrics()
-        runner = perks.persistent(problem.step_fn(), problem.n_steps, cfg,
-                                  on_sync=on_sync, metrics=metrics)
-        metrics.counter("executor_retraces_total", tier=plan.tier).inc()
+        # the runner is cached by the step function; the operator comes in
+        # as an argument, so a later solve over it builds nothing
+        fn, operands = problem.step()
+        runner = perks.persistent(fn, problem.n_steps, cfg, on_sync=on_sync,
+                                  metrics=metrics, operands=(operands,))
         if spmv:
             metrics.counter("executor_spmv_total", format=spmv).inc()
         return problem.finalize(runner(problem.initial_state()))

@@ -54,9 +54,10 @@ from repro.exec.adapters import (
     _operand_sig,
     fused_block_rows,
     fusion_schedule,
+    krylov_step,
     operator_fingerprint,
 )
-from repro.exec.precision import PRECISIONS, dot_for
+from repro.exec.precision import PRECISIONS
 from repro.exec.problem import HaloSpec, Problem
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
@@ -180,15 +181,11 @@ class BiCGStabProblem(Problem):
     def initial_state(self):
         return kref.bicgstab_initial_state(self.b)
 
-    def _matvec(self):
+    def step(self):
+        it, prec = kref.bicgstab_iteration_matvec, self.precision
         if self.matvec is not None:
-            return self.matvec
-        return functools.partial(kref.spmv_ell, self.data, self.cols)
-
-    def step_fn(self):
-        mv = self._matvec()
-        dot = dot_for(self.precision)
-        return lambda s: kref.bicgstab_iteration_matvec(s, mv, dot=dot)
+            return krylov_step(it, None, prec, matvec=self.matvec), ()
+        return krylov_step(it, "ell", prec), (self.data, self.cols)
 
     def finalize(self, state):
         return state[0], state[8]
@@ -310,6 +307,13 @@ def gmres_distributed(data, cols, b, cycles: int, m: int, mesh: Mesh, *,
     return state
 
 
+def _gmres_cycle(state, mv, *, dot, m: int):
+    """One restart cycle over the state ``(x, rr, b)``."""
+    x, rr, b = state
+    x, rr = kref.gmres_cycle_matvec((x, rr), mv, b, m, dot=dot)
+    return (x, rr, b)
+
+
 @dataclasses.dataclass(frozen=True, eq=False)
 class GMRESProblem(Problem):
     """Restarted GMRES(m); one executor step = one restart cycle.
@@ -370,22 +374,13 @@ class GMRESProblem(Problem):
     def initial_state(self):
         return (jnp.zeros_like(self.b), jnp.vdot(self.b, self.b), self.b)
 
-    def _matvec(self):
+    def step(self):
+        prec, m = self.precision, self.m
         if self.matvec is not None:
-            return self.matvec
-        return functools.partial(kref.spmv_ell, self.data, self.cols)
-
-    def step_fn(self):
-        mv = self._matvec()
-        m = self.m
-        dot = dot_for(self.precision)
-
-        def cycle(state):
-            x, rr, b = state
-            x, rr = kref.gmres_cycle_matvec((x, rr), mv, b, m, dot=dot)
-            return (x, rr, b)
-
-        return cycle
+            return krylov_step(_gmres_cycle, None, prec, matvec=self.matvec,
+                               m=m), ()
+        return krylov_step(_gmres_cycle, "ell", prec, m=m), (self.data,
+                                                            self.cols)
 
     def finalize(self, state):
         return state[0], state[1]

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import abc
 import dataclasses
+import functools
 import zlib
 from typing import Any, Callable, Optional, Sequence
 
@@ -88,10 +89,11 @@ class HaloSpec:
 class Problem(abc.ABC):
     """One iterative workload, described for the PERKS executor.
 
-    Subclasses (adapters) must provide the four abstract pieces; the tier
-    hooks ``run_resident``/``run_distributed`` raise by default — a
-    problem that does not override them simply does not support the tier
-    (``supports`` reports which do).
+    Subclasses (adapters) must provide the three abstract pieces and the
+    step, as :meth:`step` or as :meth:`step_fn` (each is derived from the
+    other); the tier hooks ``run_resident``/``run_distributed`` raise by
+    default — a problem that does not override them simply does not
+    support the tier (``supports`` reports which do).
     """
 
     #: problem family, used by the planner to pick a candidate generator
@@ -107,15 +109,38 @@ class Problem(abc.ABC):
     #: the executor counts (``executor_spmv_total``); None = no SpMV
     spmv_format: Optional[str] = None
 
+    def __init_subclass__(cls, **kwargs):
+        super().__init_subclass__(**kwargs)
+        if cls.step is Problem.step and cls.step_fn is Problem.step_fn:
+            raise TypeError(f"{cls.__name__} must define step() or "
+                            f"step_fn()")
+
     # -- required surface -----------------------------------------------------
 
     @abc.abstractmethod
     def initial_state(self) -> Any:
         """The state fed to the first step (a pytree of arrays)."""
 
-    @abc.abstractmethod
+    def step(self) -> tuple[Callable[[Any, Any], Any], Any]:
+        """The step apart from the arrays it reads: ``(fn, operands)``,
+        ``fn(operands, state) -> state`` one iteration and ``operands`` the
+        pytree of arrays it reads (an operator's planes).
+
+        The loop tiers cache their runner by ``fn`` and pass ``operands``
+        as an argument, so ``fn`` must be the same object for every
+        problem of one static structure (a module-level function, or one
+        memoised on hashable values) and close over no array. Defaults to
+        :meth:`step_fn` with no operands: a new ``fn`` each call, which
+        builds its runner each call."""
+        step_fn = self.step_fn()
+        return (lambda operands, state: step_fn(state)), ()
+
     def step_fn(self) -> Callable[[Any], Any]:
-        """The pure step function ``state -> state`` (one iteration)."""
+        """The pure step function ``state -> state`` (one iteration), the
+        surface of ``vmap`` and the services. Defaults to :meth:`step`
+        with its operands bound."""
+        fn, operands = self.step()
+        return functools.partial(fn, operands)
 
     @abc.abstractmethod
     def cacheable_arrays(self, *, fuse_steps: int = 1) -> Sequence[CacheableArray]:

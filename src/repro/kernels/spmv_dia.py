@@ -60,11 +60,11 @@ def ell_to_dia(data, cols) -> Optional[tuple[tuple[int, ...], np.ndarray]]:
     return tuple(int(d) for d in offsets), planes
 
 
-def spmv_dia(planes: jax.Array, offsets: tuple[int, ...],
-             x: jax.Array) -> jax.Array:
-    """y = A @ x for A in DIA form: ``y[i] = sum_j planes[j, i] *
+def spmv_dia(planes, offsets: tuple[int, ...], x: jax.Array) -> jax.Array:
+    """y = A @ x for A in DIA form: ``y[i] = sum_j planes[j][i] *
     x[i + offsets[j]]``, with ``x`` zero-padded by the widest offset on
-    each side and one static slice per diagonal."""
+    each side and one static slice per diagonal. ``planes`` is a ``(D,
+    n)`` array or a sequence of ``D`` rows."""
     n = x.shape[0]
     m = max(abs(d) for d in offsets)
     xp = jnp.pad(x, (m, m))

@@ -40,6 +40,17 @@ def _fresh_deprecation_state():
     reset_warnings()
 
 
+@pytest.fixture
+def fresh_runners():
+    """An empty loop-runner cache (``core.perks._RUNNERS``), so a test that
+    asserts a runner's build (``repro.compile``) holds whatever its worker
+    ran before it."""
+    from repro.core import perks
+
+    perks._RUNNERS.clear()
+    yield
+
+
 def run_multi_device(code: str, n_dev: int = 8, timeout: int = 360) -> dict:
     """Run ``code`` in a subprocess with ``n_dev`` fake CPU devices.
 

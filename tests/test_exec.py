@@ -13,6 +13,7 @@ Covers the tentpole contracts:
 * every legacy ``run_*`` shim warns exactly once per entry point;
 * ``autotune`` measures the candidates and returns a member of them.
 """
+import gc
 import warnings
 
 import jax
@@ -20,8 +21,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
+from repro.core import perks
 from repro.core.hardware import CHIPS
 from repro.exec import (
+    BiCGStabProblem,
     CGProblem,
     CacheDecision,
     Plan,
@@ -31,6 +35,7 @@ from repro.exec import (
     plan,
     plan_candidates,
 )
+from repro.exec import adapters
 from repro.exec.deprecation import reset_warnings
 from repro.kernels.common import BENCHMARKS, get_spec
 from repro.solvers import cg as cgs
@@ -440,3 +445,105 @@ def test_attached_chip_resolves_device_kind(platform, kind, want,
         # the device's usable HBM on a chip; the published figure planned
         # for on the CPU
         assert chip.hbm_bytes == (limit if platform == "tpu" else 16 * 2**30)
+
+
+# -- the cached loop runner ----------------------------------------------------
+
+RUNNER_CASES = {
+    "cg-dia": (CGProblem, "poisson_64", "dia"),
+    "cg-ell": (CGProblem, "graph_regular_4k", "ell"),
+    "bicgstab": (BiCGStabProblem, "poisson_64", None),
+}
+SYNCED = Plan(tier="device_loop", sync_every=20)
+
+
+def _krylov(cls, data, cols, seed):
+    """60 iterations, synced every 20 against a tolerance never met."""
+    b = jax.random.normal(jax.random.key(seed), (data.shape[0],),
+                          jnp.float32)
+    return cls.from_ell(data, cols, b, 60, tol=1e-30)
+
+
+def _assert_same(got, want):
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("case", sorted(RUNNER_CASES))
+def test_second_solve_over_one_operator_reuses_the_runner(case,
+                                                          fresh_runners):
+    """Two solves over one operator with different right-hand sides: the
+    runner is built once, the second solve dispatches it with no
+    ``repro.compile``, and its answer is bit for bit a cold run's."""
+    cls, name, spmv = RUNNER_CASES[case]
+    data, cols = cgs.load_dataset(name)
+    first = _krylov(cls, data, cols, 0)
+    if cls is CGProblem:
+        assert first.spmv_format == spmv
+    reg, tr = obs.MetricsRegistry(), obs.Tracer()
+    with obs.use_metrics(reg):
+        execute(first, SYNCED)
+        with obs.use_tracer(tr):
+            warm = execute(_krylov(cls, data, cols, 1), SYNCED)
+    assert reg.value("executor_retraces_total", tier="device_loop") == 1
+    assert reg.value("executor_runner_hits_total", tier="device_loop") == 1
+    assert tr.by_cat("compile") == []
+    assert [dict(e.args)["steps"] for e in tr.by_cat("chunk")] == [20] * 3
+
+    perks._RUNNERS.clear()
+    cold_reg = obs.MetricsRegistry()
+    with obs.use_metrics(cold_reg):
+        cold = execute(_krylov(cls, data, cols, 1), SYNCED)
+    assert cold_reg.value("executor_retraces_total", tier="device_loop") == 1
+    _assert_same(warm, cold)
+
+
+def test_a_same_shaped_operator_gets_its_own_answer(fresh_runners):
+    """Another operator of the same structure reuses the executable, and
+    its values, passed as arguments, give its own answer: 2A x = b is
+    solved by half of A's x, bit for bit as a cold run solves it."""
+    data, cols = cgs.load_dataset("poisson_64")
+    data2 = data * 2.0
+    b = jax.random.normal(jax.random.key(3), (data.shape[0],), jnp.float32)
+    reg = obs.MetricsRegistry()
+    with obs.use_metrics(reg):
+        x1, _ = execute(CGProblem.from_ell(data, cols, b, 60, tol=1e-30),
+                        SYNCED)
+        x2, _ = execute(CGProblem.from_ell(data2, cols, b, 60, tol=1e-30),
+                        SYNCED)
+    assert reg.value("executor_retraces_total", tier="device_loop") == 1
+    assert reg.value("executor_runner_hits_total", tier="device_loop") == 1
+    np.testing.assert_allclose(np.asarray(x1), 2 * np.asarray(x2),
+                               rtol=1e-6, atol=0)
+    perks._RUNNERS.clear()
+    cold, _ = execute(CGProblem.from_ell(data2, cols, b, 60, tol=1e-30),
+                      SYNCED)
+    _assert_same(x2, cold)
+
+
+def test_the_operator_outlives_its_solves(fresh_runners):
+    """The runners donate the state only: the ELL planes and the DIA
+    planes they were converted to are readable after two solves each."""
+    data, cols = cgs.load_dataset("poisson_64")
+    for cls in (CGProblem, BiCGStabProblem):
+        for seed in (0, 1):
+            execute(_krylov(cls, data, cols, seed), SYNCED)
+    _, planes = adapters.dia_operator(data, cols)
+    for a in (data, cols, *planes):
+        assert not a.is_deleted()
+        assert np.isfinite(np.asarray(a)).all()
+
+
+def test_fresh_closures_run_and_the_cache_stays_bounded(fresh_runners):
+    """A caller passing a new closure every call gets a runner of its own
+    each time; the cache keeps at most ``_RUNNERS_SIZE`` of them, and none
+    once the closures are gone."""
+    kept = []
+    for k in range(perks._RUNNERS_SIZE + 4):
+        kept.append(lambda s, k=k: s + k)
+        out = perks.device_loop(kept[-1], 3)(jnp.zeros(4, jnp.int32))
+        np.testing.assert_array_equal(np.asarray(out), np.full(4, 3 * k))
+    assert len(perks._RUNNERS) == perks._RUNNERS_SIZE
+    del kept
+    gc.collect()
+    assert len(perks._RUNNERS) == 0

@@ -123,7 +123,7 @@ def test_null_tracer_records_nothing_and_is_cheap():
     assert obs.get_tracer().enabled is False
 
 
-def test_traced_execute_bit_identical_to_untraced():
+def test_traced_execute_bit_identical_to_untraced(fresh_runners):
     p = _stencil()
     pl = [c for c in plan_candidates(p) if c.tier == "host_loop"][0]
     base = execute(p, pl)
@@ -175,10 +175,12 @@ def test_spans_reach_the_profiler_for_every_tracer(tmp_path, recording):
     assert len(tr.events) == int(recording)
 
 
-def test_cg_device_loop_spans_in_the_profiler_trace(tmp_path, poisson):
-    """A CG solve on a device_loop plan with host syncs: one
-    ``repro.dispatch`` holding one ``repro.compile``, k-1 ``repro.chunk``
-    and k ``repro.barrier`` spans, k the host syncs that ran."""
+def test_cg_device_loop_spans_in_the_profiler_trace(tmp_path, poisson,
+                                                   fresh_runners):
+    """A CG solve on a device_loop plan with host syncs, its runner not yet
+    built: one ``repro.dispatch`` holding one ``repro.compile``, k-1
+    ``repro.chunk`` and k ``repro.barrier`` spans, k the host syncs that
+    ran."""
     data, cols = poisson
     p = _cg(data, cols, 0, iters=400)
     pl = [c for c in plan_candidates(p) if c.tier == "device_loop"][0]
@@ -200,6 +202,27 @@ def test_cg_device_loop_spans_in_the_profiler_trace(tmp_path, poisson):
         == {25}
     assert [s["steps_done"] for n, _, _, s in inner
             if n == "repro.barrier"] == [25 * (i + 1) for i in range(k)]
+
+
+def test_warm_cg_solve_spans_in_the_profiler_trace(tmp_path, poisson,
+                                                  fresh_runners):
+    """A second solve over one operator builds nothing: its dispatch holds
+    k ``repro.chunk`` and k ``repro.barrier`` spans and no
+    ``repro.compile``, and the executor counts a runner hit."""
+    data, cols = poisson
+    pl = [c for c in plan_candidates(_cg(data, cols, 0, iters=400))
+          if c.tier == "device_loop"][0]
+    execute(_cg(data, cols, 0, iters=400), pl)
+    reg = obs.MetricsRegistry()
+    with obs.use_metrics(reg):
+        _, spans = _profiled(
+            tmp_path, lambda: execute(_cg(data, cols, 1, iters=400), pl))
+    k = reg.value("executor_host_syncs_total", tier="device_loop")
+    assert 1 < k < 400 // 25
+    assert [n for n, _, _, _ in spans] == ["repro.dispatch"] + [
+        "repro.chunk", "repro.barrier"] * k
+    assert reg.value("executor_retraces_total", tier="device_loop") == 0
+    assert reg.value("executor_runner_hits_total", tier="device_loop") == 1
 
 
 # -- metrics -----------------------------------------------------------------

@@ -147,9 +147,30 @@ def test_cg_chunk_compiles_without_a_gather(one_chip):
     gather = functools.partial(kref.spmv_ell, data, cols)
     for prob, gathers in ((CGProblem.from_ell(data, cols, b, 500), False),
                           (CGProblem.from_matvec(gather, b, 500), True)):
-        chunk = perks._fused_runner(prob.step_fn(), 25, True)
-        text = chunk.lower(state).compile().as_text()
+        fn, operands = prob.step()
+        operands = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), operands)
+        chunk = perks._fused_runner(fn, 25, True)
+        text = chunk.jitted.lower((operands,), state).compile().as_text()
         assert (" gather(" in text) == gathers
+
+
+def test_cg_dia_chunk_reads_its_planes_in_place(one_chip):
+    """The DIA planes reach the chunk as arguments, one array a diagonal:
+    no row of a (D, n) array is cut out, and so laid out anew, at every
+    iteration."""
+    data, cols = spmv_ell.poisson2d_ell(256)
+    n = data.shape[0]
+    fn, planes = CGProblem.from_ell(data, cols, np.ones(n, np.float32),
+                                    500).step()
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                             sharding=one_chip)
+    state = (sds((n,)), sds((n,)), sds((n,)), sds(()))
+    operands = (jax.tree.map(lambda a: sds(a.shape), planes),)
+    chunk = perks._fused_runner(fn, 25, True)
+    text = chunk.jitted.lower(operands, state).compile().as_text()
+    assert f"f32[1,{n}]" not in text
 
 
 # -- gather kernels: refused on a TPU ------------------------------------------
