@@ -29,7 +29,7 @@ from repro.runtime.solver_service import (
     ServiceConfig,
     SolverService,
 )
-from repro.solvers.cg import load_dataset
+from repro.kernels.ops import load_dataset
 
 
 def build_requests(users: int):

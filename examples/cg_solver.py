@@ -5,7 +5,8 @@
     PYTHONPATH=src python examples/cg_solver.py --list
 
 ``--dataset`` accepts any name from the SuiteSparse-proxy registry
-(``repro.sparse.generate``) or the legacy synthetic suite; the solve is
+(``repro.sparse.generate``) or the synthetic ``poisson_*``/``banded_*``
+suite; the solve is
 preceded by the cache planner's policy choice and the ELL vs SELL-C-σ
 padding report for the chosen matrix.
 """
@@ -15,35 +16,39 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.exec import CGProblem, Plan, execute
-from repro.solvers import cg
-from repro.sparse import REGISTRY, choose_format
+from repro.core.cache_policy import cg_arrays_for
+from repro.core.hardware import TPU_V5E
+from repro.exec import CGProblem, Plan, execute, fused_block_rows
+from repro.exec.planner import cg_policy_from_arrays
+from repro.kernels.ops import SellOperator
+from repro.sparse import DATASETS, REGISTRY, choose_format, load_matrix
 from repro.sparse.generate import PROXY_ONCHIP_BYTES
 
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--dataset", default="poisson_128",
-                    help="registry or legacy dataset name")
+                    help="registry or synthetic dataset name")
     ap.add_argument("--iters", type=int, default=60)
     ap.add_argument("--list", action="store_true",
                     help="list available datasets and exit")
     args = ap.parse_args()
     if args.list:
-        for name in cg.DATASETS:
+        for name in DATASETS:
             spec = REGISTRY.get(name)
-            note = f"  [{spec.structure}] {spec.note}" if spec else "  [legacy]"
+            note = (f"  [{spec.structure}] {spec.note}" if spec
+                    else "  [synthetic]")
             print(f"{name:20s}{note}")
         return
 
-    csr = cg.load_matrix(args.dataset)
+    csr = load_matrix(args.dataset)
     n = csr.shape[0]
     iters = args.iters
 
     fmt, reports = choose_format(csr, c=32, sigma=256)
-    plan = cg.plan_policy(matrix=csr)
-    regime = cg.plan_policy(matrix=csr,
-                            budget_bytes=PROXY_ONCHIP_BYTES)["policy"]
+    arrays = cg_arrays_for(csr)
+    plan = cg_policy_from_arrays(arrays, int(TPU_V5E.onchip_bytes * 0.9))
+    regime = cg_policy_from_arrays(arrays, PROXY_ONCHIP_BYTES)["policy"]
     print(f"dataset {args.dataset}: n={n}, nnz={csr.nnz}")
     print(f"  planner        : policy={plan['policy']} "
           f"(vectors {plan['vector_fraction']:.0%}, "
@@ -78,7 +83,7 @@ def main():
     x_f, rr_f = execute(problem, Plan(
         tier="resident",
         policy=plan["policy"] if plan["policy"] in ("VEC", "MIX") else "MIX",
-        block_rows=cg.fused_block_rows(n)))
+        block_rows=fused_block_rows(n)))
 
     print(f"CG {args.dataset} (n={n}), {iters} iters (|b|^2 = {bb:.1f})")
     print(f"  host loop      : {t_h * 1e3:7.1f} ms, "
@@ -88,7 +93,7 @@ def main():
     print(f"  PERKS kernel   : rr/bb = {float(rr_f) / bb:.2e} "
           f"(whole loop in one Pallas kernel, vectors VMEM-resident)")
     if fmt == "sell":
-        op = cg.SellOperator.from_matrix(csr.to_sell(c=32, sigma=256))
+        op = SellOperator.from_matrix(csr.to_sell(c=32, sigma=256))
         sell_problem = CGProblem.from_matvec(op.matvec, b, iters, matrix=csr)
         t0 = time.perf_counter()
         x_s, rr_s = execute(sell_problem, Plan(tier="device_loop"))

@@ -158,18 +158,6 @@ def stencil_shard_arrays(
                           2 * radius * fuse_steps * row_bytes)
 
 
-@dataclasses.dataclass(frozen=True)
-class TemporalBlockPlan:
-    """Cost/benefit of fusing ``fuse_steps`` time steps per barrier
-    (paper Eq. 5 generalized to t; arXiv:2306.03336)."""
-
-    fuse_steps: int
-    barriers: int                  # halo exchanges / HBM passes for n_steps
-    halo_rows_per_exchange: int    # 2*r*t rows moved per exchange (vs 2*r)
-    redundant_row_updates: int     # extra row-updates over the whole run
-    gm_bytes: float                # generalized Eq. 5 main-memory traffic
-
-
 def gm_bytes_fused(
     n_steps: int,
     domain_bytes: int,
@@ -241,33 +229,10 @@ def deep_scratch_rows(sub_rows: int, halo: int, fuse_steps: int) -> int:
             + (fuse_steps + 3) * halo)
 
 
-def plan_fuse_steps(
-    n_steps: int,
-    shard_rows: int,
-    row_bytes: int,
-    radius: int,
-    *,
-    cached_bytes: int = 0,
-    max_fuse: int = 8,
-) -> TemporalBlockPlan:
-    """Pick the deepest feasible temporal blocking for a row-partitioned
-    stencil: the largest t <= max_fuse whose r*t-wide halo still fits in
-    the shard (``halo_exchange`` needs ``r*t <= shard_rows``), reported
-    with its barrier count, redundant compute, and generalized-Eq.-5
-    traffic. Redundant compute per pass is sum_{k=1}^{t-1} 2*r*k row
-    updates (the shrinking wide halo)."""
-    t = max(1, min(max_fuse, shard_rows // max(1, radius), n_steps))
-    barriers = -(-n_steps // t)
-    redundant = barriers * radius * t * (t - 1)       # = sum 2*r*k over a pass
-    gm = gm_bytes_fused(n_steps, shard_rows * row_bytes, cached_bytes,
-                        row_bytes=row_bytes, radius=radius, fuse_steps=t)
-    return TemporalBlockPlan(t, barriers, 2 * radius * t, redundant, gm)
-
-
 def cg_arrays(n_rows: int, nnz: int, dtype_bytes: int, index_bytes: int = 4) -> list[CacheableArray]:
     """Cacheable arrays of the PERKS conjugate-gradient solver (§III-B2).
 
-    Per CG iteration (see solvers/cg.py): the residual r is read by the
+    Per CG iteration (see ``exec/krylov.py``): the residual r is read by the
     dot products and axpy updates (3 loads) and written once; p and x and
     Ap similar; the matrix A is read once and never written. The paper
     singles out r (3 loads + 1 store) > A (1 load); we enumerate all of

@@ -1,10 +1,9 @@
 """JAX's persistent compilation cache for the programs that run on a chip.
 
 ``enable_compile_cache()`` is called by the entry points that compile for a
-device (``chip_smoke.py``, ``benchmarks/run.py``, ``launch/serve.py``,
-``launch/train.py``); the tests never turn it on. Where
-``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and nothing else
-is set. Otherwise the cache sits at one fixed directory inside the checkout
+device (``chip_smoke.py``, ``launch/serve.py``, ``launch/train.py``); the
+tests never turn it on. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
+already reads it and nothing else is set. Otherwise the cache sits at one fixed directory inside the checkout
 (``.jax_cache/``, git-ignored), so a later run of the same checkout finds
 what an earlier one compiled.
 """

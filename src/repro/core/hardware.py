@@ -64,8 +64,7 @@ TPU_V5E = Chip(
 )
 
 # Earlier/later TPU generations, for planner sensitivity studies (the
-# executor's `--chip` flag threads these through examples/ and
-# benchmarks/). Public specs:
+# executor's `--chip` flag threads these through examples/). Public specs:
 #   v4:  275 TFLOP/s bf16, 32 GiB HBM2 @ 1228 GB/s, 2400 Gbps ICI per chip
 #        over a 3D torus (6 links -> 50 GB/s/link)
 #        [cloud.google.com/tpu/docs/v4, TPU v4 ISCA'23 paper arXiv:2304.01433]

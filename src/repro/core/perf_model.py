@@ -1,13 +1,12 @@
 """Performance models.
 
 Part 1 — the paper's projected-peak model (§IV, Eqs. 4–13), reproduced
-faithfully so EXPERIMENTS.md can validate against the paper's own worked
-examples (§IV-B gives two A100 numbers we reproduce to <1%).
+faithfully enough to check against the paper's own worked examples
+(§IV-B gives two A100 numbers we reproduce to <1%).
 
 Part 2 — the three-term TPU roofline demanded by the assignment
 (compute / memory / collective), fed by ``compiled.cost_analysis()`` and
-collective bytes parsed from post-SPMD HLO. Used by launch/dryrun.py and
-benchmarks/.
+collective bytes parsed from post-SPMD HLO. Used by launch/dryrun.py.
 """
 from __future__ import annotations
 

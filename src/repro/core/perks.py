@@ -79,7 +79,7 @@ class PerksConfig:
       fuse_steps: temporal blocking (DESIGN.md §4): advance this many time
         steps per *barrier*. What the barrier is depends on the tier — a
         host dispatch for HOST_LOOP, a halo exchange for the distributed
-        stencil (``solvers/stencil.py``), an HBM streaming pass for the
+        stencil (``exec/adapters.py``), an HBM streaming pass for the
         RESIDENT kernels (``kernels/stencil2d.py``). The consumer pays for
         the fusion with a ``radius * fuse_steps`` wide halo that is
         redundantly recomputed (arXiv:2306.03336's deep temporal blocking);
@@ -426,7 +426,7 @@ def persistent(
     dispatch (the dispatch *is* the barrier there), cutting barrier count to
     ceil(n_steps / fuse_steps). DEVICE_LOOP is already fully fused, so the
     knob is a no-op at this level — the distributed/RESIDENT consumers
-    (``solvers/stencil.py``, ``kernels/stencil2d.py``) implement it as
+    (``exec/adapters.py``, ``kernels/stencil2d.py``) implement it as
     wide-halo exchange / multi-step HBM passes instead.
 
     ``metrics`` (a ``repro.obs.MetricsRegistry``) counts the steps, host

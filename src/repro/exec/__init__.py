@@ -11,8 +11,8 @@ One solver-agnostic pipeline behind every iterative workload:
 * :class:`Plan` (``plan.py``) — an immutable record of *how* to run
   (tier, fuse depth, cache assignment, shard axis) with a JSON
   round-trip, so chosen plans are loggable artifacts.
-* :func:`plan` (``planner.py``) — subsumes the five legacy planner entry
-  points; ranks candidates with the paper's performance model.
+* :func:`plan` (``planner.py``) — the one planner; ranks candidates with
+  the paper's performance model.
 * :func:`execute` / :func:`autotune` (``executor.py``) — the single
   dispatch path over all tiers, and measured top-k plan selection.
 * :class:`BatchedProblem` (``batch.py``, DESIGN.md §8) — B instances
@@ -31,9 +31,6 @@ One solver-agnostic pipeline behind every iterative workload:
   :class:`SSMScanProblem` (the Mamba2 SSD scan; chunk index as the time
   axis, state ``h`` VMEM-resident on the resident tier), so the serving
   engine (``runtime/server.py``) decodes through ``plan()``/``execute()``.
-
-The legacy ``solvers/stencil.py`` and ``solvers/cg.py`` surfaces are
-thin deprecated shims over this package.
 """
 from repro.exec.adapters import (
     CGProblem,

@@ -1,12 +1,9 @@
 """Problem adapters: stencils and CG described for the unified executor.
 
-These carry the *workload-specific* halves of what used to live in
-``solvers/stencil.py`` and ``solvers/cg.py`` — the step functions, the
-resident-kernel dispatch, and the distributed shard programs — behind the
-:class:`repro.exec.problem.Problem` protocol, so ``repro.exec.execute``
-is the single dispatch path for every tier. The solver modules remain as
-thin deprecated shims over these adapters (each legacy ``run_*`` builds a
-Problem + Plan and calls ``execute``).
+These carry the *workload-specific* halves of each solver — the step
+functions, the resident-kernel dispatch, and the distributed shard
+programs — behind the :class:`repro.exec.problem.Problem` protocol, so
+``repro.exec.execute`` is the single dispatch path for every tier.
 
 A future workload (new stencil geometry, new sparse format, decode,
 multigrid) is one more adapter here: ~50 lines, no new solver file.
@@ -533,12 +530,12 @@ def cg_distributed(data, cols, b, iters: int, mesh: Mesh, *,
 class CGProblem(Problem):
     """Conjugate gradient on an SPD operator.
 
-    Two operator forms: block-ELL planes (``data``/``cols`` — the legacy
-    path, required for the fused resident kernel and the distributed
-    tier) and/or an opaque ``matvec`` callable (e.g. the SELL-C-σ
-    operator), which takes precedence for the loop tiers. ``matrix`` may
-    carry any ``repro.sparse`` container so the cache planner ranks A by
-    its **true** nnz rather than padded slots.
+    Two operator forms: block-ELL planes (``data``/``cols``, required
+    for the fused resident kernel and the distributed tier) and/or an
+    opaque ``matvec`` callable (e.g. the SELL-C-σ operator), which takes
+    precedence for the loop tiers. ``matrix`` may carry any
+    ``repro.sparse`` container so the cache planner ranks A by its
+    **true** nnz rather than padded slots.
     """
 
     b: jax.Array

@@ -5,8 +5,7 @@ the empirical winner with its timing table.
 The executor owns only *orchestration*: the loop combinators
 (``core.perks``) for the host/device tiers and the problem's own tier
 hooks for resident/distributed. All workload specifics live in the
-Problem adapters, all decisions in the Plan — which is what makes the
-legacy ``run_*`` surfaces one-line shims (DESIGN.md §7).
+Problem adapters, all decisions in the Plan (DESIGN.md §7).
 """
 from __future__ import annotations
 
@@ -51,14 +50,12 @@ def _record_plan_metrics(plan: Plan) -> None:
 def execute(problem: Problem, plan: Plan, *, mesh=None):
     """Run ``problem`` under ``plan``; returns the problem's final result.
 
-    Reproduces the legacy ``run_*`` entry points exactly: for the same
-    plan the executor routes through the identical combinators/kernels,
-    so results are bit-identical (<= 2 ulp where ``fuse_steps > 1``
-    changes window shapes, DESIGN.md §4 — the same bound the legacy
-    paths carry). The ambient observability context (``repro.obs``) sees
-    every call: executor counters and the ``repro.dispatch`` span around
-    the run (with the loop tiers' compile/chunk/barrier spans inside it)
-    always, cache events and in-memory records when a real tracer is
+    Every tier runs the problem's one step function or its kernels, so
+    the tiers agree: bit for bit between the loop tiers, to <= 2 ulp where
+    ``fuse_steps > 1`` changes window shapes (DESIGN.md §4). The ambient
+    observability context (``repro.obs``) sees every call: executor
+    counters and the ``repro.dispatch`` span around the run (with the
+    loop tiers' compile/chunk/barrier spans inside it) always, cache events and in-memory records when a real tracer is
     installed, and a predicted-vs-measured row in the drift ledger when
     one is active (the ledger blocks on the result to time it — values
     are unchanged, only laziness).

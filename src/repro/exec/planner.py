@@ -1,33 +1,28 @@
 """``plan(problem)``: the one planner behind every PERKS solver.
 
-Before this layer, *how to run* was decided by five separate entry
-points — ``kernels.stencil3d.plan_resident_planes`` (VMEM occupancy),
-``core.cache_policy.plan_caching`` (what-to-cache knapsack),
-``core.cache_policy.plan_fuse_steps`` (temporal-blocking depth),
-``solvers.stencil.plan_for`` (stencil reporting) and
-``solvers.cg.plan_policy`` (Fig.-9 policy pick) — each consumed by a
-different ``run_*`` signature. This module subsumes them: it enumerates
-candidate :class:`~repro.exec.plan.Plan`\\ s per tier × fuse depth ×
-cache assignment, prices each with the paper's performance model
-(``core.perf_model``, Eqs. 5–11 generalized by ``gm_bytes_fused``) plus
-a per-dispatch launch-overhead term, and returns them ranked by
-projected time — not by the ad-hoc byte heuristics the old entry points
-used. ``autotune`` (``repro.exec.executor``) then measures the top
-candidates and picks the winner empirically.
+For a :class:`~repro.exec.problem.Problem` on a chip (and optionally a
+mesh), ``plan_candidates`` enumerates candidate
+:class:`~repro.exec.plan.Plan`\\ s per tier × fuse depth × cache
+assignment, drops those whose fields and kernel footprints do not fit the
+chip's HBM and VMEM, prices each with the paper's performance model
+(``core.perf_model``, Eqs. 5–11 generalized by ``gm_bytes_fused``) plus a
+per-dispatch launch-overhead term, and ranks them by projected time, or by
+measured time where a drift ledger has evidence. ``plan`` returns the top
+candidate; ``autotune`` (``repro.exec.executor``) measures the leaders and
+picks the winner empirically.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Optional, Sequence
+from typing import Optional
 
 from repro.core.cache_policy import (
-    cg_arrays,
     gm_bytes_deep,
     gm_bytes_fused,
     plan_caching,
 )
-from repro.core.hardware import CHIPS, Chip, TPU_V5E, attached_chip
+from repro.core.hardware import CHIPS, Chip, attached_chip
 from repro.core.perf_model import project_host_loop, sm_bytes_accessed
 from repro.exec.plan import CacheDecision, Plan
 from repro.exec.problem import Problem
@@ -254,10 +249,9 @@ def _stencil_candidates(problem, chip: Chip, mesh, *, max_fuse: int,
 # -----------------------------------------------------------------------------
 
 def cg_policy_from_arrays(arrays, budget_bytes: int) -> dict:
-    """The Fig.-9 policy decision (IMP/VEC/MIX) from a cache plan — the
-    exact logic of the legacy ``solvers.cg.plan_policy``, factored here so
-    both the legacy shim and the candidate generator share it. "Vectors"
-    are every array that is not the operator A (for CG: r/p/x/Ap; for
+    """The Fig.-9 policy decision (IMP/VEC/MIX) from a cache plan of the
+    Krylov working set under ``budget_bytes``. "Vectors" are every array
+    that is not the operator A (for CG: r/p/x/Ap; for
     BiCGStab the seven working vectors; for GMRES the basis V rides with
     them), so one policy function serves the whole Krylov family."""
     cplan = plan_caching(arrays, budget_bytes)
@@ -554,37 +548,3 @@ def plan(problem: Problem, *, chip=None, mesh=None,
         problem, chip=chip, mesh=mesh, budget_bytes=budget_bytes,
         max_fuse=max_fuse, shard_axis=shard_axis, sub_rows=sub_rows,
         sync_every=sync_every, batch=batch, ledger=ledger)[0]
-
-
-# -- legacy planner surfaces (delegated to by the solver shims) ----------------
-
-def stencil_plan_summary(x_shape: Sequence[int], dtype_bytes: int, spec, *,
-                         chip=TPU_V5E, sub_rows: int = 128,
-                         fuse_steps: int = 1) -> dict:
-    """Cache plan + fractions for reporting (the legacy ``plan_for`` dict).
-    Host-side arithmetic on static shapes only — no device ops."""
-    chip = _as_chip(chip)
-    rows = plan_resident_planes(tuple(x_shape), dtype_bytes, spec, chip=chip,
-                                sub_rows=sub_rows, fuse_steps=fuse_steps)
-    row_elems = math.prod(x_shape[1:])
-    domain = math.prod(x_shape)
-    cached = rows * row_elems
-    return {"cached_rows": rows, "cached_cells": cached,
-            "cached_fraction": cached / domain}
-
-
-def cg_policy(n_rows: Optional[int] = None, nnz: Optional[int] = None,
-              dtype_bytes: int = 4, *, chip=TPU_V5E, matrix=None,
-              budget_bytes: Optional[int] = None) -> dict:
-    """The legacy ``plan_policy`` dict (Fig.-9 policy + fractions)."""
-    from repro.core.cache_policy import cg_arrays_for
-    chip = _as_chip(chip)
-    if matrix is not None:
-        arrays = cg_arrays_for(matrix)
-    else:
-        arrays = cg_arrays(n_rows, nnz, dtype_bytes)
-    budget = (int(chip.onchip_bytes * 0.9) if budget_bytes is None
-              else int(budget_bytes))
-    out = cg_policy_from_arrays(arrays, budget)
-    out.pop("_plan")
-    return out

@@ -21,7 +21,7 @@ for the kernel's lifetime. Two matrix policies:
 
 The dot products (rr, p.Ap) are the device-wide barrier of the paper: every
 iteration's scalars depend on the whole domain, which on a mesh becomes a
-psum (see solvers/cg.py for the distributed wrapper).
+psum (see ``exec/adapters.cg_distributed`` for the distributed tier).
 """
 from __future__ import annotations
 

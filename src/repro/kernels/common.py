@@ -4,7 +4,7 @@ limit by.
 
 A ``StencilSpec`` is a pure description — offsets + weights — consumed by
 the Pallas kernels (``stencil2d.py``/``stencil3d.py``), the jnp oracles
-(``ref.py``) and the system-level solvers (``solvers/stencil.py``).
+(``ref.py``) and the executor's stencil adapter (``exec/adapters.py``).
 
 Boundary semantics used everywhere in this repo: the outermost ``radius``
 cells of the domain are Dirichlet (frozen); only the interior is updated.

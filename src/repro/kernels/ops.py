@@ -6,13 +6,18 @@ cannot compile raises (the gather kernels ``spmv``, ``spmv_sell``, ``cg``,
 ``bicgstab``, ``gmres_cycle`` — see ``spmv_ell.py``). On any other backend
 (the test suite's CPU) they run the same kernel bodies in interpret mode,
 which the tests validate against the ``ref.py`` oracles. Model code and
-solvers call through these wrappers only.
+solvers call through these wrappers only. The device-side dataset
+helpers (``load_dataset``, ``SellOperator``, ``load_sell``) live here
+too, beside the SELL wrapper they feed.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
+from typing import Any
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels.common import StencilSpec
 from repro.kernels import stencil2d as _s2d
@@ -22,6 +27,7 @@ from repro.kernels import cg_fused as _cg
 from repro.kernels import krylov_fused as _kry
 from repro.kernels import ssm_scan as _ssm
 from repro.kernels import decode_attn as _da
+from repro.sparse import SellMatrix, load_matrix
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "steps", "sub_rows"))
@@ -80,6 +86,49 @@ def spmv_sell(data, cols, slice_offsets, slice_k, x, *, c: int, k_max: int):
     with ``SellMatrix.row_positions()`` to restore row order."""
     return _sell.spmv_sell(data, cols, slice_offsets, slice_k, x,
                            c=c, k_max=k_max)
+
+
+def load_dataset(name: str):
+    """A ``repro.sparse`` dataset as device ELL planes (data, cols)."""
+    ell = load_matrix(name).to_ell()
+    return jnp.asarray(ell.data), jnp.asarray(ell.cols)
+
+
+@dataclasses.dataclass(frozen=True)
+class SellOperator:
+    """Device-resident SELL-C-σ operator: flat streams + slice tables +
+    the row-order-restoring gather. ``matvec`` runs the Pallas kernel
+    (``spmv_sell.py``) with x VMEM-resident."""
+
+    data: jax.Array
+    cols: jax.Array
+    slice_offsets: jax.Array
+    slice_k: jax.Array
+    positions: jax.Array       # original row -> permuted padded position
+    c: int
+    k_max: int
+    n_rows: int
+    #: the source container (true nnz) so downstream CGProblems rank A by
+    #: the bytes it actually streams, not the padded slots
+    matrix: Any = None
+
+    @staticmethod
+    def from_matrix(sell: SellMatrix) -> "SellOperator":
+        return SellOperator(
+            jnp.asarray(sell.data), jnp.asarray(sell.cols),
+            jnp.asarray(sell.slice_offsets), jnp.asarray(sell.slice_k),
+            jnp.asarray(sell.row_positions()), sell.c, sell.k_max,
+            sell.n_rows, matrix=sell)
+
+    def matvec(self, x: jax.Array) -> jax.Array:
+        y = spmv_sell(self.data, self.cols, self.slice_offsets,
+                      self.slice_k, x, c=self.c, k_max=self.k_max)
+        return y[self.positions]
+
+
+def load_sell(name: str, c: int = 32, sigma: int = 256) -> SellOperator:
+    """A dataset as a device SELL-C-σ operator."""
+    return SellOperator.from_matrix(load_matrix(name).to_sell(c=c, sigma=sigma))
 
 
 @functools.partial(jax.jit, static_argnames=("iters", "resident_matrix", "block_rows"))

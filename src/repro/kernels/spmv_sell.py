@@ -26,7 +26,7 @@ Kernel mapping:
 
 Output is in *permuted, padded* row order (n_slices * C rows) — this
 holds for ``ops.spmv_sell`` too. Callers restore original order with a
-``SellMatrix.row_positions()`` gather; ``solvers.cg.SellOperator.matvec``
+``SellMatrix.row_positions()`` gather; ``ops.SellOperator.matvec``
 is the wrapper that does both steps.
 """
 from __future__ import annotations

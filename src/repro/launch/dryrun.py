@@ -11,8 +11,7 @@ ShapeDtypeStruct stand-ins — no allocation — and records:
   * per-op collective bytes parsed from the post-SPMD HLO
   * the three roofline terms + dominant bottleneck (§Roofline)
 
-Artifacts land in runs/dryrun/<mesh>/<arch>__<shape>.json, consumed by
-benchmarks/roofline.py and EXPERIMENTS.md.
+Artifacts land in runs/dryrun/<mesh>/<arch>__<shape>.json.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch gemma-7b --shape train_4k
@@ -127,7 +126,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
                                              in_axes)
             # pin the output cache sharding — the compiler otherwise picks a
             # (sometimes replicated) layout for the prefill cache, which at
-            # 32k x 80L is itself larger than HBM (EXPERIMENTS.md §Perf)
+            # 32k x 80L is itself larger than HBM
             cache_structs = _input_shardings(
                 model, rules, mesh,
                 model.cache_spec(shape.global_batch, shape.seq_len),

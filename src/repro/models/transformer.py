@@ -293,7 +293,7 @@ def decode_step(params, cfg: ModelConfig, cache, tokens):
     # The cache is a scan CARRY (not xs/ys): while-loop carries alias
     # in-place under donation, so the decode step's live memory is ONE cache
     # buffer — scan xs->ys stacking would triple it (measured 27 GB vs 9 GB
-    # on gemma decode_32k; see EXPERIMENTS.md §Perf).
+    # on gemma decode_32k).
     if cfg.mla is not None:
         def body(carry, args):
             x, ckv = carry

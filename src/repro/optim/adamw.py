@@ -76,8 +76,8 @@ def apply(cfg: AdamWConfig, params, opt_state, grads, *,
     Leaves under ``params[scan_key]`` (the stacked per-layer weights) are
     updated inside a ``lax.scan`` over the layer axis: the update math
     upcasts to f32, and letting XLA schedule all layers' f32 temporaries
-    concurrently was measured at +10 GB live on the 235B config
-    (EXPERIMENTS.md §Perf) — the scan serialises them to one layer's worth.
+    concurrently was measured at +10 GB live on the 235B config; the scan
+    serialises them to one layer's worth.
     """
     step = opt_state["step"] + 1
     lr = schedule(cfg, step)

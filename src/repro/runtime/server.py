@@ -7,8 +7,7 @@ generates through the PERKS executor: it wraps the batch as a
 only when shapes change), and runs ``execute()`` — the resident tier is
 ``Model.decode_loop``, N tokens per dispatch with a donated cache (the
 paper's persistent-kernel execution applied to serving). The baseline
-mode dispatches ``decode_step`` per token for the benchmark comparison
-(benchmarks/decode_bench.py).
+mode dispatches ``decode_step`` per token, the per-token baseline.
 
 :func:`start_metrics_server` exposes any :class:`repro.obs.MetricsRegistry`
 (the ambient one by default) over HTTP in the Prometheus text exposition

@@ -200,8 +200,8 @@ class SolverService:
         runner = perks.persistent(bp.step_fn(), bp.n_steps, cfg)
         return lambda batch: batch.finalize(runner(batch.initial_state()))
 
-    def _plan_for(self, bp: BatchedProblem) -> tuple[Plan, Optional[Callable],
-                                                     float]:
+    def _plan_and_runner(self, bp: BatchedProblem
+                         ) -> tuple[Plan, Optional[Callable], float]:
         """The key's plan + steady-state runner, and the planning seconds
         spent on THIS call — measured here, inside the plan cache, so a
         warm key reports exactly 0.0 and run_batch can report cold-key
@@ -254,7 +254,7 @@ class SolverService:
         pad_to = self.cfg.max_batch if self.cfg.pad_to_max else None
         bp = BatchedProblem.from_instances([p.problem for p in taken],
                                            pad_to=pad_to)
-        chosen, runner, plan_s = self._plan_for(bp)
+        chosen, runner, plan_s = self._plan_and_runner(bp)
         with self._tr().span(f"serve_batch:{bp.name}", cat="dispatch",
                              track="service", tier=chosen.tier,
                              batch_size=len(taken), padded_to=bp.batch):

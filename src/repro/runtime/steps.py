@@ -35,7 +35,7 @@ def make_train_step(model: Model, opt_cfg: adamw.AdamWConfig, *,
 
             # accumulate in the PARAM dtype: an f32 accumulator for a
             # bf16-param 235B model is an extra 2 bytes/param live
-            # (+1.9 GB/chip measured; EXPERIMENTS.md §Perf). Grad noise
+            # (+1.9 GB/chip measured). Grad noise
             # dominates bf16 rounding over <=8 microbatches.
             zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, p.dtype),
                                  params)

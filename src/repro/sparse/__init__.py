@@ -14,11 +14,13 @@ from repro.sparse.formats import (
     choose_format,
 )
 from repro.sparse.generate import (
+    DATASETS,
     PROXY_ONCHIP_BYTES,
     REGISTRY,
     DatasetSpec,
     generate,
     irregular_names,
+    load_matrix,
     nonsymmetric_names,
     symmetric_names,
 )
@@ -38,11 +40,13 @@ __all__ = [
     "PaddingReport",
     "SellMatrix",
     "choose_format",
+    "DATASETS",
     "PROXY_ONCHIP_BYTES",
     "REGISTRY",
     "DatasetSpec",
     "generate",
     "irregular_names",
+    "load_matrix",
     "nonsymmetric_names",
     "symmetric_names",
     "read_mtx",

@@ -23,8 +23,7 @@ time instead:
   reads ``C`` contiguous lanes per slot.
 
 ``PaddingReport`` quantifies the choice (fill ratio, bytes vs CSR) and
-``choose_format`` picks per matrix — the planner hook used by
-``solvers/cg.plan_policy``.
+``choose_format`` picks per matrix.
 """
 from __future__ import annotations
 

@@ -24,8 +24,8 @@ interpret-mode kernels) yet still straddle a capacity boundary: against
 the real v5e VMEM (128 MiB) they are all "small-regime", so the regime
 split is reproduced against ``PROXY_ONCHIP_BYTES`` — a 1/512-scale VMEM
 proxy, the same way the paper's suite straddles a 40 MB L2 rather than
-HBM. ``solvers/cg.plan_policy(..., budget_bytes=PROXY_ONCHIP_BYTES)``
-labels each entry's regime.
+HBM. ``exec.planner.cg_policy_from_arrays(cg_arrays_for(matrix),
+PROXY_ONCHIP_BYTES)`` labels each entry's regime.
 """
 from __future__ import annotations
 
@@ -94,7 +94,7 @@ def poisson3d(side: int, dtype=np.float32) -> CSRMatrix:
 
 def banded_spd(n: int, bands: int, seed: int = 0, dtype=np.float32) -> CSRMatrix:
     """Random SPD matrix with a constant band of ``bands`` off-diagonals
-    per side (the legacy ``banded_*`` synthetic suite, now CSR-first)."""
+    per side (the ``banded_*`` synthetic suite of ``DATASETS``)."""
     rng = np.random.default_rng(seed)
     ru, cu, vu = [], [], []
     for d in range(1, bands + 1):
@@ -329,3 +329,23 @@ def symmetric_names() -> list[str]:
 
 def nonsymmetric_names() -> list[str]:
     return [n for n, s in REGISTRY.items() if not s.symmetric]
+
+
+# name -> (constructor returning CSRMatrix, kwargs): the synthetic
+# ``poisson_*``/``banded_*`` operators plus every registry entry.
+DATASETS = {
+    "poisson_64": (poisson2d, {"side": 64}),
+    "poisson_128": (poisson2d, {"side": 128}),
+    "poisson_256": (poisson2d, {"side": 256}),
+    "banded_4k": (banded_spd, {"n": 4096, "bands": 4}),
+    "banded_16k": (banded_spd, {"n": 16384, "bands": 8}),
+    "banded_64k": (banded_spd, {"n": 65536, "bands": 4}),
+    **{name: (spec.builder, spec.kwargs)
+       for name, spec in REGISTRY.items()},
+}
+
+
+def load_matrix(name: str) -> CSRMatrix:
+    """Build one dataset as an exact CSR container (true nnz, row_nnz)."""
+    fn, kw = DATASETS[name]
+    return fn(**kw)

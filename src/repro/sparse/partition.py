@@ -1,7 +1,7 @@
 """nnz-balanced contiguous row partitioning for distributed SpMV/CG.
 
-``solvers/cg.run_distributed`` row-partitions the matrix over a mesh
-axis. Equal-*rows* sharding balances vector work but not SpMV work: on a
+Distributed CG (``exec.adapters.cg_distributed``) row-partitions the
+matrix over a mesh axis. Equal-*rows* sharding balances vector work but not SpMV work: on a
 power-law graph one shard can own most of the nonzeros and every psum
 barrier waits for it. Equal-*nnz* contiguous ranges are the standard fix
 (the same objective merge-based CSR pursues per-thread, applied at the

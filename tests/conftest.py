@@ -23,23 +23,6 @@ def rng():
     return np.random.default_rng(0)
 
 
-@pytest.fixture(autouse=True)
-def _fresh_deprecation_state():
-    """Reset the legacy-shim warn-once registry before every test.
-
-    The shims warn once *per process* (exec/deprecation.py), so whether a
-    given test observes the DeprecationWarning used to depend on which
-    tests called a shim before it — order-dependent under
-    ``pytest -p no:randomly``, random seeds, and split matrix workers.
-    Resetting per test makes every test see a fresh process-like state;
-    within a test the exactly-once contract is untouched."""
-    from repro.exec.deprecation import reset_warnings
-
-    reset_warnings()
-    yield
-    reset_warnings()
-
-
 @pytest.fixture
 def fresh_runners():
     """An empty loop-runner cache (``core.perks._RUNNERS``), so a test that

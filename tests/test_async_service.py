@@ -21,7 +21,7 @@ from repro.runtime.solver_service import (
     AsyncSolverService,
     ServiceOverloaded,
 )
-from repro.solvers.cg import load_dataset
+from repro.kernels.ops import load_dataset
 
 
 def _tick_clock():

@@ -8,7 +8,6 @@ B grows (VMEM/B), the shared CG matrix does not scale with B, and Plans
 carry ``batch`` through the JSON round-trip.
 """
 import dataclasses
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +25,7 @@ from repro.exec import (
     plan_candidates,
 )
 from repro.kernels.common import BENCHMARKS, get_spec
-from repro.solvers import cg as cgs
+from repro.kernels.ops import load_dataset
 
 B = 3
 STEPS = 3
@@ -82,7 +81,7 @@ def test_batched_stencil_fused_resident_matches_sequential():
 
 @pytest.mark.parametrize("dataset", ["poisson2d_small", "fem_band_8k"])
 def test_batched_cg_matches_sequential(dataset):
-    data, cols = cgs.load_dataset(dataset)
+    data, cols = load_dataset(dataset)
     bs = [jax.random.normal(jax.random.key(10 + i), (data.shape[0],),
                             jnp.float32) for i in range(B)]
     insts = [CGProblem.from_ell(data, cols, b, 4) for b in bs]
@@ -94,7 +93,7 @@ def test_batched_cg_matches_sequential(dataset):
 
 
 def test_batched_cg_resident_matches_sequential():
-    data, cols = cgs.load_dataset("poisson_64")
+    data, cols = load_dataset("poisson_64")
     bs = [jax.random.normal(jax.random.key(20 + i), (data.shape[0],),
                             jnp.float32) for i in range(B)]
     insts = [CGProblem.from_ell(data, cols, b, 5) for b in bs]
@@ -105,7 +104,7 @@ def test_batched_cg_resident_matches_sequential():
 
 
 def test_batched_cg_early_stop_converges_all_instances():
-    data, cols = cgs.load_dataset("poisson_64")
+    data, cols = load_dataset("poisson_64")
     bs = [jax.random.normal(jax.random.key(30 + i), (data.shape[0],),
                             jnp.float32) for i in range(B)]
     insts = [CGProblem.from_ell(data, cols, b, 500, tol=1e-10) for b in bs]
@@ -122,7 +121,7 @@ def test_batched_on_sync_is_one_stacked_reduction(monkeypatch):
     """The batched convergence check must evaluate ALL lanes with one
     device-side vmapped reduction — the per-instance host callbacks are
     never invoked (previously: B host transfers per sync point)."""
-    data, cols = cgs.load_dataset("poisson_64")
+    data, cols = load_dataset("poisson_64")
     bs = [jax.random.normal(jax.random.key(60 + i), (data.shape[0],),
                             jnp.float32) for i in range(B)]
     insts = [CGProblem.from_ell(data, cols, b, 500, tol=1e-10) for b in bs]
@@ -148,7 +147,7 @@ def test_lane_runner_retirement_bit_exact_vs_sequential():
     computes alone under the same chunked device loop."""
     from repro.exec.batch import LaneRunner
 
-    data, cols = cgs.load_dataset("poisson_64")
+    data, cols = load_dataset("poisson_64")
     chunk, n = 5, 400
     insts = [CGProblem.from_ell(
         data, cols,
@@ -324,7 +323,7 @@ def test_planner_infers_batch_from_batched_problem():
 
 def test_batched_cg_working_set_shares_matrix():
     """B-scaled working set: Krylov vectors scale by B, A does not."""
-    data, cols = cgs.load_dataset("poisson_64")
+    data, cols = load_dataset("poisson_64")
     b0 = jax.random.normal(jax.random.key(0), (data.shape[0],), jnp.float32)
     insts = [CGProblem.from_ell(data, cols, b0, 4) for _ in range(4)]
     bp = BatchedProblem.from_instances(insts)
@@ -336,7 +335,7 @@ def test_batched_cg_working_set_shares_matrix():
 
 
 def test_batch_keys_separate_operators_and_families():
-    data, cols = cgs.load_dataset("poisson_64")
+    data, cols = load_dataset("poisson_64")
     data2 = data + 0.0       # same values, DIFFERENT operator object
     b0 = jnp.ones((data.shape[0],), jnp.float32)
     p1 = CGProblem.from_ell(data, cols, b0, 4)
@@ -354,7 +353,7 @@ def test_batched_distributed_matches_sequential(dist_run):
     """One vmapped shard_map program: every instance's halo/psum rides
     the same collective round, results stay bit-exact per instance."""
     out = dist_run("""
-    import warnings, jax, jax.numpy as jnp, numpy as np, json
+    import jax, jax.numpy as jnp, numpy as np, json
     from repro.dist.mesh import make_mesh
     from repro.exec import (BatchedProblem, CGProblem, Plan, StencilProblem,
                             execute, execute_sequential)
@@ -375,10 +374,8 @@ def test_batched_distributed_matches_sequential(dist_run):
         exact[f"stencil_t{t}"] = all(
             np.array_equal(np.asarray(out[i]), np.asarray(seq[i]))
             for i in range(B))
-    from repro.solvers import cg as cgs
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore")
-        data, cols = cgs.load_dataset("poisson_64")
+    from repro.kernels.ops import load_dataset
+    data, cols = load_dataset("poisson_64")
     bs = [jax.random.normal(jax.random.key(10 + i), (data.shape[0],),
                             jnp.float32) for i in range(B)]
     cinsts = [CGProblem.from_ell(data, cols, b, 4) for b in bs]
@@ -396,15 +393,3 @@ def test_batched_distributed_matches_sequential(dist_run):
     print(json.dumps(exact))
     """)
     assert all(out.values()), out
-
-
-# -- deprecation hygiene of the new surface -------------------------------------
-
-def test_batched_path_emits_no_deprecation_warnings():
-    """The batched tier is pure repro.exec — it must never route through
-    a legacy shim."""
-    insts, bp = _stencil_batch("2d5pt")
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        execute(bp, plan(bp))
-    assert not [x for x in w if issubclass(x.category, DeprecationWarning)]

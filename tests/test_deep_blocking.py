@@ -285,6 +285,11 @@ def _projected_streamed(spec, steps, t, schedule):
     passes = StencilProblem(x, spec, steps).projected_passes(plan)
     assert passes
     assert sum(p["passes"] for p in passes) == math.ceil(steps / t)
+    # deep reads each uncached row once a pass; shallow re-reads the
+    # r*t overlap on top of what it writes
+    for p in passes:
+        rd, wr = p["bytes_read_per_pass"], p["bytes_written_per_pass"]
+        assert rd == wr if schedule == "deep" else rd >= wr, p
     streamed = sum(p["passes"] * (p["bytes_read_per_pass"]
                                   + p["bytes_written_per_pass"])
                    for p in passes)

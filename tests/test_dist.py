@@ -11,12 +11,13 @@ def test_distributed_stencil_matches_single(dist_run):
         import json, jax, jax.numpy as jnp, numpy as np
         from repro.kernels.common import get_spec
         from repro.kernels import ref
-        from repro.solvers import stencil
+        from repro.exec import Plan, StencilProblem, execute
         from repro.dist.mesh import make_mesh
         mesh = make_mesh((8,), ("data",))
         spec = get_spec("2ds9pt")
         x = jax.random.normal(jax.random.key(0), (64, 128), jnp.float32)
-        got = stencil.run_distributed(x, spec, 7, mesh)
+        got = execute(StencilProblem(x, spec, 7),
+                      Plan(tier="distributed", shard_axis="data"), mesh=mesh)
         want = ref.stencil_run(x, spec, 7)
         print(json.dumps({"err": float(jnp.abs(got - want).max())}))
     """)
@@ -26,13 +27,16 @@ def test_distributed_stencil_matches_single(dist_run):
 def test_distributed_cg_matches_single(dist_run):
     res = dist_run("""
         import json, jax, jax.numpy as jnp
-        from repro.solvers import cg
+        from repro.exec import CGProblem, Plan, execute
         from repro.kernels import ref
+        from repro.kernels.ops import load_dataset
         from repro.dist.mesh import make_mesh
         mesh = make_mesh((8,), ("data",))
-        data, cols = cg.load_dataset("poisson_64")
+        data, cols = load_dataset("poisson_64")
         b = jax.random.normal(jax.random.key(1), (data.shape[0],), jnp.float32)
-        x_d, rr_d = cg.run_distributed(data, cols, b, 15, mesh)
+        x_d, rr_d = execute(CGProblem.from_ell(data, cols, b, 15),
+                            Plan(tier="distributed", shard_axis="data"),
+                            mesh=mesh)
         x_s, rr_s = ref.cg_run(data, cols, b, 15)
         print(json.dumps({
             "err": float(jnp.abs(x_d - x_s).max()),
@@ -47,17 +51,19 @@ def test_distributed_cg_nnz_partition(dist_run):
     naive shards would be badly imbalanced."""
     res = dist_run("""
         import json, jax, jax.numpy as jnp, numpy as np
-        from repro.solvers import cg
+        from repro.exec import CGProblem, Plan, execute
         from repro.kernels import ref
         from repro.dist.mesh import make_mesh
-        from repro.sparse import balance_report, nnz_balanced_partition
+        from repro.sparse import (balance_report, load_matrix,
+                                  nnz_balanced_partition)
         mesh = make_mesh((8,), ("data",))
-        csr = cg.load_matrix("graph_powerlaw_8k")
+        csr = load_matrix("graph_powerlaw_8k")
         ell = csr.to_ell()
         data, cols = jnp.asarray(ell.data), jnp.asarray(ell.cols)
         b = jax.random.normal(jax.random.key(1), (csr.shape[0],), jnp.float32)
-        x_n, rr_n = cg.run_distributed(data, cols, b, 8, mesh,
-                                       partition="nnz")
+        x_n, rr_n = execute(CGProblem.from_ell(data, cols, b, 8),
+                            Plan(tier="distributed", shard_axis="data",
+                                 partition="nnz"), mesh=mesh)
         x_s, rr_s = ref.cg_run(data, cols, b, 8)
         bounds = nnz_balanced_partition(csr.row_nnz, 8)
         eq = np.linspace(0, csr.shape[0], 9).astype(np.int64)

@@ -4,16 +4,16 @@ Three contracts (ISSUE: nine PRs of growth outran the docs once):
 
 * every public symbol exported by ``repro.exec`` is mentioned in
   DESIGN.md or ARCHITECTURE.md;
-* every ``--sections`` name in ``benchmarks/run.py`` has a row-prefix
-  entry in BENCHMARKS.md's sections table;
+* every configuration and cell that ``BENCHMARK.json`` declares is named
+  in PERF.md's §4 (Cells);
 * every intra-repo markdown link resolves — file and, for ``#anchor``
   links, the GitHub-style heading slug.
 """
 from __future__ import annotations
 
+import json
 import os
 import re
-import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DOCS = os.path.join(REPO, "docs")
@@ -36,18 +36,16 @@ def test_every_exec_export_is_documented():
 
 
 def test_every_bench_section_has_a_schema_entry():
-    sys.path.insert(0, REPO)
-    try:
-        from benchmarks.run import SECTIONS
-    finally:
-        sys.path.remove(REPO)
-    bench_md = _read(os.path.join(DOCS, "BENCHMARKS.md"))
-    # the "--sections name -> row prefixes" table rows: | `name` | ... |
-    documented = set(re.findall(r"^\| `(\w+)` \|", bench_md, re.M))
-    missing = [s for s in SECTIONS if s not in documented]
+    bench = json.loads(_read(os.path.join(REPO, "BENCHMARK.json")))
+    perf = _read(os.path.join(REPO, "PERF.md"))
+    cells = re.search(r"^## 4\..*?(?=^## 5\.)", perf, re.M | re.S)
+    assert cells, "PERF.md has no '## 4.' (Cells) section before '## 5.'"
+    names = ([c["name"] for c in bench["configs"]]
+             + [w["name"] for w in bench["workloads"]])
+    missing = [n for n in names if f"`{n}`" not in cells.group(0)]
     assert not missing, (
-        f"--sections names with no schema entry in BENCHMARKS.md's "
-        f"sections table: {missing}")
+        f"BENCHMARK.json configurations and cells not named in PERF.md "
+        f"§4: {missing}")
 
 
 def _github_slug(heading: str) -> str:

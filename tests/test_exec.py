@@ -5,12 +5,11 @@ Covers the tentpole contracts:
 * ``Plan`` is an immutable, JSON-round-trippable artifact;
 * ``plan()`` is monotone: a larger VMEM budget never caches fewer
   bytes, a larger ``fuse_steps`` cap never costs more barriers;
-* ``execute(problem, plan)`` reproduces every legacy ``run_*`` result
-  bit-identically over all 13 stencil specs and the full sparse
-  registry (fuse_steps > 1 included — same code, same compiled graph);
-* ``plan()`` subsumes the legacy planner entry points (``plan_for``,
-  ``plan_policy`` agree with the Plan the planner emits);
-* every legacy ``run_*`` shim warns exactly once per entry point;
+* ``execute(problem, plan(problem))`` matches the jnp oracle over all 13
+  stencil specs, and CG's device loop matches it over the full sparse
+  registry;
+* ``plan()``'s resident rows and CG policy are those of
+  ``plan_resident_planes`` and ``cg_policy_from_arrays``;
 * ``autotune`` measures the candidates and returns a member of them.
 """
 import gc
@@ -35,11 +34,13 @@ from repro.exec import (
     plan,
     plan_candidates,
 )
+from repro.core.cache_policy import cg_arrays
 from repro.exec import adapters
-from repro.exec.deprecation import reset_warnings
+from repro.exec.planner import cg_policy_from_arrays
+from repro.kernels import ref
 from repro.kernels.common import BENCHMARKS, get_spec
-from repro.solvers import cg as cgs
-from repro.solvers import stencil as ssol
+from repro.kernels.ops import load_dataset, load_sell
+from repro.kernels.stencil3d import plan_resident_planes
 from repro.sparse import REGISTRY
 
 STEPS = 4
@@ -94,7 +95,7 @@ def test_plan_derived_fields():
     assert p.cache[0].fraction == 0.25
 
 
-# -- planner: candidates, monotonicity, legacy subsumption ----------------------
+# -- planner: candidates, monotonicity, the row and policy choices ---------------------
 
 def test_plan_candidates_ranked_and_typed():
     spec = get_spec("2d5pt")
@@ -159,120 +160,114 @@ def test_planner_chip_capacity_sensitivity():
 
 
 def test_plan_subsumes_legacy_stencil_planner():
-    """plan() resident candidates carry exactly plan_for's row decision."""
+    """plan() resident candidates carry exactly plan_resident_planes' rows."""
     spec = get_spec("2d5pt")
     problem = StencilProblem(
         jax.ShapeDtypeStruct((4096, 4096), jnp.float32), spec, 1000)
-    legacy = ssol.plan_for((4096, 4096), 4, spec)
+    rows = plan_resident_planes((4096, 4096), 4, spec, chip=CHIPS["tpu_v5e"])
     cands = plan_candidates(problem)
     resident_t1 = next(c for c in cands
                        if c.tier == "resident" and c.fuse_steps == 1)
-    assert resident_t1.cached_rows == legacy["cached_rows"]
-    assert resident_t1.cache[0].cached_bytes == legacy["cached_cells"] * 4
+    assert resident_t1.cached_rows == rows
+    assert resident_t1.cache[0].cached_bytes == rows * 4096 * 4
+
+
+def _cg_policy(n, nnz):
+    budget = int(CHIPS["tpu_v5e"].onchip_bytes * 0.9)
+    return cg_policy_from_arrays(cg_arrays(n, nnz, 4), budget)["policy"]
 
 
 def test_plan_subsumes_legacy_cg_planner():
-    """The CG candidates' policy agrees with legacy plan_policy."""
+    """The CG candidates' policy agrees with cg_policy_from_arrays."""
     for n, nnz in ((10_000, 50_000), (10**6, 3 * 10**8)):
-        legacy = cgs.plan_policy(n, nnz)
+        policy = _cg_policy(n, nnz)
         b = jax.ShapeDtypeStruct((n,), jnp.float32)
         problem = CGProblem(b=b, n_steps=8,
                             data=jax.ShapeDtypeStruct((n, max(1, nnz // n)),
                                                       jnp.float32),
                             cols=None)
         cands = plan_candidates(problem)
-        if legacy["policy"] == "IMP":
+        if policy == "IMP":
             assert all(c.tier != "resident" for c in cands)
         else:
-            assert any(c.policy == legacy["policy"] for c in cands)
+            assert any(c.policy == policy for c in cands)
     # huge problem: vectors alone exceed VMEM -> IMP == no resident cand
-    assert cgs.plan_policy(10**9, 10**10)["policy"] == "IMP"
+    assert _cg_policy(10**9, 10**10) == "IMP"
 
 
-# -- executor vs legacy: all 13 stencil specs -----------------------------------
+# -- the planner's own plan against the oracle: all 13 stencil specs -----------
 
 @pytest.mark.parametrize("name", sorted(BENCHMARKS))
 def test_executor_matches_legacy_stencil(name):
-    """execute() must reproduce every legacy run_* bit-identically (the
-    shims route through the same code; this guards the routing)."""
+    """execute() under the plan the planner picks matches the jnp oracle
+    on every spec, whatever tier that is."""
     spec = get_spec(name)
     x = _domain(spec)
     problem = StencilProblem(x, spec, STEPS)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        legacy_host = ssol.run_host_loop(x, spec, STEPS)
-        legacy_dev = ssol.run_device_loop(x, spec, STEPS)
-        legacy_res = ssol.run_resident(x, spec, STEPS,
-                                       cached_rows=x.shape[0] // 2,
-                                       sub_rows=8)
-        legacy_fused = ssol.run_resident(x, spec, STEPS,
-                                         cached_rows=x.shape[0] // 2,
-                                         sub_rows=32, fuse_steps=2)
-    np.testing.assert_array_equal(
-        np.asarray(execute(problem, Plan(tier="host_loop"))),
-        np.asarray(legacy_host))
-    np.testing.assert_array_equal(
-        np.asarray(execute(problem, Plan(tier="device_loop"))),
-        np.asarray(legacy_dev))
-    np.testing.assert_array_equal(
-        np.asarray(execute(problem, Plan(tier="resident",
-                                         cached_rows=x.shape[0] // 2,
-                                         sub_rows=8))),
-        np.asarray(legacy_res))
-    # fuse_steps > 1: same plan -> same compiled graph -> still exact
-    np.testing.assert_array_equal(
-        np.asarray(execute(problem, Plan(tier="resident",
-                                         cached_rows=x.shape[0] // 2,
-                                         sub_rows=32, fuse_steps=2))),
-        np.asarray(legacy_fused))
+    np.testing.assert_allclose(
+        np.asarray(execute(problem, plan(problem))),
+        np.asarray(ref.stencil_run(x, spec, STEPS)), rtol=0, atol=1e-5)
 
 
-# -- executor vs legacy: the full sparse registry -------------------------------
+# -- CG's device loop against the oracle: the full sparse registry -------------
 
 @pytest.mark.parametrize("name", sorted(REGISTRY))
 def test_executor_matches_legacy_cg(name):
-    data, cols = cgs.load_dataset(name)
+    """Exact where CG keeps the ELL gather the oracle runs; to f32
+    reassociation where the DIA SpMV sums in another order."""
+    data, cols = load_dataset(name)
     b = jax.random.normal(jax.random.key(1), (data.shape[0],), jnp.float32)
     iters = 5
     problem = CGProblem.from_ell(data, cols, b, iters)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        x_leg, rr_leg = cgs.run_device_loop(data, cols, b, iters)
     x_new, rr_new = execute(problem, Plan(tier="device_loop"))
-    np.testing.assert_array_equal(np.asarray(x_new), np.asarray(x_leg))
-    assert float(rr_new) == float(rr_leg)
+    x_ref, rr_ref = ref.cg_run(data, cols, b, iters)
+    if problem.spmv_format == "ell":
+        np.testing.assert_array_equal(np.asarray(x_new), np.asarray(x_ref))
+        assert float(rr_new) == float(rr_ref)
+    else:
+        np.testing.assert_allclose(np.asarray(x_new), np.asarray(x_ref),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(float(rr_new), float(rr_ref), rtol=1e-5)
 
 
 def test_executor_matches_legacy_cg_fused_and_sell():
-    data, cols = cgs.load_dataset("poisson_64")
+    """Resident MIX and the SELL matvec against the device loop."""
+    data, cols = load_dataset("poisson_64")
     b = jax.random.normal(jax.random.key(1), (data.shape[0],), jnp.float32)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        x_leg, rr_leg = cgs.run_fused(data, cols, b, 8, policy="MIX")
-        op = cgs.load_sell("graph_powerlaw_8k")
-        bs = jax.random.normal(jax.random.key(2), (op.n_rows,), jnp.float32)
-        x_sell_leg, _ = cgs.run_device_loop_sell(op, bs, 5)
     p = CGProblem.from_ell(data, cols, b, 8)
-    x_new, rr_new = execute(p, Plan(tier="resident", policy="MIX",
+    x_res, rr_res = execute(p, Plan(tier="resident", policy="MIX",
                                     block_rows=256))
-    np.testing.assert_array_equal(np.asarray(x_new), np.asarray(x_leg))
-    ps = CGProblem.from_matvec(op.matvec, bs, 5)
-    x_sell_new, _ = execute(ps, Plan(tier="device_loop"))
-    np.testing.assert_array_equal(np.asarray(x_sell_new),
-                                  np.asarray(x_sell_leg))
+    x_loop, rr_loop = execute(p, Plan(tier="device_loop"))
+    np.testing.assert_allclose(np.asarray(x_res), np.asarray(x_loop),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(float(rr_res), float(rr_loop), rtol=1e-4)
+    op = load_sell("graph_powerlaw_8k")
+    bs = jax.random.normal(jax.random.key(2), (op.n_rows,), jnp.float32)
+    ds, cs = load_dataset("graph_powerlaw_8k")
+    x_sell, _ = execute(CGProblem.from_matvec(op.matvec, bs, 5,
+                                              matrix=op.matrix),
+                        Plan(tier="device_loop"))
+    x_ell, _ = execute(CGProblem.from_ell(ds, cs, bs, 5),
+                       Plan(tier="device_loop"))
+    np.testing.assert_allclose(np.asarray(x_sell), np.asarray(x_ell),
+                               rtol=1e-5, atol=1e-5)
 
 
 def test_executor_early_stop_matches_legacy():
-    data, cols = cgs.load_dataset("poisson_64")
+    """The device loop's early stop, synced every 25 steps, lands where
+    the host loop checking at the same cadence stops: the same iterate,
+    bit for bit, well before the step cap."""
+    data, cols = load_dataset("poisson_64")
     b = jax.random.normal(jax.random.key(0), (data.shape[0],), jnp.float32)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        x_leg, rr_leg = cgs.run_device_loop(data, cols, b, 500,
-                                            sync_every=25, tol=1e-10)
     p = CGProblem.from_ell(data, cols, b, 500, tol=1e-10)
-    x_new, rr_new = execute(p, Plan(tier="device_loop", sync_every=25))
-    np.testing.assert_array_equal(np.asarray(x_new), np.asarray(x_leg))
-    assert float(rr_new) == float(rr_leg)
+    reg = obs.MetricsRegistry()
+    with obs.use_metrics(reg):
+        x_dev, rr_dev = execute(p, Plan(tier="device_loop", sync_every=25))
+    assert reg.value("executor_steps_total", tier="device_loop") < 500
+    x_host, rr_host = execute(p, Plan(tier="host_loop", fuse_steps=25))
+    assert float(rr_dev) < 1e-10 * float(jnp.vdot(b, b)) * 10
+    np.testing.assert_array_equal(np.asarray(x_dev), np.asarray(x_host))
+    assert float(rr_dev) == float(rr_host)
 
 
 def test_declared_convergence_check_is_planned_and_honored():
@@ -280,7 +275,7 @@ def test_declared_convergence_check_is_planned_and_honored():
     (device-loop candidates carry sync_every) and early-stops; a
     hand-built plan that drops the check warns instead of silently
     running all steps."""
-    data, cols = cgs.load_dataset("poisson_64")
+    data, cols = load_dataset("poisson_64")
     b = jax.random.normal(jax.random.key(0), (data.shape[0],), jnp.float32)
     problem = CGProblem.from_ell(data, cols, b, 500, tol=1e-10)
     dev = next(c for c in plan_candidates(problem)
@@ -298,7 +293,7 @@ def test_host_loop_honors_declared_convergence():
     per-step loop with the same check bit-for-bit."""
     from repro.exec.executor import honors_on_sync
 
-    data, cols = cgs.load_dataset("poisson_64")
+    data, cols = load_dataset("poisson_64")
     b = jax.random.normal(jax.random.key(7), (data.shape[0],), jnp.float32)
     problem = CGProblem.from_ell(data, cols, b, 500, tol=1e-10)
     assert honors_on_sync(Plan(tier="host_loop"), 500)
@@ -364,62 +359,6 @@ def test_autotune_returns_measured_winner():
         assert Plan.from_json(r.plan.to_json()) == r.plan
 
 
-# -- deprecation hygiene --------------------------------------------------------
-
-STENCIL_SHIMS = ("run_host_loop", "run_device_loop", "run_resident",
-                 "run_distributed")
-CG_SHIMS = ("run_host_loop", "run_device_loop", "run_device_loop_sell",
-            "run_fused", "run_distributed")
-
-
-def _call_shim(module, entry):
-    spec = get_spec("2d5pt")
-    x = jax.random.normal(jax.random.key(0), (16, 16), jnp.float32)
-    if module is ssol:
-        if entry == "run_distributed":
-            # needs a mesh; validation raises before any warning matters —
-            # exercise the warn path via a 1-chip mesh if available
-            from repro.dist.mesh import make_mesh
-            mesh = make_mesh((1,), ("data",))
-            return ssol.run_distributed(x, spec, 2, mesh)
-        return getattr(ssol, entry)(x, spec, 2)
-    data, cols = cgs.load_dataset("poisson_64")
-    b = jnp.ones((data.shape[0],), jnp.float32)
-    if entry == "run_device_loop_sell":
-        op = cgs.load_sell("poisson_64")
-        return cgs.run_device_loop_sell(op, b, 2)
-    if entry == "run_fused":
-        return cgs.run_fused(data, cols, b, 2)
-    if entry == "run_distributed":
-        from repro.dist.mesh import make_mesh
-        mesh = make_mesh((1,), ("data",))
-        return cgs.run_distributed(data, cols, b, 2, mesh)
-    return getattr(cgs, entry)(data, cols, b, 2)
-
-
-@pytest.mark.parametrize("module,entry",
-                         [(ssol, e) for e in STENCIL_SHIMS]
-                         + [(cgs, e) for e in CG_SHIMS],
-                         ids=lambda v: v if isinstance(v, str) else
-                         v.__name__.rsplit(".", 1)[-1])
-def test_legacy_shim_warns_exactly_once(module, entry):
-    reset_warnings()
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        _call_shim(module, entry)
-        first = [x for x in w if issubclass(x.category, DeprecationWarning)
-                 and entry in str(x.message)]
-        assert len(first) == 1, [str(x.message) for x in w]
-        assert "repro.exec" in str(first[0].message)
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        _call_shim(module, entry)     # second call: silent
-        again = [x for x in w if issubclass(x.category, DeprecationWarning)
-                 and entry in str(x.message)]
-        assert again == [], [str(x.message) for x in w]
-    reset_warnings()
-
-
 @pytest.mark.parametrize("platform,kind,want", [
     ("tpu", "TPU v5 lite", "tpu_v5e"),
     ("tpu", "TPU v99", None),          # unknown kind: an error
@@ -476,7 +415,7 @@ def test_second_solve_over_one_operator_reuses_the_runner(case,
     runner is built once, the second solve dispatches it with no
     ``repro.compile``, and its answer is bit for bit a cold run's."""
     cls, name, spmv = RUNNER_CASES[case]
-    data, cols = cgs.load_dataset(name)
+    data, cols = load_dataset(name)
     first = _krylov(cls, data, cols, 0)
     if cls is CGProblem:
         assert first.spmv_format == spmv
@@ -502,7 +441,7 @@ def test_a_same_shaped_operator_gets_its_own_answer(fresh_runners):
     """Another operator of the same structure reuses the executable, and
     its values, passed as arguments, give its own answer: 2A x = b is
     solved by half of A's x, bit for bit as a cold run solves it."""
-    data, cols = cgs.load_dataset("poisson_64")
+    data, cols = load_dataset("poisson_64")
     data2 = data * 2.0
     b = jax.random.normal(jax.random.key(3), (data.shape[0],), jnp.float32)
     reg = obs.MetricsRegistry()
@@ -524,7 +463,7 @@ def test_a_same_shaped_operator_gets_its_own_answer(fresh_runners):
 def test_the_operator_outlives_its_solves(fresh_runners):
     """The runners donate the state only: the ELL planes and the DIA
     planes they were converted to are readable after two solves each."""
-    data, cols = cgs.load_dataset("poisson_64")
+    data, cols = load_dataset("poisson_64")
     for cls in (CGProblem, BiCGStabProblem):
         for seed in (0, 1):
             execute(_krylov(cls, data, cols, seed), SYNCED)

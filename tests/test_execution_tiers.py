@@ -30,9 +30,10 @@ import numpy as np
 import pytest
 
 from repro.core import perks
+from repro.exec import Plan, StencilProblem, execute
+from repro.exec.adapters import fusion_schedule
 from repro.kernels import ref
 from repro.kernels.common import BENCHMARKS, get_spec
-from repro.solvers import stencil
 
 STEPS = 4
 
@@ -42,14 +43,17 @@ def _domain(spec):
     return jax.random.normal(jax.random.key(0), shape, jnp.float32)
 
 
+def _run(x, spec, steps, tier, **plan):
+    return execute(StencilProblem(x, spec, steps), Plan(tier=tier, **plan))
+
+
 @pytest.mark.parametrize("name", sorted(BENCHMARKS))
 def test_tiers_bit_identical(name):
     spec = get_spec(name)
     x = _domain(spec)
-    host = stencil.run_host_loop(x, spec, STEPS)
-    device = stencil.run_device_loop(x, spec, STEPS)
-    resident = stencil.run_resident(x, spec, STEPS,
-                                    cached_rows=x.shape[0])
+    host = _run(x, spec, STEPS, "host_loop")
+    device = _run(x, spec, STEPS, "device_loop")
+    resident = _run(x, spec, STEPS, "resident", cached_rows=x.shape[0])
     step = functools.partial(ref.stencil_step, spec=spec)
     chunked = perks.chunked_loop(step, STEPS, sync_every=2)(x)
     np.testing.assert_array_equal(np.asarray(host), np.asarray(device))
@@ -61,10 +65,9 @@ def test_tiers_bit_identical(name):
 def test_partial_caching_within_ulp(name):
     spec = get_spec(name)
     x = _domain(spec)
-    device = stencil.run_device_loop(x, spec, STEPS)
-    perks_partial = stencil.run_resident(x, spec, STEPS,
-                                         cached_rows=x.shape[0] // 2,
-                                         sub_rows=8)
+    device = _run(x, spec, STEPS, "device_loop")
+    perks_partial = _run(x, spec, STEPS, "resident",
+                         cached_rows=x.shape[0] // 2, sub_rows=8)
     np.testing.assert_allclose(np.asarray(perks_partial), np.asarray(device),
                                rtol=0, atol=2.5e-7)
 
@@ -79,10 +82,10 @@ def test_resident_fused_matches_per_step(name, fuse):
     spec = get_spec(name)
     x = _domain(spec)
     steps = 5
-    base = stencil.run_resident(x, spec, steps, cached_rows=x.shape[0] // 2,
-                                sub_rows=32)
-    fused = stencil.run_resident(x, spec, steps, cached_rows=x.shape[0] // 2,
-                                 sub_rows=32, fuse_steps=fuse)
+    base = _run(x, spec, steps, "resident", cached_rows=x.shape[0] // 2,
+                sub_rows=32)
+    fused = _run(x, spec, steps, "resident", cached_rows=x.shape[0] // 2,
+                 sub_rows=32, fuse_steps=fuse)
     np.testing.assert_allclose(np.asarray(fused), np.asarray(base),
                                rtol=0, atol=5e-7)
     # and against the jnp oracle at the usual kernel tolerance
@@ -94,7 +97,7 @@ def test_resident_fused_matches_per_step(name, fuse):
 def test_fusion_schedule_covers_steps_with_ceil_barriers():
     for steps in (1, 2, 5, 7, 12):
         for t in (1, 2, 3, 4, 16):
-            sched = stencil.fusion_schedule(steps, t)
+            sched = fusion_schedule(steps, t)
             assert sum(n * ct for n, ct in sched) == steps
             assert sum(n for n, _ in sched) == -(-steps // t)  # ceil
             assert all(ct <= t for _, ct in sched)
@@ -104,12 +107,18 @@ def test_fusion_schedule_covers_steps_with_ceil_barriers():
 
 _DIST_FUSED = """
     import json, jax, jax.numpy as jnp, numpy as np
+    from repro.exec import Plan, StencilProblem, execute
     from repro.kernels.common import BENCHMARKS
     from repro.kernels import ref
-    from repro.solvers import stencil
     from repro.dist.mesh import make_mesh
 
     mesh = make_mesh((4,), ("data",))
+
+    def run(x, spec, t):
+        return execute(StencilProblem(x, spec, 5),
+                       Plan(tier="distributed", shard_axis="data",
+                            fuse_steps=t), mesh=mesh)
+
     out = {{}}
     for name, spec in BENCHMARKS.items():
         if spec.ndim != {ndim}:
@@ -117,18 +126,18 @@ _DIST_FUSED = """
         shape = (64, 128) if spec.ndim == 2 else (32, 12, 16)
         shard = shape[0] // 4
         x = jax.random.normal(jax.random.key(0), shape, jnp.float32)
-        base = stencil.run_distributed(x, spec, 5, mesh, fuse_steps=1)
+        base = run(x, spec, 1)
         oracle_err = float(jnp.abs(base - ref.stencil_run(x, spec, 5)).max())
         rows = {{"oracle_err": oracle_err}}
         for t in (2, 4):
             if spec.radius * t > shard:
                 try:
-                    stencil.run_distributed(x, spec, 5, mesh, fuse_steps=t)
+                    run(x, spec, t)
                     rows[str(t)] = "missing ValueError"
                 except ValueError:
                     rows[str(t)] = "infeasible"
                 continue
-            got = stencil.run_distributed(x, spec, 5, mesh, fuse_steps=t)
+            got = run(x, spec, t)
             rows[str(t)] = float(jnp.abs(got - base).max())
         out[name] = rows
     print(json.dumps(out))
@@ -157,8 +166,8 @@ def test_distributed_fused_collective_count(dist_run):
     scan trip counts multiplied through."""
     res = dist_run(textwrap.dedent("""
         import json, jax, jax.numpy as jnp
+        from repro.exec import Plan, StencilProblem, execute
         from repro.kernels.common import get_spec
-        from repro.solvers import stencil
         from repro.dist.mesh import make_mesh
 
         def count_ppermute(jx, mult=1):
@@ -180,8 +189,10 @@ def test_distributed_fused_collective_count(dist_run):
         x = jnp.zeros((64, 128), jnp.float32)
         out = {}
         for t in (1, 2, 4):
-            jx = jax.make_jaxpr(lambda x: stencil.run_distributed(
-                x, spec, 7, mesh, fuse_steps=t))(x)
+            jx = jax.make_jaxpr(lambda x: execute(
+                StencilProblem(x, spec, 7),
+                Plan(tier="distributed", shard_axis="data", fuse_steps=t),
+                mesh=mesh))(x)
             out[str(t)] = count_ppermute(jx.jaxpr)
         print(json.dumps(out))
     """), n_dev=8, timeout=600)
@@ -194,11 +205,12 @@ def test_distributed_cg_fused_reductions(dist_run):
     """Pipelined CG: ONE psum per iteration (vs two), matching textbook CG
     even past convergence (banded_4k reaches machine-zero residual well
     before iteration 25 — the regime where an unguarded recurrence
-    explodes; see solvers/cg.py)."""
+    explodes; see ``exec/adapters.cg_distributed``)."""
     res = dist_run("""
         import json, jax, jax.numpy as jnp
-        from repro.solvers import cg
+        from repro.exec import CGProblem, Plan, execute
         from repro.kernels import ref
+        from repro.kernels.ops import load_dataset
         from repro.dist.mesh import make_mesh
 
         def count_psum(jx, mult=1):
@@ -216,22 +228,26 @@ def test_distributed_cg_fused_reductions(dist_run):
             return n
 
         mesh = make_mesh((8,), ("data",))
+
+        def run(data, cols, b, iters, fused):
+            return execute(CGProblem.from_ell(data, cols, b, iters),
+                           Plan(tier="distributed", shard_axis="data",
+                                fuse_reductions=fused), mesh=mesh)
+
         out = {}
         for ds, iters in (("banded_4k", 25), ("poisson_64", 25)):
-            data, cols = cg.load_dataset(ds)
+            data, cols = load_dataset(ds)
             b = jax.random.normal(jax.random.key(1), (data.shape[0],),
                                   jnp.float32)
             x_ref, rr_ref = ref.cg_run(data, cols, b, iters)
-            x_f, rr_f = cg.run_distributed(data, cols, b, iters, mesh,
-                                           fuse_reductions=True)
+            x_f, rr_f = run(data, cols, b, iters, True)
             scale = float(jnp.abs(x_ref).max())
             out[ds] = {"rel_err": float(jnp.abs(x_f - x_ref).max()) / scale,
                        "rr": float(rr_f), "rr_ref": float(rr_ref)}
-        data, cols = cg.load_dataset("poisson_64")
+        data, cols = load_dataset("poisson_64")
         b = jnp.ones((data.shape[0],))
         for fused, key in ((True, "fused"), (False, "textbook")):
-            jx = jax.make_jaxpr(lambda b: cg.run_distributed(
-                data, cols, b, 5, mesh, fuse_reductions=fused))(b)
+            jx = jax.make_jaxpr(lambda b: run(data, cols, b, 5, fused))(b)
             out[key + "_psums"] = count_psum(jx.jaxpr)
         print(json.dumps(out))
     """, n_dev=8, timeout=600)
@@ -250,11 +266,11 @@ _KRYLOV_TIERS = """
     from repro.exec import BiCGStabProblem, GMRESProblem, Plan, execute
     from repro.exec.krylov import cg_sstep_distributed
     from repro.kernels import ref
-    from repro.solvers import cg as cgs
+    from repro.kernels.ops import load_dataset
     from repro.dist.mesh import make_mesh
 
     mesh = make_mesh((8,), ("data",))
-    data, cols = cgs.load_dataset("banded_4k")
+    data, cols = load_dataset("banded_4k")
     b = jax.random.normal(jax.random.key(1), (data.shape[0],), jnp.float32)
     out = {}
 
@@ -340,7 +356,7 @@ def test_krylov_collective_counts(dist_run):
         from repro.exec.krylov import (bicgstab_distributed,
                                        cg_sstep_distributed,
                                        gmres_distributed)
-        from repro.solvers import cg as cgs
+        from repro.kernels.ops import load_dataset
         from repro.dist.mesh import make_mesh
 
         def count_psum(jx, mult=1):
@@ -358,7 +374,7 @@ def test_krylov_collective_counts(dist_run):
             return n
 
         mesh = make_mesh((8,), ("data",))
-        data, cols = cgs.load_dataset("poisson_64")
+        data, cols = load_dataset("poisson_64")
         b = jnp.ones((data.shape[0],))
         out = {}
         for fused, key in ((True, "bicgstab_fused"),

@@ -42,7 +42,7 @@ from repro.runtime.solver_service import (
     ServiceConfig,
     SolverService,
 )
-from repro.solvers.cg import load_dataset
+from repro.kernels.ops import load_dataset
 
 
 def _tick_clock():
@@ -386,13 +386,17 @@ def test_drift_report_thresholds():
 
 
 def test_ledger_records_have_finite_ratios(tmp_path):
-    """The CI gate's invariant: every autotuned row has a nonzero
-    prediction and a finite prediction_ratio."""
+    """Every autotuned row has a nonzero prediction and a finite
+    prediction_ratio, and each measured candidate leaves one ``measure``
+    event in the ambient trace."""
     path = str(tmp_path / "ledger.json")
     led = obs.DriftLedger(path)
-    autotune(_stencil(), top_k=3, warmup=0, iters=1, ledger=led)
+    tr = obs.Tracer()
+    with obs.use_tracer(tr):
+        autotune(_stencil(), top_k=3, warmup=0, iters=1, ledger=led)
     recs = obs.DriftLedger(path).records()
     assert recs
+    assert len(tr.by_cat("measure")) == len(recs) == 3
     for key, sig, rec in recs:
         assert rec.predicted_s and rec.predicted_s > 0, (key, sig)
         assert math.isfinite(rec.prediction_ratio), (key, sig)

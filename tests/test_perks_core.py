@@ -9,7 +9,7 @@ from _hyp import given, settings, st  # optional-dep shim (tests/_hyp.py)
 
 from repro.core import perks
 from repro.core.cache_policy import (CacheableArray, gm_bytes_fused,
-                                     plan_caching, plan_fuse_steps,
+                                     plan_caching,
                                      cg_arrays, stencil_arrays,
                                      stencil_shard_arrays)
 from repro.core.hardware import A100, TPU_V5E
@@ -220,16 +220,6 @@ def test_gm_bytes_fused_recovers_and_beats_eq5():
     assert fused < base            # t x fewer passes dominates the overlap
     full = gm_bytes_fused(N, dom, dom, row_bytes=rb, radius=r, fuse_steps=4)
     assert full == 2 * dom         # fully cached: initial load + final store
-
-
-def test_plan_fuse_steps_respects_shard_and_counts_barriers():
-    p = plan_fuse_steps(100, shard_rows=16, row_bytes=10, radius=3)
-    assert p.fuse_steps == 5                   # 16 // 3
-    assert p.barriers == 20                    # ceil(100/5)
-    assert p.halo_rows_per_exchange == 2 * 3 * 5
-    p1 = plan_fuse_steps(100, shard_rows=2, row_bytes=10, radius=2)
-    assert p1.fuse_steps == 1 and p1.barriers == 100
-    assert p1.redundant_row_updates == 0
 
 
 # -- performance model (paper §IV-B worked examples) -----------------------------

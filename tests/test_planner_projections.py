@@ -1,34 +1,30 @@
-"""CI perf-regression gate on the planner's *deterministic* projections.
+"""Golden gate on the planner's *deterministic* projections.
 
-Wall-clock timing on shared CI runners is too noisy to gate on; the perf
-model (``core.perf_model``, paper Eqs. 5-11, generalized by the batched
-planner) is pure arithmetic over static shapes and chip specs —
-bit-reproducible on any machine. This script projects the planner's
+The perf model (``core.perf_model``, paper Eqs. 5-11, generalized by the
+batched planner) is pure arithmetic over static shapes and chip specs, so
+it is bit-reproducible on any machine. This projects the planner's
 winning time for a fixed portfolio of problems (stencil families at
-production shapes, CG at several operator sizes, each at batch 1 and 8)
-and compares against the committed baseline:
+production shapes, CG, BiCGStab and GMRES at one operator size or two,
+decode and the SSD scan, each at batch 1 and 8) on ``ShapeDtypeStruct``
+operands, and compares each with ``data/planner_projections.json``.
 
-    PYTHONPATH=src python benchmarks/check_regression.py            # gate
-    PYTHONPATH=src python benchmarks/check_regression.py --update   # refresh
+A case fails when its projection regresses more than ``TOLERANCE`` (5%)
+or disappears; the portfolio test fails when an entry is added that the
+baseline lacks. Improvements pass. A change that moves a projection on
+purpose refreshes the baseline in the same commit:
 
-The gate fails when any projection regresses more than ``TOLERANCE`` (5%)
-versus ``baseline_projections.json``, when a baseline entry disappears
-(coverage regression), or when a new entry is not yet in the baseline
-(refresh it in the same PR that adds the entry). Improvements are
-reported and allowed — refresh the baseline to lock them in.
+    PYTHONPATH=src python tests/test_planner_projections.py > tests/data/planner_projections.json
 """
 
 from __future__ import annotations
 
-import argparse
+import functools
 import json
 import os
-import sys
-
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 import jax.numpy as jnp
+import pytest
 
 from repro.exec import (
     BiCGStabProblem,
@@ -41,10 +37,11 @@ from repro.exec import (
 )
 from repro.kernels.common import get_spec
 
-_HERE = os.path.dirname(os.path.abspath(__file__))
-BASELINE_PATH = os.path.join(_HERE, "baseline_projections.json")
+BASELINE_PATH = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "data", "planner_projections.json"
+)
 
-# allowed slowdown before the gate fails
+# allowed slowdown before a case fails
 TOLERANCE = 0.05
 
 # planner-visible portfolio: (family, shape, steps) x batch, projected on
@@ -70,6 +67,7 @@ SSMS = ((4096, 8, 16, 32, 128),)  # (T, H, P, N, chunk)
 BATCHES = (1, 8)
 
 
+@functools.lru_cache(maxsize=1)
 def current_projections() -> dict[str, float]:
     out: dict[str, float] = {}
     for name, shape, steps in STENCILS:
@@ -142,54 +140,24 @@ def current_projections() -> dict[str, float]:
     return out
 
 
-def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument(
-        "--update",
-        action="store_true",
-        help="rewrite the baseline with current projections",
-    )
-    ap.add_argument("--baseline", default=BASELINE_PATH)
-    args = ap.parse_args(argv)
+with open(BASELINE_PATH) as f:
+    BASELINE: dict[str, float] = json.load(f)
 
+
+@pytest.mark.parametrize("key", sorted(BASELINE))
+def test_planner_projection_within_tolerance(key):
     current = current_projections()
-    if args.update:
-        with open(args.baseline, "w") as f:
-            json.dump(current, f, indent=2, sort_keys=True)
-            f.write("\n")
-        print(f"wrote {len(current)} projections to {args.baseline}")
-        return 0
+    assert key in current, f"{key}: projection disappeared (coverage regression)"
+    base, cur = BASELINE[key], current[key]
+    assert cur <= base * (1.0 + TOLERANCE), (
+        f"{key}: {base:.4e}s -> {cur:.4e}s ({(cur / base - 1.0) * 100.0:+.1f}%)"
+    )
 
-    with open(args.baseline) as f:
-        baseline = json.load(f)
 
-    failures = []
-    print(f"{'problem':48s} {'baseline_s':>12s} {'current_s':>12s} {'ratio':>7s}")
-    for key in sorted(baseline):
-        if key not in current:
-            failures.append(f"{key}: projection disappeared (coverage regression)")
-            continue
-        base, cur = baseline[key], current[key]
-        ratio = cur / base if base else float("inf")
-        mark = ""
-        if ratio > 1.0 + TOLERANCE:
-            mark = "  <-- REGRESSION"
-            pct = (ratio - 1.0) * 100.0
-            failures.append(f"{key}: {base:.3e}s -> {cur:.3e}s ({pct:+.1f}%)")
-        elif ratio < 1.0 - TOLERANCE:
-            mark = "  (improved; --update to lock in)"
-        print(f"{key:48s} {base:12.4e} {cur:12.4e} {ratio:7.3f}{mark}")
-    for key in sorted(set(current) - set(baseline)):
-        failures.append(f"{key}: not in baseline — refresh it with --update")
-
-    if failures:
-        print(f"\nFAIL: {len(failures)} projection regression(s):", file=sys.stderr)
-        for line in failures:
-            print(f"  {line}", file=sys.stderr)
-        return 1
-    print(f"\nOK: {len(baseline)} projections within {TOLERANCE:.0%} of baseline")
-    return 0
+def test_planner_projection_portfolio_matches_baseline():
+    extra = sorted(set(current_projections()) - set(BASELINE))
+    assert not extra, f"not in {BASELINE_PATH}; refresh it: {extra}"
 
 
 if __name__ == "__main__":
-    raise SystemExit(main())
+    print(json.dumps(current_projections(), indent=2, sort_keys=True))

@@ -20,7 +20,7 @@ from repro.runtime.solver_service import (
     ServiceConfig,
     SolverService,
 )
-from repro.solvers.cg import load_dataset
+from repro.kernels.ops import load_dataset
 from repro.sparse.generate import banded_spd
 
 STEPS = 4

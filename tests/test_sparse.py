@@ -8,9 +8,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.cache_policy import cg_arrays, cg_arrays_for
+from repro.exec import CGProblem, Plan, execute
+from repro.exec.planner import cg_policy_from_arrays
 from repro.kernels import ops, ref
 from repro.kernels.spmv_ell import dense_to_ell
-from repro.solvers import cg as cgs
 from repro.sparse import (
     COOMatrix,
     CSRMatrix,
@@ -162,7 +164,7 @@ def test_registry_spmv_matches_oracle(name):
     want = csr.matvec(x).astype(np.float32)
     scale = max(1.0, float(np.abs(want).max()))
 
-    op = cgs.SellOperator.from_matrix(sell)
+    op = ops.SellOperator.from_matrix(sell)
     got_sell = np.asarray(op.matvec(jnp.asarray(x)))
     np.testing.assert_allclose(got_sell / scale, want / scale, atol=2e-6)
 
@@ -221,11 +223,14 @@ def test_padding_report_accounting(rng):
 def test_cg_sell_matches_ell_device_loop():
     csr = generate("fem_band_8k")
     ell = csr.to_ell()
-    op = cgs.SellOperator.from_matrix(csr.to_sell(c=32, sigma=256))
+    op = ops.SellOperator.from_matrix(csr.to_sell(c=32, sigma=256))
     b = jax.random.normal(KEY, (csr.shape[0],), jnp.float32)
-    x_e, rr_e = cgs.run_device_loop(jnp.asarray(ell.data),
-                                    jnp.asarray(ell.cols), b, 20)
-    x_s, rr_s = cgs.run_device_loop_sell(op, b, 20)
+    loop = Plan(tier="device_loop")
+    x_e, rr_e = execute(CGProblem.from_ell(jnp.asarray(ell.data),
+                                           jnp.asarray(ell.cols), b, 20),
+                        loop)
+    x_s, rr_s = execute(CGProblem.from_matvec(op.matvec, b, 20,
+                                              matrix=op.matrix), loop)
     scale = float(jnp.abs(x_e).max())
     assert float(jnp.abs(x_s - x_e).max()) / scale < 1e-4
     assert abs(float(rr_s) - float(rr_e)) <= 1e-3 * (float(rr_e) + 1e-12)
@@ -242,9 +247,9 @@ def test_plan_policy_uses_true_nnz():
     padded_slots = int(ell.data.size)
     assert padded_slots > 10 * csr.nnz
     budget = csr.shape[0] * 4 * 4 + csr.nnz * 8 + 1024
-    true_plan = cgs.plan_policy(matrix=csr, budget_bytes=budget)
-    padded_plan = cgs.plan_policy(csr.shape[0], padded_slots,
-                                  budget_bytes=budget)
+    true_plan = cg_policy_from_arrays(cg_arrays_for(csr), budget)
+    padded_plan = cg_policy_from_arrays(
+        cg_arrays(csr.shape[0], padded_slots, 4), budget)
     assert true_plan["policy"] == "MIX"
     assert true_plan["matrix_fraction"] == 1.0
     assert padded_plan["matrix_fraction"] < 0.2
@@ -358,31 +363,18 @@ def test_nonsymmetric_entries_straddle_proxy_vmem():
     assert big.nnz * 8 > PROXY_ONCHIP_BYTES
 
 
-def test_sell_operator_threads_true_nnz_to_planner(monkeypatch):
-    """Regression: ``run_device_loop_sell`` used to build its CGProblem
-    without ``matrix=``, so the planner saw nnz=0 for A on the SELL path
-    (A absent from the knapsack entirely). The SellOperator now carries
-    its source container and the shim threads it through — the captured
-    problem must rank A by the container's TRUE nnz, not its padded
-    slots and not zero."""
-    from repro.core.cache_policy import cg_arrays_for
-    from repro.solvers import cg as cgs
-
-    op = cgs.load_sell("fem_band_8k")
+def test_sell_operator_threads_true_nnz_to_planner():
+    """A SELL operator carries its source container, and a CGProblem built
+    on its matvec with ``matrix=`` ranks A by the container's TRUE nnz,
+    not its padded slots and not zero (without the container A would be
+    absent from the knapsack entirely)."""
+    op = ops.load_sell("fem_band_8k")
     assert op.matrix is not None
-    captured = {}
-    real_execute = cgs.execute
-
-    def spy(problem, plan, **kw):
-        captured["problem"] = problem
-        return real_execute(problem, plan, **kw)
-
-    monkeypatch.setattr(cgs, "execute", spy)
     b = np.random.default_rng(0).standard_normal(op.n_rows).astype(np.float32)
-    with pytest.warns(DeprecationWarning):
-        cgs.run_device_loop_sell(op, jnp.asarray(b), 2)
-
-    prob = captured["problem"]
+    prob = CGProblem.from_matvec(op.matvec, jnp.asarray(b), 2,
+                                 matrix=op.matrix)
+    x, rr = execute(prob, Plan(tier="device_loop"))
+    assert np.isfinite(np.asarray(x)).all() and np.isfinite(float(rr))
     assert prob.matrix is op.matrix
     a_entry = {a.name: a for a in prob.cacheable_arrays()}["A"]
     true_a = {a.name: a for a in cg_arrays_for(op.matrix)}["A"]

@@ -20,7 +20,8 @@ from repro.exec import execute_sequential
 from repro.exec import adapters
 from repro.kernels import ref as kref
 from repro.kernels.spmv_dia import ell_to_dia, spmv_dia
-from repro.solvers.cg import load_dataset, load_matrix
+from repro.kernels.ops import load_dataset
+from repro.sparse import load_matrix
 from repro.sparse.generate import poisson2d
 
 

@@ -5,10 +5,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import hlo_costs
+from repro.core.cache_policy import cg_arrays
+from repro.core.hardware import TPU_V5E
+from repro.exec import CGProblem, Plan, StencilProblem, execute
+from repro.exec.planner import cg_policy_from_arrays
 from repro.kernels import ref
 from repro.kernels.common import get_spec
-from repro.solvers import cg as cg_solver
-from repro.solvers import stencil as stencil_solver
+from repro.kernels.ops import load_dataset
+from repro.kernels.stencil3d import plan_resident_planes
 
 KEY = jax.random.key(0)
 
@@ -18,9 +22,10 @@ KEY = jax.random.key(0)
 def test_stencil_execution_tiers_identical():
     spec = get_spec("2d13pt")
     x = jax.random.normal(KEY, (64, 128), jnp.float32)
-    a = stencil_solver.run_host_loop(x, spec, 5)
-    b = stencil_solver.run_device_loop(x, spec, 5)
-    c = stencil_solver.run_resident(x, spec, 5, cached_rows=32, sub_rows=16)
+    problem = StencilProblem(x, spec, 5)
+    a = execute(problem, Plan(tier="host_loop"))
+    b = execute(problem, Plan(tier="device_loop"))
+    c = execute(problem, Plan(tier="resident", cached_rows=32, sub_rows=16))
     want = ref.stencil_run(x, spec, 5)
     for got in (a, b, c):
         np.testing.assert_allclose(got, want, atol=1e-5)
@@ -28,42 +33,46 @@ def test_stencil_execution_tiers_identical():
 
 def test_stencil_cache_plan_reporting():
     spec = get_spec("2d5pt")
-    plan = stencil_solver.plan_for((4096, 4096), 4, spec)
-    assert 0 < plan["cached_rows"] <= 4096
-    assert 0 < plan["cached_fraction"] <= 1.0
+    rows = plan_resident_planes((4096, 4096), 4, spec, chip=TPU_V5E)
+    assert 0 < rows <= 4096
     # small domain fully cached
-    plan_small = stencil_solver.plan_for((1024, 1024), 4, spec)
-    assert plan_small["cached_fraction"] == 1.0
+    assert plan_resident_planes((1024, 1024), 4, spec, chip=TPU_V5E) == 1024
 
 
 # -- CG system ----------------------------------------------------------------
 
 def test_cg_tiers_agree_and_converge():
-    data, cols = cg_solver.load_dataset("poisson_64")
+    data, cols = load_dataset("poisson_64")
     b = jax.random.normal(KEY, (data.shape[0],), jnp.float32)
-    x_h, rr_h = cg_solver.run_host_loop(data, cols, b, 25)
-    x_d, rr_d = cg_solver.run_device_loop(data, cols, b, 25)
-    x_f, rr_f = cg_solver.run_fused(data, cols, b, 25, policy="MIX")
+    problem = CGProblem.from_ell(data, cols, b, 25)
+    x_h, rr_h = execute(problem, Plan(tier="host_loop"))
+    x_d, rr_d = execute(problem, Plan(tier="device_loop"))
+    x_f, rr_f = execute(problem, Plan(tier="resident", policy="MIX",
+                                      block_rows=256))
     np.testing.assert_allclose(x_h, x_d, rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(x_h, x_f, rtol=1e-3, atol=1e-4)
     assert float(rr_d) < float(jnp.vdot(b, b))
 
 
 def test_cg_early_stop_on_convergence():
-    data, cols = cg_solver.load_dataset("poisson_64")
+    data, cols = load_dataset("poisson_64")
     b = jax.random.normal(KEY, (data.shape[0],), jnp.float32)
-    x, rr = cg_solver.run_device_loop(data, cols, b, 500, sync_every=25,
-                                      tol=1e-10)
+    x, rr = execute(CGProblem.from_ell(data, cols, b, 500, tol=1e-10),
+                    Plan(tier="device_loop", sync_every=25))
     assert float(rr) < 1e-10 * float(jnp.vdot(b, b)) * 10
 
 
 def test_cg_policy_planner():
+    def policy(n, nnz):
+        budget = int(TPU_V5E.onchip_bytes * 0.9)
+        return cg_policy_from_arrays(cg_arrays(n, nnz, 4), budget)
+
     # small problem: everything fits -> MIX
-    assert cg_solver.plan_policy(10_000, 50_000)["policy"] == "MIX"
+    assert policy(10_000, 50_000)["policy"] == "MIX"
     # huge problem: vectors alone exceed VMEM -> IMP
-    assert cg_solver.plan_policy(10**9, 10**10)["policy"] == "IMP"
+    assert policy(10**9, 10**10)["policy"] == "IMP"
     # vectors fit, matrix does not fit at all -> policy still caches vectors
-    mid = cg_solver.plan_policy(10**6, 3 * 10**8)
+    mid = policy(10**6, 3 * 10**8)
     assert mid["vector_fraction"] == 1.0
 
 
